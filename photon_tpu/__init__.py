@@ -9,4 +9,12 @@ machinery replaced by sharded device arrays, XLA collectives, and vmapped
 batched per-entity solvers.
 """
 
+import os as _os
+
 __version__ = "0.1.0"
+
+# The checkout: the directory that holds this package. What the program
+# builds at run time (the compile cache, the native Avro decoder) lives
+# beside the package, git-ignored — never under ~ and never at a
+# temporary, pid- or time-derived name.
+CHECKOUT_ROOT = _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__)))

@@ -52,6 +52,7 @@ from photon_tpu.data.random_effect import (
 from photon_tpu.models.game import RandomEffectModel
 from photon_tpu.ops import glm as glm_ops
 from photon_tpu.ops import losses as losses_mod
+from photon_tpu.ops import placement
 from photon_tpu.ops import precision as precision_mod
 from photon_tpu.ops import segment_reduce
 from photon_tpu.ops.normalization import NormalizationContext
@@ -522,6 +523,7 @@ def _solve_newton_batched(
     variance_computation: VarianceComputationType,
     l2_weight: Array,
     incremental_weight: Array,
+    spmd: bool = False,
 ):
     """Batch-level damped-Newton/IRLS for a whole dense bucket.
 
@@ -607,10 +609,10 @@ def _solve_newton_batched(
     # The fused Newton kernel is f32-only: a bf16-stored slab takes the
     # batch-minor XLA path below (which reads the slab at half width —
     # the storage win survives the fallback).
-    if nk.kernel_supported(task, x.dtype, r, sub_dim):
+    if nk.kernel_supported(task, x.dtype, r, sub_dim, spmd=spmd):
         # Fused Pallas step: the [S, S] Hessians never leave VMEM (the
-        # XLA path's padded [B, S, S] HBM round trip was the dominant
-        # per-iteration traffic; ops/newton_kernel.py, 3.1x measured).
+        # XLA path's padded [B, S, S] HBM round trip is the traffic it
+        # removes; ops/newton_kernel.py).
         bp = nk.pad_lanes(b)
 
         def pad_b(a):
@@ -1067,7 +1069,7 @@ def _solve_one_entity(
     jax.jit,
     static_argnames=(
         "sub_dim", "task", "opt_config", "use_owlqn", "variance_computation",
-        "direct", "newton", "precision", "gram_mults",
+        "direct", "newton", "precision", "gram_mults", "spmd",
     ),
     # Buffer donation through _scatter_results: the [E, Smax] coefficient
     # and variance tables are CARRIES — each bucket's scatter returns the
@@ -1101,8 +1103,13 @@ def _solve_block(
     newton: bool = False,
     precision: str = "float32",
     gram_mults: tuple | None = None,
+    spmd: bool = False,
 ):
     """One bucket's batched per-entity solve (everything traced/fused).
+
+    ``spmd``: the block is sharded over a mesh (ops/placement.py), which
+    closes every Pallas route in here — GSPMD partitions the XLA solve
+    across the entity axis but cannot partition a Mosaic kernel.
 
     Lazy ``BlockPlan`` buckets materialize their [B, R, k] slabs here, INSIDE
     the compiled program, by gathering the HBM-resident raw arrays — the
@@ -1172,6 +1179,7 @@ def _solve_block(
         and segment_reduce.ell_gram_supported(
             *block.x_indices.shape, sub_dim,
             grad_mult=gram_mults[0], hess_mult=gram_mults[1],
+            spmd=spmd,
         )
     )
     if (
@@ -1184,7 +1192,7 @@ def _solve_block(
         # backend — routing it onto the batched dense solvers instead of
         # the per-entity vmapped scatter path. None = keep ELL.
         dense = segment_reduce.densify_ell_blocks(
-            block.x_indices, block.x_values, sub_dim
+            block.x_indices, block.x_values, sub_dim, spmd=spmd
         )
         if dense is not None:
             block = dataclasses.replace(
@@ -1291,6 +1299,7 @@ def _solve_block(
                 variance_computation=variance_computation,
                 l2_weight=l2_weight,
                 incremental_weight=incremental_weight,
+                spmd=spmd,
             )
             return _scatter_results(w_all, v_all, codes, w, v, it, reason)
 
@@ -1460,6 +1469,7 @@ class RandomEffectCoordinate:
             newton=newton,
             precision=precision_mod.resolve(self.precision),
             gram_mults=gram_mults,
+            spmd=placement.spans_devices(block),
         )
 
     def warmup_thunks(self):

@@ -19,20 +19,50 @@ import json
 import re
 from typing import Any, Iterable, Mapping
 
-# Per-chip peaks used for the roofline summary. v5e is the repo's target
-# part (bench.py uses the same numbers for measured utilization).
-# ``ici_bytes_per_sec`` is the per-chip aggregate inter-chip-interconnect
-# bandwidth (v5e: 4 links x ~400 Gb/s); it prices collective transfers —
-# the --spmd auditor's implicit-reshard findings — as a per-dispatch
-# lower bound the same way hbm_bytes_per_sec prices local traffic.
+# Per-chip peaks, keyed by the ``device_kind`` string JAX reports for
+# the part (``jax.devices()[0].device_kind``). Source of the v5e row:
+# Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 819 GB/s
+# HBM per chip. ``ici_bytes_per_sec`` is the per-chip aggregate
+# inter-chip-interconnect bandwidth this repo has priced collectives
+# with since PR 20 (4 links x ~400 Gb/s, less framing; the same page
+# publishes 1,600 Gbit/s); it prices collective transfers — the --spmd
+# auditor's implicit-reshard findings — as a per-dispatch lower bound
+# the same way hbm_bytes_per_sec prices local traffic.
 CHIP_PEAKS = {
-    "tpu_v5e": {
+    "TPU v5 lite": {
         "flops_per_sec": 197e12,
         "hbm_bytes_per_sec": 819e9,
         "ici_bytes_per_sec": 186e9,
     },
 }
-DEFAULT_CHIP = "tpu_v5e"
+# The part the ABSTRACT tiers price for (static cost reports, the
+# --spmd reshard pricing): they analyse programs without running them,
+# so they name their target explicitly. A path that MEASURES asks
+# ``device_chip()`` instead and never falls back to this.
+TARGET_CHIP = "TPU v5 lite"
+
+
+def device_chip() -> str:
+    """The peaks-table key of the device this process runs on.
+
+    For measuring paths (the cost ledger's priced report, the bench): a
+    measured second is only comparable with the peaks of the part that
+    spent it, so a device that is not in the table is an error, not a
+    default.
+    """
+    import jax
+
+    device = jax.devices()[0]
+    kind = device.device_kind
+    if kind not in CHIP_PEAKS:
+        raise LookupError(
+            f"no peaks for device kind {kind!r} "
+            f"(platform {device.platform!r}): known "
+            f"{sorted(CHIP_PEAKS)}. Measured seconds are priced against "
+            "the part that ran them; name a target with chip= only for "
+            "an abstract report."
+        )
+    return kind
 
 
 def program_cost(lowered: Any) -> dict[str, float]:
@@ -93,7 +123,7 @@ def _boundary_aval_bytes(lowered: Any) -> float:
 
 
 def roofline(
-    cost: Mapping[str, float], chip: str = DEFAULT_CHIP
+    cost: Mapping[str, float], chip: str = TARGET_CHIP
 ) -> dict[str, Any]:
     """Roofline classification of one program's cost counters.
 
@@ -118,7 +148,7 @@ def roofline(
 
 
 def program_report(
-    lowered: Any, chip: str = DEFAULT_CHIP
+    lowered: Any, chip: str = TARGET_CHIP
 ) -> dict[str, Any]:
     """cost + roofline for one lowered program (bench/report entry)."""
     cost = program_cost(lowered)
@@ -128,7 +158,7 @@ def program_report(
 
 
 def fused_fit_report(
-    fused: Any, coords: dict, chip: str = DEFAULT_CHIP
+    fused: Any, coords: dict, chip: str = TARGET_CHIP
 ) -> dict[str, Any]:
     """Per-program predicted cost of one FusedFit generation.
 
@@ -184,7 +214,7 @@ def hlo_shape_bytes(shape_text: str) -> float:
 
 
 def collective_transfer(
-    sequence: Iterable[Mapping[str, str]], chip: str = DEFAULT_CHIP
+    sequence: Iterable[Mapping[str, str]], chip: str = TARGET_CHIP
 ) -> dict[str, Any]:
     """Price an ordered collective sequence as bytes over the interconnect.
 

@@ -1218,15 +1218,15 @@ def audit(
 ) -> tuple[list[Finding], dict]:
     """Run every memory contract; returns (findings, report).
 
-    Builds run under ``disable_x64`` (the tier-2 discipline: audited
-    traces match the production f32 configuration even when the host
-    process enabled x64).
+    Builds run under ``jax.enable_x64(False)`` (the tier-2 discipline:
+    audited traces match the production f32 configuration even when the
+    host process enabled x64).
     """
-    from jax.experimental import disable_x64
+    import jax
 
     findings: list[Finding] = []
     report: dict[str, Any] = {"contracts": {}, "waivers": dict(TIER2_WAIVERS)}
-    with disable_x64():
+    with jax.enable_x64(False):
         resolved = (
             collect_contracts() if contracts is None else list(contracts)
         )

@@ -2387,24 +2387,25 @@ def audit(
 ) -> tuple[list[Finding], dict]:
     """Run every contract; returns (findings, report).
 
-    The registry builds run under ``disable_x64`` so the audited traces
-    match the production (f32) configuration even when the host process
-    enabled x64 (the test harness does).
+    The registry builds run under ``jax.enable_x64(False)`` so the
+    audited traces match the production (f32) configuration even when
+    the host process enabled x64 (the test harness does).
     """
+    import jax
+
     _ensure_virtual_devices()
-    from jax.experimental import disable_x64
 
     from photon_tpu.analysis import costmodel
 
     if chip is None:
-        chip = costmodel.DEFAULT_CHIP
+        chip = costmodel.TARGET_CHIP
     findings: list[Finding] = []
     report: dict[str, Any] = {"contracts": {}}
     # Serial ingest for the whole audit: contract builds must be
     # deterministic, and the estimator fixtures would otherwise spawn
     # background warm compiles nobody consumes (the ingest-pipeline
     # contract invokes the warm compile explicitly, synchronously).
-    with disable_x64(), _serial_ingest_env():
+    with jax.enable_x64(False), _serial_ingest_env():
         resolved = (
             collect_contracts() if contracts is None else list(contracts)
         )

@@ -301,71 +301,6 @@ def partition_coverage(
 
 
 # --------------------------------------------------------------------------
-# the shard_map path diagnosis (the xfail, named statically)
-# --------------------------------------------------------------------------
-
-
-def diagnose_shard_map_path() -> dict[str, Any]:
-    """Statically diagnose the column-sharded (tensor-parallel) mesh path.
-
-    Traces ``FeatureShardedSparse.matvec`` abstractly and returns a
-    structured verdict: ``ok`` (True / False / None when single-device),
-    the ``stage`` reached, the ``divergent_op`` the trace died in, and
-    the raw ``reason``. On jax 0.4.37 the path dies importing
-    ``jax.shard_map`` (it lives in ``jax.experimental.shard_map`` until
-    0.4.38+) — the auditor names that op so the 4 xfailed
-    TestColumnFeatureSharding tests cite a diagnosed finding instead of
-    a mystery failure (tests/test_analysis_spmd.py pins this).
-    """
-    import jax
-    import numpy as np
-
-    from photon_tpu.parallel.mesh import (
-        MODEL_AXIS,
-        make_mesh,
-        shard_features_by_column,
-    )
-
-    if len(jax.devices()) < 2:
-        return {
-            "ok": None,
-            "stage": "setup",
-            "divergent_op": None,
-            "reason": "single visible device — column sharding needs >= 2",
-        }
-    stage = "build"
-    try:
-        mesh = make_mesh(axis_name=MODEL_AXIS)
-        n_dev = int(mesh.shape[MODEL_AXIS])
-        n, d = 4, 2 * n_dev
-        rng = np.random.default_rng(0)
-        indices = rng.integers(0, d, size=(n, 2))
-        values = rng.normal(size=(n, 2)).astype(np.float32)
-        fs = shard_features_by_column(indices, values, d, mesh)
-        stage = "trace"
-        jax.jit(lambda w: fs.matvec(w)).trace(
-            jax.ShapeDtypeStruct((fs.d,), np.float32)
-        )
-        stage = "done"
-        return {"ok": True, "stage": stage, "divergent_op": None, "reason": ""}
-    except Exception as exc:  # noqa: BLE001 — the diagnosis IS the catch
-        m = re.search(r"cannot import name '(\w+)'", str(exc))
-        op = m.group(1) if m else type(exc).__name__
-        return {
-            "ok": False,
-            "stage": stage,
-            "divergent_op": op,
-            "reason": f"{type(exc).__name__}: {exc}",
-            "hint": (
-                "jax 0.4.37 ships shard_map as jax.experimental."
-                "shard_map.shard_map, not jax.shard_map — the mesh "
-                "rebuild (ROADMAP item 1) must import the experimental "
-                "path or move to pjit/NamedSharding"
-            ),
-        }
-
-
-# --------------------------------------------------------------------------
 # contract builders
 # --------------------------------------------------------------------------
 
@@ -484,13 +419,6 @@ def build_mesh_spmd(hosts: int) -> SpmdTrace:
         f"{len(coverage['leaves'])} placed leaves against "
         f"{len(coverage['rules'])} partition rules"
     ]
-    diag = diagnose_shard_map_path()
-    if diag["ok"] is False:
-        notes.append(
-            "column (shard_map) path statically diagnosed: divergent op "
-            f"'{diag['divergent_op']}' at stage {diag['stage']} — "
-            f"{diag['reason']}"
-        )
     return SpmdTrace(hosts=host_traces, coverage=coverage, notes=notes)
 
 
@@ -1126,13 +1054,15 @@ def audit(
 
     ``hosts`` overrides each contract's declared simulated host count
     (CI's multichip-smoke step passes the gloo dryrun's process count).
-    Returns ``(findings, report)``; builds run under ``disable_x64`` so
-    the audited traces match the production (f32) configuration.
+    Returns ``(findings, report)``; builds run under
+    ``jax.enable_x64(False)`` so the audited traces match the production
+    (f32) configuration.
     """
+    import jax
+
     from photon_tpu.analysis import program as program_mod
 
     program_mod._ensure_virtual_devices()
-    from jax.experimental import disable_x64
 
     findings: list[Finding] = []
     report: dict[str, Any] = {"contracts": {}}
@@ -1145,7 +1075,7 @@ def audit(
             "findings": len(lint),
             "suppressed": sum(1 for f in lint if f.suppressed),
         }
-    with disable_x64(), program_mod._serial_ingest_env():
+    with jax.enable_x64(False), program_mod._serial_ingest_env():
         resolved = (
             collect_contracts() if contracts is None else list(contracts)
         )

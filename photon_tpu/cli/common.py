@@ -4,71 +4,55 @@ from __future__ import annotations
 
 import contextlib
 import logging
+import os
 
 
 def maybe_init_distributed() -> bool:
-    """Initialize the JAX multi-host runtime when launched under a
-    coordinator (the cluster-session bring-up the reference does in
+    """Initialize the JAX multi-host runtime when a coordinator is
+    configured (the cluster-session bring-up the reference does in
     SparkSessionConfiguration.scala:109; here controller-less multi-host:
     every process calls jax.distributed.initialize and jax.devices() then
     spans all hosts, so the estimator's auto mesh rides ICI/DCN).
 
-    Uses JAX's own cluster auto-detection (GCE/GKE TPU pods, SLURM, K8s,
-    or the JAX_COORDINATOR_ADDRESS/JAX_NUM_PROCESSES/JAX_PROCESS_ID env
-    vars); a plain single-host launch is a no-op. Returns True when
-    initialization ran.
+    The launch is multi-host only when ``JAX_COORDINATOR_ADDRESS`` is
+    set; ``JAX_NUM_PROCESSES`` / ``JAX_PROCESS_ID`` complete it (with
+    all three given, JAX's cluster auto-detection is switched off — on a
+    host with TPU chips that detection queries the GCE metadata server,
+    which a sealed machine cannot reach). Without a coordinator this
+    never calls ``jax.distributed.initialize``: a single-process launch
+    touches no network. Returns True when initialization ran.
     """
+    from photon_tpu.obs import fleet
+
+    address = os.environ.get("JAX_COORDINATOR_ADDRESS")
+    if not address:
+        # The init half of the fleet clock-alignment handshake
+        # (obs/fleet.py) is sampled on EVERY path out of here —
+        # single-host runs ship 1-rank bundles too.
+        fleet.mark_init()
+        return False
+
     import jax
 
-    # jax.distributed.is_initialized only exists from jax 0.5; on older
-    # versions read the global client state the accessor wraps.
-    initialized = getattr(jax.distributed, "is_initialized", None)
-    if initialized is None:
-        def initialized() -> bool:
-            try:
-                from jax._src.distributed import global_state
-            except ImportError:  # pragma: no cover — layout moved again
-                return False
-            return getattr(global_state, "client", None) is not None
-    def _mark_fleet_clock() -> None:
-        # The init half of the fleet clock-alignment handshake
-        # (obs/fleet.py): sample the monotonic↔epoch offset and probe
-        # the post-init rank identity, so a later bundle commit can
-        # bound how far this host's clock mapping drifted over the run.
-        # Sampled on EVERY path out of here — single-host runs ship
-        # 1-rank bundles too.
-        from photon_tpu.obs import fleet
-
+    if jax.distributed.is_initialized():
         fleet.mark_init()
-
-    if initialized():
-        _mark_fleet_clock()
         return False  # idempotent CLI re-entry in one process
-    try:
-        jax.distributed.initialize()
-    except ValueError as e:
-        # Auto-detection found no cluster environment — the normal
-        # single-host case. Any OTHER ValueError (e.g. coordinator set but
-        # num_processes missing) is real misconfiguration: half-configured
-        # pods silently training independent models would be far worse
-        # than failing fast.
-        if "coordinator_address" in str(e):
-            _mark_fleet_clock()
-            return False
-        raise
-    except RuntimeError as e:
-        # Programmatic re-entry after the XLA backend is already up (tests,
-        # notebooks calling main() mid-session): multi-host init is a
-        # process-start decision, so treat as single-host. The wording has
-        # moved across jax versions ("before any JAX calls" / "before any
-        # JAX computations"); match both. Anything else (real cluster
-        # misconfiguration) propagates.
-        msg = str(e)
-        if ("before any JAX" in msg or "called once" in msg):
-            _mark_fleet_clock()
-            return False
-        raise
-    _mark_fleet_clock()
+    counts = {
+        arg: int(os.environ[var])
+        for arg, var in (("num_processes", "JAX_NUM_PROCESSES"),
+                         ("process_id", "JAX_PROCESS_ID"))
+        if var in os.environ
+    }
+    # A half-configured launch (coordinator set, counts missing and no
+    # cluster environment to supply them) raises from initialize: pods
+    # silently training independent models would be far worse than
+    # failing fast.
+    jax.distributed.initialize(
+        coordinator_address=address,
+        **counts,
+        cluster_detection_method="deactivate" if len(counts) == 2 else None,
+    )
+    fleet.mark_init()
     logging.getLogger("photon.cli").info(
         "multi-host runtime up: process %d/%d, %d global device(s)",
         jax.process_index(), jax.process_count(), len(jax.devices()),

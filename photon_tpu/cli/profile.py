@@ -321,6 +321,11 @@ def main(argv=None) -> int:
                         "the best-of estimator stable on a loaded box)")
     parser.add_argument("--overhead-budget", type=float, default=0.05,
                         help="max tolerated on/off overhead fraction")
+    parser.add_argument("--chip", default=None,
+                        help="costmodel.CHIP_PEAKS key to price rows "
+                        "against. Default: the device this runs on (an "
+                        "error if it has no peaks row). CI on the CPU "
+                        "names the target it checks the census for.")
     args = parser.parse_args(argv)
 
     from photon_tpu import obs
@@ -381,8 +386,8 @@ def main(argv=None) -> int:
     serve_kernel_probe = _serve_kernel_probe()
     attribution = ledger.attribution_since(mark, wall_seconds=None)
 
-    table = ledger.render_top_k(args.top)
-    rows = ledger.top_k(args.top)
+    table = ledger.render_top_k(args.top, args.chip)
+    rows = ledger.top_k(args.top, args.chip)
     print(table)
     if rows:
         worst = rows[0]
@@ -415,7 +420,7 @@ def main(argv=None) -> int:
             "fused-fit wall attributed nothing (ledger feed dead)")
     if kernel_probe is not None:
         probe_rows = [
-            r for r in ledger.report()["rows"]
+            r for r in ledger.report(args.chip)["rows"]
             if r.get("program") == kernel_probe["program"]
         ]
         if not probe_rows:
@@ -428,7 +433,7 @@ def main(argv=None) -> int:
                 "(vs_roofline is None — analytic cost missing)")
     if serve_kernel_probe is not None:
         probe_rows = [
-            r for r in ledger.report()["rows"]
+            r for r in ledger.report(args.chip)["rows"]
             if r.get("program") == serve_kernel_probe["program"]
         ]
         if not probe_rows:
@@ -442,7 +447,7 @@ def main(argv=None) -> int:
 
     if args.json:
         doc = {
-            "report": ledger.report(),
+            "report": ledger.report(args.chip),
             "attribution": attribution,
             "fit_window": {
                 "wall_seconds": round(fit_wall, 6),

@@ -20,8 +20,9 @@ rows/s ingest floor). This module owns the machinery that overlaps them:
   packed plan buffer is pushed as granule-aligned chunks with each
   ``jax.device_put`` enqueued ASYNCHRONOUSLY while the host fills the
   next chunk's staging buffer, then fused into the one contiguous buffer
-  by a donated in-trace concatenate (the chunk buffers' HBM is donated,
-  so peak device memory stays ~1x). Small builds (below one chunk) take
+  by an in-trace concatenate (peak device memory is ~2x the buffer
+  until the chunks are dropped: they cannot be donated into an output
+  larger than each of them). Small builds (below one chunk) take
   the legacy single-shot path — byte-identical layout either way.
 - **PIPELINE_STATS**: per-stage seconds (plan / pack / transfer /
   compile / compile_wait) + the measured compile-overlap fraction, reset
@@ -479,24 +480,20 @@ _concat_cache: dict[int, object] = {}
 
 
 def _concat_chunks(chunks: tuple):
-    """Donated in-trace concatenate: one program per chunk COUNT (chunk
-    sizes recur — all equal but the last — so similarly sized ingests
-    share the executable), with the chunk buffers' device memory donated
-    into the output."""
+    """In-trace concatenate: one program per chunk COUNT (chunk sizes
+    recur — all equal but the last — so similarly sized ingests share
+    the executable). The chunks are NOT donated: the output is larger
+    than any one input, so XLA cannot alias it into a chunk — on the
+    TPU v5e the donation only produced "Some donated buffers were not
+    usable" on every call (PR 21 chip run); the chunk buffers are freed
+    when the caller drops its references."""
     import jax
 
     fn = _concat_cache.get(len(chunks))
     if fn is None:
         import jax.numpy as jnp
 
-        # Donation frees the chunk buffers' HBM into the output on
-        # accelerators; the CPU backend would warn on every call.
-        donate = (
-            (0,) if jax.default_backend() not in ("cpu",) else ()
-        )
-        fn = jax.jit(
-            lambda cs: jnp.concatenate(cs), donate_argnums=donate
-        )
+        fn = jax.jit(lambda cs: jnp.concatenate(cs))
         _concat_cache[len(chunks)] = fn
     return fn(tuple(chunks))
 
@@ -507,7 +504,7 @@ def packed_device_put(arrays) -> tuple:
     Below one chunk this is the legacy single-shot path (one staging fill,
     one ``device_put``). Above it, granule-aligned chunks stream out with
     the host filling chunk i+1 while chunk i's transfer drains, and a
-    donated concatenate restores the ONE contiguous buffer every packed
+    concatenate restores the ONE contiguous buffer every packed
     consumer slices at static offsets (the layout contract is unchanged —
     byte-identical to the single-shot buffer).
 

@@ -5,11 +5,12 @@ block decoder feeding ingest) is native C, mirroring how the reference
 leans on the JVM Avro runtime's generated decoders (AvroUtils.scala:62)
 rather than interpreting schemas per record.
 
-``get_avro_decoder()`` compiles ``avrodec.c`` into a per-user cache
-directory on first use (source-hash keyed, so edits rebuild) and returns
-the extension module, or None when no working compiler is available —
-callers fall back to the interpreter codec, so the native layer is a pure
-accelerator, never a dependency.
+``get_avro_decoder()`` compiles ``avrodec.c`` into
+``<checkout>/.native_cache`` on first use (source-hash keyed, so edits
+rebuild) and returns the extension module, or None when no working
+compiler is available — callers fall back to the interpreter codec (and
+a WARNING says so), so the native layer is a pure accelerator, never a
+dependency.
 """
 
 from __future__ import annotations
@@ -21,6 +22,8 @@ import os
 import subprocess
 import sysconfig
 
+from photon_tpu import CHECKOUT_ROOT
+
 logger = logging.getLogger(__name__)
 
 _SOURCE = os.path.join(os.path.dirname(__file__), "avrodec.c")
@@ -29,9 +32,11 @@ _failed = False
 
 
 def _cache_dir() -> str:
+    # Built inside the checkout (git-ignored), not under ~: a sealed
+    # machine's home is new on every run, and an object file left in a
+    # shared home by another machine is not this checkout's build.
     base = os.environ.get(
-        "PHOTON_NATIVE_CACHE",
-        os.path.join(os.path.expanduser("~"), ".cache", "photon_tpu_native"),
+        "PHOTON_NATIVE_CACHE", os.path.join(CHECKOUT_ROOT, ".native_cache")
     )
     os.makedirs(base, exist_ok=True)
     return base
@@ -58,7 +63,7 @@ def _build() -> str | None:
         )
     except (OSError, subprocess.SubprocessError) as e:
         detail = getattr(e, "stderr", b"") or b""
-        logger.info(
+        logger.warning(
             "native avro decoder unavailable (%s: %s); falling back to the "
             "interpreter codec", e, detail.decode(errors="replace")[:500],
         )
@@ -89,7 +94,7 @@ def get_avro_decoder():
         spec.loader.exec_module(mod)
         _cached = mod
     except Exception as e:  # any load failure -> interpreter fallback
-        logger.info("native avro decoder failed to load (%s)", e)
+        logger.warning("native avro decoder failed to load (%s)", e)
         # A corrupted cache file would otherwise poison every later
         # process; drop it so the next call rebuilds from source.
         try:

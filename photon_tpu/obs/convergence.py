@@ -84,8 +84,7 @@ def record(coordinates: tuple[str, ...], array) -> None:
 def _series(t: dict) -> dict:
     """Materialize one parked trace (device->host fetch cached per
     entry: repeated consumers — snapshot then write_jsonl — pay the
-    transfer once, which matters on tunneled backends where every pull
-    is a ~100ms round trip).
+    transfer once).
 
     Double-checked swap: the transfer itself runs OUTSIDE the module
     lock — a concurrent exporter must never block the training thread's

@@ -55,7 +55,7 @@ from __future__ import annotations
 import threading
 import time
 
-from photon_tpu.analysis.costmodel import DEFAULT_CHIP, roofline
+from photon_tpu.analysis.costmodel import device_chip, roofline
 
 # The coordinate slot for costs that belong to no single coordinate
 # (the serve ladder, slab materialization, whole-program rows).
@@ -440,7 +440,7 @@ def _blocking_reason(row: dict, roof: dict | None) -> str:
     return "bandwidth" if roof["bound"] == "hbm" else "compute"
 
 
-def report(chip: str = DEFAULT_CHIP) -> dict:
+def report(chip: str | None = None) -> dict:
     """The priced ledger: every row joined to its program's static
     cost and roofline.
 
@@ -452,7 +452,14 @@ def report(chip: str = DEFAULT_CHIP) -> dict:
     seconds (measured minus bound x dispatches), and the blocking
     reason. Cost thunks are priced here, outside every lock a dispatch
     path takes.
+
+    ``chip`` keys ``costmodel.CHIP_PEAKS``. The default is the device
+    these seconds were measured on (``costmodel.device_chip()``), and a
+    device without a peaks row is an error; only a caller that is not
+    measuring the device (CI on the CPU, tests) names a target.
     """
+    if chip is None:
+        chip = device_chip()
     snap = snapshot()
     # A parts-split program (the fused fit) spreads ONE program's
     # dispatches over several coordinate rows: each row carries only
@@ -521,7 +528,7 @@ def report(chip: str = DEFAULT_CHIP) -> dict:
     }
 
 
-def top_k(k: int = 5, chip: str = DEFAULT_CHIP) -> list[dict]:
+def top_k(k: int = 5, chip: str | None = None) -> list[dict]:
     """The k worst rows by wasted-seconds-vs-roofline (the profile
     CLI's table), residual rows excluded — they have no program to
     blame by construction."""
@@ -531,7 +538,7 @@ def top_k(k: int = 5, chip: str = DEFAULT_CHIP) -> list[dict]:
     return rows[: max(int(k), 0)]
 
 
-def render_top_k(k: int = 5, chip: str = DEFAULT_CHIP) -> str:
+def render_top_k(k: int = 5, chip: str | None = None) -> str:
     """Human-readable top-k table (one line per row)."""
     rows = top_k(k, chip)
     if not rows:

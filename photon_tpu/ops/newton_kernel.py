@@ -22,12 +22,13 @@ the remaining ~3-6x of per-iteration headroom; this kernel implements it:
   tracking the largest passing step per lane (argmax on bools does not
   lower in Mosaic).
 
-Measured (bench user bucket, [~100k, 64, 17] logistic): 9.9ms per Newton
-step vs 30.9ms for the batch-minor XLA step — 3.1x.
+Speed against the batch-minor XLA step: not measured on this chip (the
+round-5 figure came from a backend that no longer exists).
 
 Scope: float32, dense slabs, logistic/Poisson losses (the two losses the
-damped-Newton path serves), R * S bounded so a block fits VMEM. The
-batch-minor XLA path remains as fallback and parity oracle
+damped-Newton path serves), and a block whose VMEM estimate fits the
+budget (``_vmem_estimate_bytes``). The batch-minor XLA path remains as
+the route for every other shape and as parity oracle
 (tests/test_newton_kernel.py).
 """
 
@@ -63,10 +64,22 @@ PROGRAM_AUDIT = dict(
     recompiles_on=("bucket_shape", "line_search_trials"),
     hot_loop=True,
 )
-# x block is [S, R, LANES] f32 in VMEM; stay well under the ~16MB budget
-# (double buffering + scratch + vectors).
-_MAX_RS = 16_384
+# VMEM budget of one grid step, out of v5e's 16 MiB default scoped
+# limit. The estimate below was calibrated by AOT-compiling the kernel
+# for a v5e topology across (r, s): every shape with an estimate up to
+# 16.0 MiB compiled and every one from 16.5 MiB was refused ("Scoped
+# allocation ... exceeded scoped vmem limit"); the old gate, r * s <=
+# 16384, admitted 8 MiB x blocks that are refused once double buffered.
+_VMEM_BUDGET_BYTES = 14 * 2**20
 _LINE_SEARCH_TRIALS = 16
+
+
+def _vmem_estimate_bytes(r: int, s: int) -> int:
+    """Per-grid-step VMEM of the kernel, f32 x 128 lanes: the
+    double-buffered [S, R] x block, ~16 live [R]-row vectors (the three
+    double-buffered row operands plus the body's margin / curvature /
+    line-search temporaries) and the [S, S] Hessian scratch."""
+    return 4 * LANES * (2 * r * s + 16 * r + s * s)
 
 
 def interpret_required() -> bool:
@@ -79,16 +92,23 @@ def interpret_required() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def kernel_supported(task: TaskType, dtype, r: int, s: int) -> bool:
+def kernel_supported(
+    task: TaskType, dtype, r: int, s: int, *, spmd: bool = False
+) -> bool:
+    """Whether the Pallas step serves this bucket. ``spmd``: the
+    operands span several devices (ops/placement.py) — GSPMD does not
+    partition a Mosaic kernel, so a mesh fit takes the XLA step."""
     flag = os.environ.get("PHOTON_NEWTON_KERNEL", "auto").lower()
     if flag in ("0", "off", "false"):  # photon: ignore[spmd-host-divergence] -- kernel-select flag is launch config, exported fleet-uniform; divergence trips the --spmd trace proof
+        return False
+    if spmd:
         return False
     if jnp.dtype(dtype) != jnp.float32:
         return False
     if task not in (TaskType.LOGISTIC_REGRESSION,
                     TaskType.POISSON_REGRESSION):
         return False
-    if r * s > _MAX_RS:
+    if _vmem_estimate_bytes(r, s) > _VMEM_BUDGET_BYTES:
         return False
     if flag in ("1", "on", "force"):  # photon: ignore[spmd-host-divergence] -- kernel-select flag is launch config, exported fleet-uniform; divergence trips the --spmd trace proof
         # Callers pass interpret=interpret_required() so a forced run on
@@ -264,6 +284,7 @@ def newton_step_lanes(
         ],
         scratch_shapes=[pltpu.VMEM((s, s, LANES), jnp.float32)],
         interpret=interpret,
+        name="newton_step_lanes",
     )(x_t, w, y, wt, off, l2, mt, vm, f)
 
 

@@ -16,10 +16,13 @@ This kernel reformulates scatter-add as a WINDOWED ONE-HOT CONTRACTION:
   steps in VMEM (init at ``k == 0``), so the result is written to HBM
   exactly once;
 - for each (out tile j, step k) the kernel streams ONE ``_IN_TILE``
-  block of (ids, values) and adds ``values @ onehot(ids - j*_OUT_TILE)``
-  — an [IT] x [IT, OT] matmul at full MXU width; elements whose id
-  falls outside the window contribute an all-zero one-hot row, so
-  visiting extra tiles is always CORRECT, only ever wasteful;
+  block of (ids, values), both LANE-major ``[1, IT]`` (blocks
+  ``(None, 1, IT)`` over ``[tiles, 1, IT]`` arrays — the layout Mosaic
+  accepts), and adds ``values @ onehot(ids - j*_OUT_TILE)`` with the
+  one-hot built transposed, ``[OT, IT]``, and contracted over the lane
+  dim of both; elements whose id falls outside the window contribute an
+  all-zero one-hot column, so visiting extra tiles is always CORRECT,
+  only ever wasteful;
 - which input tiles each out tile visits comes from a SCALAR-PREFETCHED
   ``starts`` vector (``pltpu.PrefetchScalarGridSpec``): the block index
   maps resolve ``starts[j] + k`` before the body runs. The caller
@@ -128,15 +131,22 @@ def interpret_required() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def kernel_supported(num_values: int, num_segments: int, dtype) -> bool:
+def kernel_supported(
+    num_values: int, num_segments: int, dtype, *, spmd: bool = False
+) -> bool:
     """Whether the Pallas path serves this reduce shape on this backend.
 
     ``PHOTON_SEGMENT_KERNEL``: ``auto`` (default — real TPU only),
     ``force``/``on``/``1`` (every backend; non-TPU runs interpreted —
     slow, for parity tests), ``off``/``0`` (always the XLA fallback).
+    ``spmd``: the operands span several devices (ops/placement.py) —
+    GSPMD does not partition a Mosaic kernel, so mesh scoring and mesh
+    solves keep the XLA scatter.
     """
     flag = os.environ.get("PHOTON_SEGMENT_KERNEL", "auto").lower()
     if flag in ("0", "off", "false"):  # photon: ignore[spmd-host-divergence] -- kernel-select flag is launch config, exported fleet-uniform; divergence trips the --spmd trace proof
+        return False
+    if spmd:
         return False
     if jnp.dtype(dtype) not in (jnp.dtype(jnp.float32),
                                 jnp.dtype(jnp.bfloat16)):
@@ -211,15 +221,24 @@ def _kernel(starts_ref, ids_ref, vals_ref, out_ref):
         out_ref[...] = jnp.zeros_like(out_ref)
 
     base = pl.program_id(0) * _OUT_TILE
-    ids = ids_ref[0]  # [IT, 1] int32
-    onehot = (
-        ids
+    # ids and values both arrive LANE-major, [1, IT]: the one-hot is
+    # built transposed, [OT, IT] (segment along sublanes, element along
+    # lanes), so the ids broadcast down the sublanes for free and no
+    # operand ever has a trailing dim of 1 (which pads to 128 lanes).
+    onehot_t = (
+        ids_ref[...]
         == base
-        + jax.lax.broadcasted_iota(jnp.int32, (_IN_TILE, _OUT_TILE), 1)
+        + jax.lax.broadcasted_iota(jnp.int32, (_OUT_TILE, _IN_TILE), 0)
     ).astype(jnp.float32)
     vals = vals_ref[...].astype(jnp.float32)  # [1, IT]
-    out_ref[...] += jnp.dot(
-        vals, onehot, preferred_element_type=jnp.float32
+    # [1, IT] x [OT, IT]^T -> [1, OT]: contraction over the lane dim of
+    # both (the MXU's native transposed-rhs form). HIGHEST: the default
+    # f32 contraction may round operands to bf16, and the values are the
+    # payload here.
+    out_ref[...] += jax.lax.dot_general(
+        vals, onehot_t, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )
 
 
@@ -257,27 +276,33 @@ def _windowed_sum(
     ids_p = jnp.pad(ids, (0, pad), constant_values=n_pad)
     vals_p = jnp.pad(values, (0, pad))
     starts = jnp.clip(starts, 0, m_tiles - k_tiles).astype(jnp.int32)
+    # Every array is [tiles, 1, TILE] and every block [None, 1, TILE]:
+    # Mosaic requires a block's last two dims to be (8, 128)-divisible
+    # or EQUAL to the array's, and (1, TILE) equals it by construction.
+    # (A [tiles, TILE] array with (1, TILE) blocks is refused at
+    # lowering.) The leading tile index is squeezed away in the kernel.
+    in_tile = pl.BlockSpec(
+        (None, 1, _IN_TILE), lambda j, k, s: (s[j] + k, 0, 0)
+    )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(out_tiles, k_tiles),
-        in_specs=[
-            pl.BlockSpec(
-                (1, _IN_TILE, 1), lambda j, k, s: (s[j] + k, 0, 0)
-            ),
-            pl.BlockSpec((1, _IN_TILE), lambda j, k, s: (s[j] + k, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, _OUT_TILE), lambda j, k, s: (j, 0)),
+        in_specs=[in_tile, in_tile],
+        out_specs=pl.BlockSpec(
+            (None, 1, _OUT_TILE), lambda j, k, s: (j, 0, 0)
+        ),
     )
     out = pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((out_tiles, _OUT_TILE),
+        out_shape=jax.ShapeDtypeStruct((out_tiles, 1, _OUT_TILE),
                                        jnp.float32),
         interpret=interpret,
+        name="segment_reduce",
     )(
         starts,
-        ids_p.reshape(m_tiles, _IN_TILE, 1),
-        vals_p.reshape(m_tiles, _IN_TILE),
+        ids_p.reshape(m_tiles, 1, _IN_TILE),
+        vals_p.reshape(m_tiles, 1, _IN_TILE),
     )
     return out.reshape(-1)[:num_segments]
 
@@ -398,7 +423,7 @@ def window_bound_from_counts(max_count) -> int:
 
 def ell_gram_supported(
     b: int, r: int, k: int, sub_dim: int, *,
-    grad_mult: int, hess_mult: int,
+    grad_mult: int, hess_mult: int, spmd: bool = False,
 ) -> bool:
     """Whether the gram-route reduces (``ell_gram_blocks`` +
     ``ell_segment_slots``) serve this ELL block shape on this backend.
@@ -422,8 +447,8 @@ def ell_gram_supported(
         return False
     # Products are formed f32 regardless of the storage dtype.
     return (
-        kernel_supported(m_pair, b * s * s, jnp.float32)
-        and kernel_supported(b * r * k, b * s, jnp.float32)
+        kernel_supported(m_pair, b * s * s, jnp.float32, spmd=spmd)
+        and kernel_supported(b * r * k, b * s, jnp.float32, spmd=spmd)
     )
 
 
@@ -528,6 +553,7 @@ def densify_ell_blocks(
     sub_dim: int,
     *,
     site: str = "segment_reduce/densify",
+    spmd: bool = False,
 ) -> Array | None:
     """[B, R, k] slot-ELL -> [B, R, S] dense via ONE flat tiled reduce
     (the wide-subspace ``.at[rows, slots].add`` scatter of
@@ -550,7 +576,7 @@ def densify_ell_blocks(
     if (
         s > _OUT_TILE
         or k_tiles > _MAX_K_TILES
-        or not kernel_supported(m, n, x_values.dtype)
+        or not kernel_supported(m, n, x_values.dtype, spmd=spmd)
     ):
         return None
     row_base = (

@@ -2,36 +2,48 @@
 
 The AOT score ladder (serve/programs.py) lowers each coordinate's
 gather -> contract -> add as its own fusion chain inside the jitted
-program: per random coordinate, XLA materializes the gathered [B, S]
-coefficient rows and the [B, k, S] / [S, d] one-hot operands in HBM
-between chains, and the per-coordinate adds round-trip the [B] partial
-scores. This kernel scores an entire padded rung in ONE pallas_call:
+program: per random coordinate the dense-request route rebuilds an
+[E, d + 1] original-space table by scatter on EVERY dispatch
+(``models/game._score_raw_dense``), the sparse route materializes the
+[B, k, S] one-hot operand, and the per-coordinate adds round-trip the
+[B] partial scores. This kernel scores an entire padded rung in ONE
+pallas_call:
 
-- the grid is ``(rung,)`` — each step owns one request row;
-- the per-request entity codes ride as a SCALAR-PREFETCHED [C, rung]
-  int32 array (``pltpu.PrefetchScalarGridSpec``), so each random
-  coordinate's [1, S] weight row and projector row are DMA'd straight
-  from the HBM-resident tables by the BlockSpec index maps
-  (``codes[c, i]``, clamped at 0) before the body runs — the gather
-  never materializes a [B, S] intermediate;
-- inside the body every contraction is a one-hot multiply-reduce in
-  VMEM with float32 accumulators; coordinate partials add in registers
-  and the [1, 1] score is written once. Cold rows (code -1) multiply
-  their random contribution by 0 — fixed-effect-only, the same
-  semantics as ``models/game._score_raw_dense`` / ``_score_raw_sparse``.
+- the ROW GATHER stays in XLA, in the same jitted program: each random
+  coordinate's weight and projector rows for the rung's codes are taken
+  once (``[rung, S]``, a few KiB) and cold rows (code -1) are zeroed
+  there. A table row cannot be addressed from inside a Mosaic kernel at
+  these shapes: a ``(1, S)`` block over an ``[E, S]`` table is refused
+  at lowering (a block's last two dims must be (8, 128)-divisible or
+  equal the array's), and a manual one-row DMA is refused unless S is a
+  multiple of 128 lanes and the table is 32-bit;
+- the grid walks the rung in tiles of up to ``_TILE_ROWS`` requests, so
+  every block is ``(tile, width)`` with ``tile`` a multiple of 8 or the
+  whole rung — legal for every ladder rung, including the latency
+  rung 1;
+- inside the body every contraction is a static loop over subspace
+  slots of masked row reductions in VMEM with float32 accumulators;
+  coordinate partials add in registers and the [tile, 1] score block is
+  written once. Cold rows carry all-zero weights — fixed-effect-only,
+  the same semantics as ``models/game._score_raw_dense`` /
+  ``_score_raw_sparse``.
 
 Storage dtypes: f32 or bf16 tables (the serving precision policy);
-feature payloads are cast to the table dtype at the contraction and
+feature payloads are rounded to the table dtype at the contraction and
 every reduction accumulates f32 — the ops/precision.py invariant, and
 the parity contract with the jit fallback (tests/test_serve_kernel.py).
+Arithmetic inside the body is f32 throughout: a bf16 x bf16 product is
+exact in f32, so rounding it back to bf16 reproduces the bf16 multiply
+bit for bit without needing a bf16 vector unit (v5e has none).
 
-Scope and fallback mirror ops/segment_reduce.py: Mosaic lowering is
-TPU-only, so ``interpret_required()`` routes forced runs on other
-backends through ``interpret=True``; unforced non-TPU backends keep the
-jitted per-coordinate chain, which doubles as the parity oracle. The
-``PHOTON_SERVE_KERNEL`` flag (auto/force/off) picks the path ONCE at
-``ScorePrograms`` construction — tables stay traced operands either
-way, so values-only reloads re-enter the same executables.
+Scope mirrors ops/segment_reduce.py: Mosaic lowering is TPU-only, so
+``interpret_required()`` routes forced runs on other backends through
+``interpret=True``; unforced non-TPU backends keep the jitted
+per-coordinate chain, which doubles as the parity oracle. The gate
+(``kernel_supported``) is decided ONCE at ``ScorePrograms``
+construction from the table dtype and the model's static widths —
+tables stay traced operands either way, so values-only reloads re-enter
+the same executables.
 """
 
 from __future__ import annotations
@@ -41,15 +53,14 @@ import os
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 Array = jax.Array
 
 # Program contract (audited by `python -m photon_tpu.analysis
 # --semantic`): one ladder rung through the fused kernel is ONE program
-# — tables, features and the prefetched codes are traced operands; only
-# the rung batch and the model structure (shard widths, coordinate
-# count, sub_dims) are static and may mint a new executable. No host
+# — tables, features and the codes are traced operands; only the rung
+# batch and the model structure (shard widths, coordinate count,
+# sub_dims) are static and may mint a new executable. No host
 # callbacks, no f64: this kernel IS the steady-state request loop.
 PROGRAM_AUDIT = dict(
     name="serve-kernel",
@@ -62,10 +73,11 @@ PROGRAM_AUDIT = dict(
 
 # Memory contract (`--memory`, ANALYSIS.md): the fused rung's live set
 # is the resident tables (weights at storage width + int32 projector +
-# fixed weights) plus the padded request payloads and the [rung] f32
-# output — NO gathered [rung, s] coefficient intermediate and no
-# [rung, k, s] one-hot operand, which is the kernel's memory story vs
-# the jit chain. Scaffold constant mirrors the serving audit.
+# fixed weights) plus the padded request payloads, the gathered
+# [rung, s] weight + projector rows and the [rung] f32 output — NO
+# rebuilt [e, d] original-space table and no [rung, k, s] one-hot
+# operand, which is the kernel's memory story vs the jit chain.
+# Scaffold constant mirrors the serving audit.
 MEMORY_AUDIT = dict(
     name="serve-kernel-memory",
     entry="ops.serve_kernel.fused_score",
@@ -75,12 +87,18 @@ MEMORY_AUDIT = dict(
         # Resident: [e, s] weights at storage width + [e, s] int32
         # projector + [d] fixed weights (+ a fixed scaffold constant);
         # per request row: the padded feature payloads (d dense + du
-        # shard columns), the prefetched code, and the f32 score. NO
-        # rung * s gathered-coefficient term — the kernel's gathers
-        # live in VMEM blocks, which is the whole point.
+        # shard columns), the code, the gathered weight + projector
+        # row (the XLA-side take the kernel consumes), and the f32
+        # score.
+        # The last term is the body's [tile, width] lane-iota / mask /
+        # select temporaries: VMEM values in the compiled kernel,
+        # priced here because the audit walks the interpret-path
+        # lowering, where they are ordinary buffers.
         "serve_kernel_b*": (
             "e * s * (wbytes + 4) + d * wbytes + 52 * wbytes"
             " + rung * (d + du + s) * wbytes"
+            " + rung * s * (wbytes + 4)"
+            " + rung * 3 * (d + du) * 4"
         ),
     },
     tolerance=1.5,
@@ -98,14 +116,25 @@ NUMERICS_AUDIT = dict(
     covers=("serve-kernel",),
     builder="build_serve_kernel_numerics",
     budgets={
-        # One bf16 storage rounding on the deepest path (feature cast
-        # at the contraction; the table sides are already storage
-        # width) + f32 accumulator rounding over the summed one-hot
+        # Two bf16 roundings on the deepest path — the feature cast at
+        # the contraction (the table sides are already storage width)
+        # and the storage-width PRODUCT, which the jit chain's bf16
+        # multiply rounds implicitly and this body rounds explicitly
+        # (``_rounded``) — + f32 accumulator rounding over the summed
         # reduce lengths: the [s, d] random gather + the [s] row
         # contraction + the [d] fixed contraction, plus the per-rung
         # output accumulation.
         "serve_kernel_b*": (
-            "u16 + u32 * (s * (d + du) + d + du + 2 * s + 4 * rung)"
+            "2 * u16"
+            " + u32 * (s * (d + du) + d + du + 2 * s + 4 * rung)"
+        ),
+    },
+    suppress={
+        "numerics-cast-roundtrip": (
+            "_rounded's f32->bf16->f32 IS the arithmetic: a bf16 x bf16 "
+            "product is exact in f32, so rounding it to bf16 and back "
+            "reproduces the jit chain's bf16 multiply bit for bit "
+            "without a bf16 vector unit (v5e has none)"
         ),
     },
     tolerance=1.5,
@@ -126,19 +155,65 @@ def interpret_required() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def kernel_supported(dtype) -> bool:
+# Request rows per grid step; a rung below it is ONE step over the whole
+# rung. A multiple of 8 (the f32 sublane count), so every [tile, width]
+# block is legal.
+_TILE_ROWS = 128
+# Shape bounds of the gate (checked in code, never discovered by a
+# compiler refusal). The body is a STATIC loop over subspace slots (one
+# masked row reduction each), so the unrolled term count is bounded;
+# and every operand block is [tile, width] f32 in VMEM, double
+# buffered, so the summed lane-padded widths are bounded to keep the
+# blocks under half of v5e's 16 MiB scoped VMEM.
+_MAX_UNROLLED_TERMS = 512
+_MAX_BLOCK_BYTES = 8 * 2**20
+
+
+def _lanes(width: int) -> int:
+    return -(-int(width) // 128) * 128
+
+
+def shape_supported(fe_dims, re_dims) -> bool:
+    """Whether the fused kernel is legal AND sane for this model
+    structure: ``fe_dims`` [(kind, d, k)] per fixed coordinate,
+    ``re_dims`` [(kind, d, k, s)] per random one (the ``_record_site``
+    vocabulary). A wide sparse fixed effect (d in the millions) or a
+    wide random subspace stays on the jitted chain."""
+    terms = 0
+    lanes = 0
+    for kind, d, k in fe_dims:
+        lanes += _lanes(d)  # the [1, d] weight row
+        if kind == "dense":
+            lanes += _lanes(d)
+        else:
+            terms += k
+            lanes += 2 * _lanes(k)
+    for kind, d, k, s_dim in re_dims:
+        terms += s_dim
+        lanes += 2 * _lanes(s_dim)  # gathered weight + projector rows
+        lanes += _lanes(d) if kind == "dense" else 2 * _lanes(k)
+    return (
+        terms <= _MAX_UNROLLED_TERMS
+        and 2 * 4 * _TILE_ROWS * lanes <= _MAX_BLOCK_BYTES
+    )
+
+
+def kernel_supported(dtype, fe_dims=(), re_dims=()) -> bool:
     """Whether the fused kernel serves score dispatches on this backend.
 
     ``PHOTON_SERVE_KERNEL``: ``auto`` (default — real TPU only),
     ``force``/``on``/``1`` (every backend; non-TPU runs interpreted —
     slow, for parity tests and the profile probe), ``off``/``0``
-    (always the jitted per-coordinate chain).
+    (always the jitted per-coordinate chain). The dtype and
+    ``shape_supported`` bounds apply under every flag value.
     """
     flag = os.environ.get("PHOTON_SERVE_KERNEL", "auto").lower()
     if flag in ("0", "off", "false"):  # photon: ignore[spmd-host-divergence] -- kernel-select flag is launch config, exported fleet-uniform; divergence trips the --spmd trace proof
         return False
     if jnp.dtype(dtype) not in (jnp.dtype(jnp.float32),
                                 jnp.dtype(jnp.bfloat16)):
+        return False
+    if not shape_supported(fe_dims, re_dims):
         return False
     if flag in ("1", "on", "force"):  # photon: ignore[spmd-host-divergence] -- kernel-select flag is launch config, exported fleet-uniform; divergence trips the --spmd trace proof
         return True
@@ -162,8 +237,9 @@ def _record_site(site: str, rung: int, fe_dims, re_dims, dtype) -> None:
             flops += 2.0 * rung * k * d
             hbm += rung * k * 8.0
     for kind, d, k, s in re_dims:
-        # One [1, s] weight + projector row gathered per request.
-        hbm += rung * s * (esize + 4.0)
+        # One [1, s] weight + projector row gathered per request (the
+        # XLA-side take), written once and read once by the kernel.
+        hbm += 3.0 * rung * s * (esize + 4.0)
         if kind == "dense":
             flops += 2.0 * rung * (s * d + s)
             hbm += rung * d * 4.0
@@ -204,85 +280,85 @@ def traced_sites() -> dict[str, dict]:
     return out
 
 
+def _rounded(x, wdtype):
+    """``x`` (f32) rounded to the table storage dtype, held as f32."""
+    if wdtype == jnp.dtype(jnp.float32):
+        return x
+    return x.astype(wdtype).astype(jnp.float32)
+
+
 def _make_kernel(fe_ops, re_ops):
     """Kernel body closure over the STATIC coordinate walk.
 
     ``fe_ops``: [(kind, shard_ref_slots, w_slot, wdtype)] per fixed
-    coordinate; ``re_ops``: [(kind, shard_ref_slots, w_slot, code_row,
-    wdtype)] per random one. Slot numbers index the positional operand
-    refs; the scalar-prefetched codes ref comes first.
+    coordinate; ``re_ops``: [(kind, shard_ref_slots, w_slot, wdtype)]
+    per random one (the gathered weight rows sit at ``w_slot``, the
+    gathered projector rows at ``w_slot + 1``). Slot numbers index the
+    positional operand refs. Every value in the body is [tile, width]
+    or [tile, 1] float32/int32.
     """
 
-    def kernel(codes_ref, *refs):
+    def kernel(*refs):
         out_ref = refs[-1]
-        i = pl.program_id(0)
-        acc = jnp.zeros((1, 1), jnp.float32)
+        tile = out_ref.shape[0]
+        acc = jnp.zeros((tile, 1), jnp.float32)
         for kind, shard, w_slot, wdtype in fe_ops:
-            w = refs[w_slot][...]  # [1, d]
+            w = refs[w_slot][...].astype(jnp.float32)  # [1, d]
+            d = w.shape[1]
             if kind == "dense":
-                x = refs[shard[0]][...].astype(wdtype)  # [1, d]
+                x = _rounded(refs[shard[0]][...], wdtype)  # [tile, d]
                 acc += jnp.sum(
-                    (x * w).astype(jnp.float32), axis=1, keepdims=True
+                    _rounded(x * w, wdtype), axis=1, keepdims=True
                 )
             else:
-                idx = refs[shard[0]][...]  # [1, k] int32
-                val = refs[shard[1]][...]  # [1, k]
-                k = idx.shape[1]
-                d = w.shape[1]
-                onehot = (
-                    idx[0][:, None]
-                    == jax.lax.broadcasted_iota(jnp.int32, (k, d), 1)
-                ).astype(jnp.float32)
-                # One-hot gather is exact: f32 sum of one bf16 value.
-                gathered = jnp.sum(
-                    onehot * w.astype(jnp.float32), axis=1
-                ).astype(wdtype)
-                acc += jnp.sum(
-                    (val[0].astype(wdtype) * gathered).astype(
-                        jnp.float32
-                    ),
-                )[None, None]
-        for kind, shard, w_slot, code_row, wdtype in re_ops:
-            w = refs[w_slot][...]       # [1, s] gathered table row
-            proj = refs[w_slot + 1][...]  # [1, s] int32 projector row
-            s = w.shape[1]
-            # Cold / padding rows (code -1) contribute zero — the
-            # fixed-effect-only fallback of the jit chain.
-            known = (codes_ref[code_row, i] >= 0).astype(jnp.float32)
+                idx = refs[shard[0]][...]  # [tile, k] int32
+                val = _rounded(refs[shard[1]][...], wdtype)
+                lane = jax.lax.broadcasted_iota(jnp.int32, (tile, d), 1)
+                for j in range(idx.shape[1]):
+                    # Masked row reduction = exact gather of w[idx[:, j]]
+                    # (one term per row).
+                    picked = jnp.sum(
+                        jnp.where(idx[:, j:j + 1] == lane, w, 0.0),
+                        axis=1, keepdims=True,
+                    )
+                    acc += _rounded(val[:, j:j + 1] * picked, wdtype)
+        for kind, shard, w_slot, wdtype in re_ops:
+            # Gathered table rows; cold / padding rows (code -1) were
+            # zeroed by the caller — the fixed-effect-only fallback of
+            # the jit chain.
+            w = refs[w_slot][...].astype(jnp.float32)  # [tile, s]
+            proj = refs[w_slot + 1][...]  # [tile, s] int32
             if kind == "dense":
-                x = refs[shard[0]][...]  # [1, d] f32 payload
-                d = x.shape[1]
-                # proj -1 pads match no feature id: the spill-drop of
-                # _score_raw_dense's scatter.
-                onehot = (
-                    proj[0][:, None]
-                    == jax.lax.broadcasted_iota(jnp.int32, (s, d), 1)
-                ).astype(jnp.float32)
-                # One-hot gather is exact (distinct projector slots:
-                # one term per row), so rounding AFTER it equals the
-                # jit chain's x.astype(w.dtype) — one storage rounding,
-                # no f32->bf16->f32 round-trip in the cast graph.
-                xg = jnp.sum(
-                    onehot * x.astype(jnp.float32)[0][None, :], axis=1
-                ).astype(wdtype)
-                z = jnp.sum(
-                    (w[0] * xg).astype(jnp.float32)
-                )
+                x = refs[shard[0]][...]  # [tile, d] f32 payload
+                lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+                for j in range(w.shape[1]):
+                    # proj -1 pads match no feature id: the spill-drop
+                    # of _score_raw_dense's scatter. The masked
+                    # reduction is an exact gather (distinct projector
+                    # slots: one term per row), so rounding AFTER it
+                    # equals the jit chain's x.astype(w.dtype) — one
+                    # storage rounding.
+                    xg = jnp.sum(
+                        jnp.where(proj[:, j:j + 1] == lane, x, 0.0),
+                        axis=1, keepdims=True,
+                    )
+                    acc += _rounded(
+                        w[:, j:j + 1] * _rounded(xg, wdtype), wdtype
+                    )
             else:
-                idx = refs[shard[0]][...]  # [1, k] int32
-                val = refs[shard[1]][...]  # [1, k]
-                k = idx.shape[1]
-                onehot = (
-                    idx[0][:, None] == proj[0][None, :]
-                ).astype(jnp.float32)  # [k, s]; duplicates sum
-                contrib = jnp.sum(
-                    val[0].astype(jnp.float32)[:, None] * onehot, axis=0
-                ).astype(wdtype)  # storage rounding, like_storage
-                z = jnp.sum(
-                    (contrib.astype(jnp.float32))
-                    * w[0].astype(jnp.float32)
-                )
-            acc += (known * z)[None, None]
+                idx = refs[shard[0]][...]  # [tile, k] int32
+                val = refs[shard[1]][...].astype(jnp.float32)
+                for j in range(w.shape[1]):
+                    # Duplicate feature ids in a request sum.
+                    contrib = jnp.sum(
+                        jnp.where(idx == proj[:, j:j + 1], val, 0.0),
+                        axis=1, keepdims=True,
+                    )
+                    # Storage rounding of the slot total (like_storage);
+                    # the product of two storage-width values is exact
+                    # in f32, as in the jit chain's f32-accumulated
+                    # einsum.
+                    acc += _rounded(contrib, wdtype) * w[:, j:j + 1]
         out_ref[...] = acc
 
     return kernel
@@ -317,31 +393,28 @@ def fused_score(
         (leaf if isinstance(leaf, jax.Array) or hasattr(leaf, "shape")
          else leaf[0]).shape[0]
     )
-    n_codes = len(re_ws)
-    codes_arr = (
-        jnp.stack([c.astype(jnp.int32) for c in codes])
-        if n_codes
-        else jnp.zeros((1, rung), jnp.int32)
-    )
+    tile = min(rung, _TILE_ROWS)
 
     operands: list = []
     in_specs: list = []
     shard_slots: dict[int, tuple[int, ...]] = {}
 
-    def row_spec(width: int):
-        return pl.BlockSpec((1, width), lambda i, s: (i, 0))
+    def add_rows(arr):
+        # [rung, width] request-major operand, walked in row tiles.
+        operands.append(arr)
+        in_specs.append(
+            pl.BlockSpec((tile, arr.shape[1]), lambda i: (i, 0))
+        )
 
     for si, kind in enumerate(spec_kinds):
         if kind == "dense":
-            x = feats[si]
             shard_slots[si] = (len(operands),)
-            operands.append(x)
-            in_specs.append(row_spec(x.shape[1]))
+            add_rows(feats[si])
         else:
             idx, val = feats[si]
             shard_slots[si] = (len(operands), len(operands) + 1)
-            operands += [idx.astype(jnp.int32), val]
-            in_specs += [row_spec(idx.shape[1]), row_spec(val.shape[1])]
+            add_rows(idx.astype(jnp.int32))
+            add_rows(val)
 
     fe_ops = []
     fe_dims = []
@@ -356,15 +429,15 @@ def fused_score(
         )
         fe_dims.append((spec_kinds[fi], d, kk))
         operands.append(w.reshape(1, d))
-        in_specs.append(pl.BlockSpec((1, d), lambda i, s: (0, 0)))
+        in_specs.append(pl.BlockSpec((1, d), lambda i: (0, 0)))
 
     re_ops = []
     re_dims = []
     wdtype = jnp.dtype(fe_ws[0].dtype) if fe_ws else None
-    for ci, (w, proj, fi) in enumerate(zip(re_ws, re_projs, re_feat)):
+    for w, proj, code, fi in zip(re_ws, re_projs, codes, re_feat):
         sdim = int(w.shape[1])
         re_ops.append(
-            (spec_kinds[fi], shard_slots[fi], len(operands), ci,
+            (spec_kinds[fi], shard_slots[fi], len(operands),
              jnp.dtype(w.dtype))
         )
         wdtype = jnp.dtype(w.dtype)
@@ -374,30 +447,26 @@ def fused_score(
             re_dims.append(
                 ("sparse", 0, int(feats[fi][0].shape[1]), sdim)
             )
-
-        def table_row(i, s, c=ci):
-            # Codes are scalar-prefetched: the DMA for this request's
-            # table row is issued from the index map, before the body.
-            return (jnp.maximum(s[c, i], 0), 0)
-
-        operands.append(w)
-        in_specs.append(pl.BlockSpec((1, sdim), table_row))
-        operands.append(proj.astype(jnp.int32))
-        in_specs.append(pl.BlockSpec((1, sdim), table_row))
+        # The row gather, in XLA (see the module docstring for why it
+        # cannot live in the kernel): jnp.take wraps negative indices
+        # numpy-style, so -1 is clamped and masked explicitly.
+        code = code.astype(jnp.int32)
+        safe = jnp.maximum(code, 0)
+        add_rows(jnp.where(
+            (code >= 0)[:, None], jnp.take(w, safe, axis=0), 0
+        ).astype(w.dtype))
+        add_rows(jnp.take(proj.astype(jnp.int32), safe, axis=0))
 
     _record_site(site, rung, fe_dims, re_dims, wdtype or jnp.float32)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(rung,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1), lambda i, s: (i, 0)),
-    )
     out = pl.pallas_call(
         _make_kernel(tuple(fe_ops), tuple(re_ops)),
-        grid_spec=grid_spec,
+        grid=(pl.cdiv(rung, tile),),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((tile, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rung, 1), jnp.float32),
         interpret=(
             interpret_required() if interpret is None else interpret
         ),
-    )(codes_arr, *operands)
+        name="serve_score",
+    )(*operands)
     return out[:, 0]

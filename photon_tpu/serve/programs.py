@@ -310,14 +310,25 @@ class ScorePrograms:
             self.specs[s].kind for s in self.shard_order
         )
         # Fused-kernel engagement is decided ONCE, at construction (the
-        # PHOTON_SERVE_KERNEL auto/force/off gate + table dtype): the
-        # choice is baked into the traced program, so the AOT ladder,
-        # the zero-recompile contract and values-only reloads behave
-        # identically on both paths — tables stay traced operands.
+        # PHOTON_SERVE_KERNEL auto/force/off gate + table dtype + the
+        # model's static widths): the choice is baked into the traced
+        # program, so the AOT ladder, the zero-recompile contract and
+        # values-only reloads behave identically on both paths — tables
+        # stay traced operands.
         from photon_tpu.ops import serve_kernel as serve_kernel_mod
 
         self.use_kernel = serve_kernel_mod.kernel_supported(
-            str(w0.dtype)
+            str(w0.dtype),
+            fe_dims=tuple(
+                (self.specs[s].kind, tables.fixed[n].num_features,
+                 self.specs[s].k)
+                for n, s in zip(self._fe_names, fe_shards)
+            ),
+            re_dims=tuple(
+                (self.specs[s].kind, self.specs[s].d, self.specs[s].k,
+                 int(tables.random[n].weights.shape[1]))
+                for n, s in zip(self._re_names, re_shards)
+            ),
         )
 
         def score_fn(fe_ws, re_ws, re_projs, feats, codes):

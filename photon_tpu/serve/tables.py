@@ -91,8 +91,7 @@ def _device_swap(old, new_host: np.ndarray):
     resident while the transfer drains. Donation marks ``old`` deleted,
     so this path requires the serving queue QUIESCED (see
     ``CoefficientTables.reload(donate=True)``). CPU backends skip
-    donation (same guard as data/pipeline._concat_chunks: the backend
-    would warn on every call)."""
+    donation (the backend would warn on every call)."""
     import jax
 
     key = (tuple(old.shape), str(old.dtype))
@@ -232,8 +231,12 @@ class CoefficientTables:
             # bf16 table storage (serving mixed precision): half the
             # resident HBM and half the gather width per request; the
             # score kernels accumulate f32 (models/game.py).
-            return jax.device_put(
-                precision_mod.in_storage(jnp.asarray(arr), resolved)
+            # The table OWNS its buffer (copy=True): a device-resident
+            # f32 coefficient array would otherwise be aliased, and a
+            # donating reload (``_device_swap``, which donates only off
+            # the CPU) would delete the caller's model from under it.
+            return precision_mod.in_storage(
+                jnp.array(arr, copy=True), resolved
             )
 
         fixed: dict[str, FixedTable] = {}

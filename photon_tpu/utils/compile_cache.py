@@ -19,9 +19,11 @@ from __future__ import annotations
 import os
 import threading
 
-_DEFAULT_DIR = os.path.join(
-    os.path.expanduser("~"), ".cache", "photon_tpu_xla"
-)
+from photon_tpu import CHECKOUT_ROOT
+
+# The cache path is part of JAX's cache key, so the default must be the
+# same string on every run from one checkout.
+_DEFAULT_DIR = os.path.join(CHECKOUT_ROOT, ".jax_cache")
 
 # Host-concurrency contract (audited by `python -m photon_tpu.analysis
 # --concurrency`). The counters here are written from whatever thread
@@ -81,10 +83,11 @@ def aot_compile(lowered, *, ledger_key: str | None = None):
     fallback jit path's compile becomes a cache hit instead of a second
     full compile. Counted in ``cache_stats()``.
 
-    A RETRIED site (resilience layer): a transient compile failure — a
-    flaky compiler RPC on tunneled backends, the injected
-    ``compile.aot`` fault — re-runs ``lowered.compile()`` with backoff;
-    deterministic compile errors propagate on the first attempt.
+    A RETRIED site (resilience layer): a transient compile failure —
+    an UNAVAILABLE from the runtime, the injected ``compile.aot`` fault
+    — re-runs ``lowered.compile()`` with backoff; deterministic compile
+    errors (a Mosaic refusal, out of HBM) propagate on the first
+    attempt.
 
     ``ledger_key`` names this compile in the cost ledger's compile-time
     account (obs/ledger.py) — callers pass their cache key (the serve
@@ -150,20 +153,20 @@ def _install_listener() -> None:
 
 
 def enable_compilation_cache(cache_dir: str | None = None) -> str | None:
-    """Point JAX's persistent compilation cache at a local directory.
+    """Switch JAX's persistent compilation cache on.
 
-    Resolution order: explicit argument, ``PHOTON_COMPILE_CACHE`` env var,
-    ``~/.cache/photon_tpu_xla``. The value ``off`` (env or argument)
-    disables wiring. Safe to call multiple times; returns the directory in
-    effect (or None when disabled).
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has already read it
+    into its own config: this sets NO directory in code and reports the
+    one JAX holds. Otherwise the cache lives at ``<checkout>/.jax_cache``.
+    The explicit ``cache_dir`` argument (tests) overrides both; the
+    value ``off`` disables wiring. Safe to call multiple times; returns
+    the directory in effect (or None when disabled).
     """
     import jax
 
     global _dir_in_effect
 
-    if cache_dir is None:  # photon: ignore[spmd-host-divergence] -- cache dir is host-local config; changes where artifacts persist, never what is traced
-        cache_dir = os.environ.get("PHOTON_COMPILE_CACHE", _DEFAULT_DIR)
-    if not cache_dir or cache_dir.lower() == "off":  # photon: ignore[spmd-host-divergence] -- cache dir is host-local config; changes where artifacts persist, never what is traced
+    if cache_dir is not None and cache_dir.lower() in ("", "off"):
         # Genuinely disable: a process that enabled the cache earlier
         # must stop persisting/hitting it, or cache_stats() would report
         # dir=None while the counters keep climbing.
@@ -172,10 +175,16 @@ def enable_compilation_cache(cache_dir: str | None = None) -> str | None:
         with _lock:
             _dir_in_effect = None
         return None
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    if cache_dir is None and os.environ.get("JAX_COMPILATION_CACHE_DIR"):  # photon: ignore[spmd-host-divergence] -- cache dir is host-local config; changes where artifacts persist, never what is traced
+        # JAX read the variable into its config at import; report what
+        # it holds and leave it alone.
+        cache_dir = jax.config.jax_compilation_cache_dir
+    else:
+        cache_dir = cache_dir or _DEFAULT_DIR
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     # Cache everything that took meaningful compile time; the default
-    # threshold (1s) would skip many of the small eager-op programs whose
-    # first-compile latency dominates cold starts on remote backends.
+    # threshold (1s) would skip many of the small eager-op programs
+    # that a cold start compiles by the dozen.
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
     # JAX initializes the cache singleton AT MOST ONCE, on the first
     # compile: if anything jitted before this call (an import-time eager
@@ -190,12 +199,11 @@ def enable_compilation_cache(cache_dir: str | None = None) -> str | None:
 
 
 def _reset_cache_singleton() -> None:
-    try:
-        from jax._src import compilation_cache as _cc
+    # Private API: if it moves, fail here rather than run with the cache
+    # silently off (chip_smoke.py proves the cache took: entries > 0).
+    from jax._src import compilation_cache as _cc
 
-        _cc.reset_cache()
-    except Exception:  # pragma: no cover — internal API may move
-        pass
+    _cc.reset_cache()
 
 
 def _dir_stats(cache_dir: str) -> tuple[int, int]:

@@ -315,10 +315,10 @@ def test_costmodel_counts_matmul_flops():
 
 def test_costmodel_roofline_classifies_bounds():
     flops_bound = costmodel.roofline(
-        {"flops": 1e15, "hbm_bytes": 1.0}, chip="tpu_v5e"
+        {"flops": 1e15, "hbm_bytes": 1.0}, chip=costmodel.TARGET_CHIP
     )
     hbm_bound = costmodel.roofline(
-        {"flops": 1.0, "hbm_bytes": 1e13}, chip="tpu_v5e"
+        {"flops": 1.0, "hbm_bytes": 1e13}, chip=costmodel.TARGET_CHIP
     )
     assert flops_bound["bound"] == "flops"
     assert hbm_bound["bound"] == "hbm"
@@ -343,7 +343,7 @@ def test_fuse_ineligibility_reasons_match_fuse_eligible():
     )
     from photon_tpu.parallel.mesh import make_mesh
 
-    with jax.experimental.disable_x64():
+    with jax.enable_x64(False):
         est, data = program._tiny_glmix()
         datasets, _ = est.prepare(data)
         coords = est._build_coordinates(

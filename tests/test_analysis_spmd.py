@@ -104,7 +104,7 @@ class TestCollectiveParsers:
             [{"op": "all-gather", "shape": "f32[128,64]{1,0}"}]
         )
         assert priced["total_bytes"] == 128 * 64 * 4
-        peak = costmodel.CHIP_PEAKS[costmodel.DEFAULT_CHIP][
+        peak = costmodel.CHIP_PEAKS[costmodel.TARGET_CHIP][
             "ici_bytes_per_sec"
         ]
         assert priced["min_seconds_ici"] == pytest.approx(
@@ -486,28 +486,6 @@ class TestContractHygiene:
 
 
 # --------------------------------------------------------------------------
-# the shard_map xfail, statically named
-# --------------------------------------------------------------------------
-
-
-class TestShardMapDiagnosis:
-    def test_divergent_op_is_named(self):
-        """Pins the citation the 6 xfailed column-sharding tests carry:
-        on jax 0.4.37 the column (tensor-parallel) path dies importing
-        ``jax.shard_map`` — the auditor names that op statically. When
-        a jax upgrade makes this pass (ok True), flip the xfails to
-        passing tests and relax this pin."""
-        diag = S.diagnose_shard_map_path()
-        if diag["ok"] is None:
-            pytest.skip(diag["reason"])
-        assert diag["ok"] is False
-        assert diag["stage"] == "trace"
-        assert diag["divergent_op"] == "shard_map"
-        assert "cannot import name 'shard_map'" in diag["reason"]
-        assert "jax.experimental" in diag["hint"]
-
-
-# --------------------------------------------------------------------------
 # the fleet census join + benchtrend gauges (satellite plumbing)
 # --------------------------------------------------------------------------
 
@@ -606,9 +584,6 @@ class TestAuditGate:
         out = capsys.readouterr().out
         assert "contract mesh-spmd" in out
         assert "@ok" in out
-        # The xfail diagnosis surfaces as a note on multi-device runs.
-        if len(jax.devices()) >= 2:
-            assert "divergent op 'shard_map'" in out
 
     def test_cli_arg_validation(self):
         assert cli_main(["--spmd", "photon_tpu"]) == 2

@@ -805,20 +805,35 @@ def test_log_file_sink(tmp_path, glmix_avro, capsys):
     assert "executed in" in text  # Timed sections land in the sink
 
 
-def test_maybe_init_distributed_single_host_noop():
-    """Pins the single-host contract of maybe_init_distributed: with no
-    cluster environment it must be a silent no-op (False), and it must stay
-    a no-op on re-entry after the XLA backend is up. This test is the canary
-    for JAX rewording the internal error messages the handler matches — if
-    it starts failing after a JAX upgrade, update the matchers in
-    photon_tpu/cli/common.py."""
+def test_maybe_init_distributed_single_host_noop(monkeypatch):
+    """A single-process launch never calls jax.distributed.initialize:
+    on a host with TPU chips JAX's cluster auto-detection queries the
+    GCE metadata server, which a sealed machine cannot reach. Only a
+    configured coordinator (JAX_COORDINATOR_ADDRESS) makes the launch
+    multi-host."""
+    import jax
+
     from photon_tpu.cli.common import is_coordinator, maybe_init_distributed
 
-    # The test process has long since initialized the CPU backend
-    # (conftest), which is exactly the programmatic re-entry case.
+    calls = []
+    monkeypatch.setattr(
+        jax.distributed, "initialize", lambda **kw: calls.append(kw))
+    monkeypatch.delenv("JAX_COORDINATOR_ADDRESS", raising=False)
     assert maybe_init_distributed() is False
     assert maybe_init_distributed() is False  # idempotent
+    assert calls == []
     assert is_coordinator() is True
+
+    # Configured: the three variables reach initialize verbatim and
+    # switch JAX's own cluster detection off.
+    monkeypatch.setenv("JAX_COORDINATOR_ADDRESS", "localhost:1234")
+    monkeypatch.setenv("JAX_NUM_PROCESSES", "2")
+    monkeypatch.setenv("JAX_PROCESS_ID", "1")
+    assert maybe_init_distributed() is True
+    assert calls == [dict(
+        coordinator_address="localhost:1234", num_processes=2,
+        process_id=1, cluster_detection_method="deactivate",
+    )]
 
 
 def test_feature_stats_artifact(tmp_path, glmix_avro, capsys):

@@ -23,13 +23,13 @@ from photon_tpu.analysis import program
 
 @pytest.fixture(scope="module")
 def fused_trace():
-    with jax.experimental.disable_x64():
+    with jax.enable_x64(False):
         return program.build_fused_fit()
 
 
 @pytest.fixture(scope="module")
 def unfused_trace():
-    with jax.experimental.disable_x64():
+    with jax.enable_x64(False):
         return program.build_unfused_update()
 
 
@@ -99,7 +99,7 @@ def test_census_checks_pass_on_the_real_contracts(
 
 
 def test_newton_kernel_shape_specialization():
-    with jax.experimental.disable_x64():
+    with jax.enable_x64(False):
         trace = program.build_newton_kernel()
     base = trace.programs["newton_step"].signature
     assert _all_signatures(trace, []) == {base}
@@ -173,3 +173,63 @@ def test_cache_stats_disabled_reports_none_dir():
         assert cache_stats()["dir"] is None
     finally:
         jax.config.update("jax_compilation_cache_dir", prev_dir)
+
+
+def test_cache_dir_comes_from_the_jax_variable(tmp_path, monkeypatch):
+    """Where JAX_COMPILATION_CACHE_DIR is set the program sets NO
+    directory in code (the chip tool places the cache from outside; the
+    path is part of the cache key) and cache_stats() reports it."""
+    from photon_tpu.utils import compile_cache
+
+    prev_dir = jax.config.jax_compilation_cache_dir
+    prev_min = jax.config.jax_persistent_cache_min_compile_time_secs
+    outside = str(tmp_path / "outside")
+    try:
+        # What JAX does at import when the variable is set.
+        jax.config.update("jax_compilation_cache_dir", outside)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
+        # The retired override must not be read any more.
+        monkeypatch.setenv("PHOTON_COMPILE_CACHE", str(tmp_path / "old"))
+        updates = []
+        real_update = jax.config.update
+        monkeypatch.setattr(
+            jax.config, "update",
+            lambda k, v: (updates.append(k), real_update(k, v))[1],
+        )
+        assert compile_cache.enable_compilation_cache() == outside
+        assert "jax_compilation_cache_dir" not in updates
+        assert jax.config.jax_compilation_cache_dir == outside
+        assert compile_cache.cache_stats()["dir"] == outside
+    finally:
+        monkeypatch.undo()
+        compile_cache.enable_compilation_cache("off")
+        jax.config.update("jax_compilation_cache_dir", prev_dir)
+        jax.config.update(
+            "jax_persistent_cache_min_compile_time_secs", prev_min
+        )
+
+
+def test_cache_dir_defaults_to_the_checkout(monkeypatch):
+    """Variable unset: <checkout>/.jax_cache, the same string on every
+    call (never a temporary name, a pid or a time), whatever
+    PHOTON_COMPILE_CACHE says."""
+    import pathlib
+
+    from photon_tpu.utils import compile_cache
+
+    prev_dir = jax.config.jax_compilation_cache_dir
+    prev_min = jax.config.jax_persistent_cache_min_compile_time_secs
+    want = str(pathlib.Path(__file__).resolve().parents[1] / ".jax_cache")
+    try:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.setenv("PHOTON_COMPILE_CACHE", "/nonexistent/old")
+        assert compile_cache.enable_compilation_cache() == want
+        assert compile_cache.enable_compilation_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+        assert compile_cache.cache_stats()["dir"] == want
+    finally:
+        compile_cache.enable_compilation_cache("off")
+        jax.config.update("jax_compilation_cache_dir", prev_dir)
+        jax.config.update(
+            "jax_persistent_cache_min_compile_time_secs", prev_min
+        )

@@ -180,19 +180,6 @@ class TestColumnFeatureSharding:
             mesh=mesh,
         )
 
-    # Quarantined, not hidden: the installed jax 0.4.37 has no
-    # top-level `from jax import shard_map` (parallel/mesh.py
-    # FeatureShardedSparse.matvec), failing since the seed. strict=False
-    # keeps tier-1 signal clean now AND starts passing silently the day
-    # the import gains a version guard — at which point drop these marks.
-    @pytest.mark.xfail(
-        strict=False, reason=(
-            "diagnosed by the tier-6 SPMD auditor: divergent op "
-            "'shard_map' at stage trace (jax 0.4.37 has no "
-            "jax.shard_map; see analysis.spmd.diagnose_shard_map_path, "
-            "pinned in tests/test_analysis_spmd.py)"
-        )
-    )
     def test_column_sharded_parity(self, rng):
         """Sharded-vs-unsharded coefficient parity for the wide solve —
         the tp analog of test_fit_parity_sharded_vs_single_device."""
@@ -223,14 +210,6 @@ class TestColumnFeatureSharding:
             rtol=1e-7,
         )
 
-    @pytest.mark.xfail(
-        strict=False, reason=(
-            "diagnosed by the tier-6 SPMD auditor: divergent op "
-            "'shard_map' at stage trace (jax 0.4.37 has no "
-            "jax.shard_map; see analysis.spmd.diagnose_shard_map_path, "
-            "pinned in tests/test_analysis_spmd.py)"
-        )
-    )
     def test_column_sharded_with_random_effect(self, rng):
         """tp fixed effect + ep random effect chained by residual routing."""
         game = self._wide_game(rng)
@@ -272,14 +251,6 @@ class TestColumnFeatureSharding:
         assert not isinstance(
             datasets["global"].features, FeatureShardedSparse)
 
-    @pytest.mark.xfail(
-        strict=False, reason=(
-            "diagnosed by the tier-6 SPMD auditor: divergent op "
-            "'shard_map' at stage trace (jax 0.4.37 has no "
-            "jax.shard_map; see analysis.spmd.diagnose_shard_map_path, "
-            "pinned in tests/test_analysis_spmd.py)"
-        )
-    )
     def test_column_warm_start_across_configs(self, rng):
         """Lambda-ladder warm starts pad the trimmed model back into the
         sharded solve space."""
@@ -297,14 +268,6 @@ class TestColumnFeatureSharding:
         assert results[1].model["global"].model.coefficients.means.shape == (
             77,)
 
-    @pytest.mark.xfail(
-        strict=False, reason=(
-            "diagnosed by the tier-6 SPMD auditor: divergent op "
-            "'shard_map' at stage trace (jax 0.4.37 has no "
-            "jax.shard_map; see analysis.spmd.diagnose_shard_map_path, "
-            "pinned in tests/test_analysis_spmd.py)"
-        )
-    )
     def test_column_incremental_training(self, rng):
         """The Gaussian prior from a trimmed (logical-d) model must pad into
         the column-sharded solve space, parity with the replicated path."""

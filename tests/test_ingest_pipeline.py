@@ -320,6 +320,98 @@ def test_aot_warm_compile_first_fit_identical_to_serial():
     assert 0.0 <= report["compile_overlap_fraction"] <= 1.0
 
 
+def _two_random_effect_pair(seed=5):
+    """Two random-effect coordinates: their plan arrays share ONE packed
+    buffer (GameEstimator._resolve_pending), the second coordinate's at
+    a non-zero offset."""
+    from photon_tpu.estimators.game_estimator import (
+        GameEstimator,
+        FixedEffectCoordinateConfiguration,
+        RandomEffectCoordinateConfiguration,
+    )
+    from photon_tpu.types import TaskType
+
+    rng = np.random.default_rng(seed)
+    n, d = 400, 4
+    x = rng.normal(size=(n, d)).astype(np.float32)
+    x[:, -1] = 1.0
+    users = rng.integers(0, 11, size=n)
+    items = rng.integers(0, 7, size=n)
+    y = (
+        x @ rng.normal(size=d) + rng.normal(size=11)[users]
+        + rng.normal(size=7)[items]
+    ).astype(np.float32)
+    data = make_game_dataset(
+        y, {"features": DenseFeatures(x)},
+        id_tags={"userId": users, "itemId": items},
+    )
+    est = GameEstimator(
+        TaskType.LINEAR_REGRESSION,
+        {
+            "global": FixedEffectCoordinateConfiguration("features"),
+            "per-user": RandomEffectCoordinateConfiguration(
+                RandomEffectDataConfiguration("userId", "features")),
+            "per-item": RandomEffectCoordinateConfiguration(
+                RandomEffectDataConfiguration("itemId", "features")),
+        },
+        intercept_indices={"features": d - 1},
+        num_iterations=2,
+        mesh="off",
+    )
+    return est, data
+
+
+def test_aot_warm_compile_with_two_random_effects_matches_serial():
+    """The warm compile's skeletons must model the SHARED packed buffer:
+    the materialize program slices it at static offsets, and below one
+    transfer granule a standalone skeleton's buffer has the same aval —
+    the executable was accepted and the second coordinate read the
+    first one's plan arrays (its entities came back untrained)."""
+    with ingest_mode(serial=True):
+        est_s, data_s = _two_random_effect_pair()
+        want = _model_tables(est_s.fit(data_s)[0])
+    with ingest_mode(serial=False):
+        est_p, data_p = _two_random_effect_pair()
+        got = _model_tables(est_p.fit(data_p)[0])
+        fused = next(reversed(est_p._fused_cache.values()))
+    assert fused._aot is not None, "warm-compile artifacts were not used"
+    assert fused._aot["layout"] == fused.packed_layout()
+    offsets = [sl[0][0] for sl in fused.packed_layout().values()]
+    assert offsets[0] == 0 and offsets[1] > 0
+    assert want["per-item"].any(axis=1).all()
+    for cid in want:
+        np.testing.assert_array_equal(want[cid], got[cid], cid)
+
+
+def test_aot_materialize_for_another_layout_is_not_dispatched():
+    """Slice offsets are static, not part of any operand's aval: the
+    layout is compared explicitly, and an executable compiled for
+    another one is dropped for the jit path."""
+    with ingest_mode(serial=True):
+        est, data = _two_random_effect_pair()
+        want = _model_tables(est.fit(data)[0])
+    with ingest_mode(serial=False):
+        est2, data2 = _two_random_effect_pair()
+        est2.prepare(data2)
+        art = est2._aot_future.result()
+        shifted = {
+            cid: tuple((off + 1, shape) for off, shape in slices)
+            for cid, slices in art["layout"].items()
+        }
+
+        def refuse(mat_ops):
+            raise AssertionError("dispatched for another layout")
+
+        from concurrent.futures import Future
+
+        fut = Future()
+        fut.set_result({**art, "layout": shifted, "mat": refuse})
+        est2._aot_future = fut
+        got = _model_tables(est2.fit(data2)[0])
+    for cid in want:
+        np.testing.assert_array_equal(want[cid], got[cid], cid)
+
+
 def test_stale_shape_prediction_falls_back_to_jit():
     """Exact zeros in a dense shard break the oracle's fully-dense
     assumption: the warm-compiled executable must be discarded and the

@@ -22,6 +22,11 @@ from photon_tpu import obs
 from photon_tpu.analysis import costmodel
 from photon_tpu.obs import ledger
 
+# These tests price synthetic seconds on the CPU: they name the abstract
+# tiers' target part. A measuring path asks the device instead — and a
+# device without a peaks row is an error (test below).
+CHIP = costmodel.TARGET_CHIP
+
 
 @pytest.fixture
 def armed():
@@ -177,14 +182,14 @@ class TestAttribution:
 
 class TestReport:
     def test_roofline_join_and_wasted_seconds(self, armed):
-        peaks = costmodel.CHIP_PEAKS[costmodel.DEFAULT_CHIP]
+        peaks = costmodel.CHIP_PEAKS[costmodel.TARGET_CHIP]
         # One dispatch bound by HBM: 819 GB at peak = 1s lower bound.
         ledger.register_program(
             "p", phase="fit",
             cost={"flops": 1.0, "hbm_bytes": peaks["hbm_bytes_per_sec"]},
         )
         ledger.record_dispatch("p", 3.0, phase="fit")
-        row = ledger.report()["rows"][0]
+        row = ledger.report(CHIP)["rows"][0]
         assert row["roofline_bound"] == "hbm"
         assert row["vs_roofline"] == pytest.approx(3.0)
         assert row["wasted_seconds"] == pytest.approx(2.0)
@@ -193,13 +198,13 @@ class TestReport:
             peaks["hbm_bytes_per_sec"] / 3.0)
 
     def test_compute_bound_blocking(self, armed):
-        peaks = costmodel.CHIP_PEAKS[costmodel.DEFAULT_CHIP]
+        peaks = costmodel.CHIP_PEAKS[costmodel.TARGET_CHIP]
         ledger.register_program(
             "p", phase="fit",
             cost={"flops": peaks["flops_per_sec"], "hbm_bytes": 1.0},
         )
         ledger.record_dispatch("p", 2.0, phase="fit")
-        row = ledger.report()["rows"][0]
+        row = ledger.report(CHIP)["rows"][0]
         assert row["roofline_bound"] == "flops"
         assert row["blocking"] == "compute"
 
@@ -211,7 +216,7 @@ class TestReport:
         ledger.record_dispatch("p", 0.001, phase="serve",
                                start=20.0, end=20.001)
         row = [
-            r for r in ledger.report()["rows"] if r["dispatches"] == 2
+            r for r in ledger.report(CHIP)["rows"] if r["dispatches"] == 2
         ][0]
         assert row["host_gap_seconds"] == pytest.approx(9.999)
         assert row["blocking"] == "dispatch-gap"
@@ -222,7 +227,7 @@ class TestReport:
         # against its SHARE of the program's cost — pricing every row
         # against the whole program would double-count FLOPs across
         # rows and understate every per-coordinate vs_roofline.
-        peaks = costmodel.CHIP_PEAKS[costmodel.DEFAULT_CHIP]
+        peaks = costmodel.CHIP_PEAKS[costmodel.TARGET_CHIP]
         ledger.register_program(
             "fit", phase="fit",
             cost={"flops": 1.0, "hbm_bytes": peaks["hbm_bytes_per_sec"]},
@@ -233,7 +238,7 @@ class TestReport:
         )
         rows = {
             r["coordinate"]: r
-            for r in ledger.report()["rows"]
+            for r in ledger.report(CHIP)["rows"]
             if r["dispatches"] > 0
         }
         # Both rows ran the SAME program at the same rate: identical
@@ -251,7 +256,7 @@ class TestReport:
 
     def test_costless_program_degrades_to_measured_only(self, armed):
         ledger.record_dispatch("transfer", 0.5, phase="ingest")
-        row = ledger.report()["rows"][0]
+        row = ledger.report(CHIP)["rows"][0]
         assert row["vs_roofline"] is None
         assert row["achieved_flops_per_sec"] is None
         assert row["blocking"] == "measured-only"
@@ -266,7 +271,7 @@ class TestReport:
             cost={"flops": 0.0, "hbm_bytes": 0.0},
         )
         ledger.record_dispatch("xfer", 0.25, phase="ingest")
-        row = ledger.report()["rows"][0]
+        row = ledger.report(CHIP)["rows"][0]
         assert row["vs_roofline"] is None
         assert row["blocking"] == "measured-only"
 
@@ -279,8 +284,8 @@ class TestReport:
 
         ledger.register_program("p", phase="fit", cost_thunk=boom)
         ledger.record_dispatch("p", 0.5, phase="fit")
-        row1 = ledger.report()["rows"][0]
-        row2 = ledger.report()["rows"][0]
+        row1 = ledger.report(CHIP)["rows"][0]
+        row2 = ledger.report(CHIP)["rows"][0]
         assert row1["blocking"] == "measured-only"
         assert "no cost analysis" in row1["cost_error"]
         assert row2["cost_error"] == row1["cost_error"]
@@ -290,13 +295,21 @@ class TestReport:
         ledger.record_dispatch("slow", 2.0, phase="fit")
         ledger.record_dispatch("fast", 0.1, phase="fit")
         ledger.record_unattributed(9.0)
-        rows = ledger.top_k(5)
+        rows = ledger.top_k(5, CHIP)
         assert [r["program"] for r in rows] == ["slow", "fast"]
-        assert "slow" in ledger.render_top_k(1)
-        assert "fast" not in ledger.render_top_k(1)
+        assert "slow" in ledger.render_top_k(1, CHIP)
+        assert "fast" not in ledger.render_top_k(1, CHIP)
+
+    def test_measuring_path_refuses_a_device_without_peaks(self, armed):
+        # The default chip is the device the seconds were measured on;
+        # the CPU has no peaks row, and that is an error, not a v5e
+        # default.
+        ledger.record_dispatch("p", 1.0, phase="fit")
+        with pytest.raises(LookupError, match="no peaks for device kind"):
+            ledger.report()
 
     def test_render_empty(self, armed):
-        assert "no dispatches" in ledger.render_top_k()
+        assert "no dispatches" in ledger.render_top_k(chip=CHIP)
 
 
 # -------------------------------------------------------------------------
@@ -415,7 +428,7 @@ class TestThreadSafety:
         assert snap["compiles"]["fused_fit/fit"]["count"] == n
         assert len(snap["programs"]) == 7
         # Reports render consistently after the hammer too.
-        assert ledger.report()["rows"]
+        assert ledger.report(CHIP)["rows"]
 
 
 # -------------------------------------------------------------------------
@@ -589,7 +602,7 @@ class TestEndToEnd:
         assert out["attributed_fraction"] is not None
         # The priced report joins the REAL lowered costs (the thunks
         # re-lower here) without error.
-        top = ledger.top_k(3)
+        top = ledger.top_k(3, CHIP)
         assert top and all("blocking" in r for r in top)
 
     def test_ledger_off_fit_registers_zero_programs(self):
@@ -614,7 +627,7 @@ class TestEndToEnd:
         out = tmp_path / "profile.json"
         rc = profile.main([
             "--rows", "128", "--entities", "6", "--fits", "2",
-            "--json", str(out),
+            "--json", str(out), "--chip", CHIP,
         ])
         assert rc == 0
         doc = json.loads(out.read_text())

@@ -280,7 +280,7 @@ def tiny_glmix_fit():
     obs.reset()
     obs.enable()
     try:
-        with jax.experimental.disable_x64():
+        with jax.enable_x64(False):
             est, data = program._tiny_glmix()
             est.prepare(data)
             result = est.fit(data)[0]
@@ -344,7 +344,7 @@ def test_fused_cold_jit_window_is_not_attributed(telemetry, monkeypatch):
     from photon_tpu.analysis import program
 
     monkeypatch.setenv("PHOTON_TPU_SERIAL_INGEST", "1")
-    with jax.experimental.disable_x64():
+    with jax.enable_x64(False):
         est, data = program._tiny_glmix()
         est.prepare(data)
         cold = est.fit(data)[0]
@@ -376,7 +376,7 @@ def test_fused_retried_dispatch_window_is_not_attributed(
 
     monkeypatch.setenv("PHOTON_TPU_SERIAL_INGEST", "1")
     try:
-        with jax.experimental.disable_x64():
+        with jax.enable_x64(False):
             est, data = program._tiny_glmix()
             est.prepare(data)
             est.fit(data)  # warm the jit path: statics enter _jit_seen
@@ -395,7 +395,7 @@ def test_fused_fit_telemetry_off_keeps_seconds_none(telemetry_off):
 
     from photon_tpu.analysis import program
 
-    with jax.experimental.disable_x64():
+    with jax.enable_x64(False):
         est, data = program._tiny_glmix()
         est.prepare(data)
         result = est.fit(data)[0]
@@ -488,7 +488,7 @@ def test_telemetry_contract_zero_overhead():
 
     from photon_tpu.analysis import program
 
-    with jax.experimental.disable_x64():
+    with jax.enable_x64(False):
         trace = program.build_telemetry()
     base = {name: p.signature for name, p in trace.programs.items()}
     assert set(base) == {"materialize", "fit"}

@@ -178,3 +178,30 @@ def test_fixed_effect_on_2d_mesh(rng, mesh):
         np.asarray(m_local.coefficients.means),
         rtol=1e-8, atol=1e-10,
     )
+
+
+def test_dryrun_multichip_refuses_devices_that_are_not_there(monkeypatch):
+    """``dryrun_multichip(n)`` with fewer than n visible devices used
+    to re-execute on virtual CPU devices and return success — which is
+    how multi-chip records said ok for runs no chip saw. It raises now;
+    the virtual-CPU provisioning is the separately named
+    ``dryrun_on_virtual_cpu`` (CI's sharding check), which it never
+    falls back to."""
+    import sys
+    from pathlib import Path
+
+    import jax
+    import pytest
+
+    root = str(Path(__file__).resolve().parents[1])
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    import __graft_entry__ as graft
+
+    def no_fallback(n):
+        raise AssertionError("fell back to virtual CPU devices")
+
+    monkeypatch.setattr(graft, "dryrun_on_virtual_cpu", no_fallback)
+    wanted = len(jax.devices()) + 1
+    with pytest.raises(RuntimeError, match=f"needs {wanted} devices"):
+        graft.dryrun_multichip(wanted)

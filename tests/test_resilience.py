@@ -425,8 +425,9 @@ class TestInjectionSites:
         """A real backend fault (gRPC UNAVAILABLE) raised by the AOT
         fit executable must reach the retry wrapper — the stale-shape
         fallback must not swallow it, drop a perfectly good executable,
-        and record zero retries for a real fault. Only a NON-transient
-        error means the prediction was stale."""
+        and record zero retries for a real fault. Only the TypeError a
+        compiled executable raises for other avals means the prediction
+        was stale."""
         from photon_tpu.algorithm import fused_fit as ff
 
         calls = {"n": 0}
@@ -442,10 +443,10 @@ class TestInjectionSites:
             calls["n"] += 1
             if calls["n"] == 1:
                 raise RuntimeError("UNAVAILABLE: socket closed")
-            raise ValueError("genuinely stale prediction")
+            raise TypeError("genuinely stale prediction")
 
         def fake_mat(mat_ops):
-            raise ValueError("no AOT mat")  # falls back to jit mat
+            raise TypeError("no AOT mat")  # falls back to jit mat
 
         fake = {
             "statics": _AnyStatics(), "fit": fake_fit, "mat": fake_mat
@@ -457,9 +458,34 @@ class TestInjectionSites:
         assert len(results) == 1
         # attempt 1 re-raised the transient (executable retained);
         # attempt 2 re-entered the SAME executable, whose stale-shape
-        # ValueError then fell back to jit and succeeded.
+        # TypeError then fell back to jit and succeeded.
         assert calls["n"] >= 2
         assert retry_stats()["recovered"] >= 1
+
+    def test_device_failure_in_aot_fit_is_not_relabelled_stale(
+        self, rng, monkeypatch
+    ):
+        """A deterministic failure of the AOT executable that is not a
+        signature mismatch (a Mosaic fault, out of HBM) surfaces with
+        its own message instead of being logged as a stale prediction
+        and recompiled through the jit path."""
+        from photon_tpu.algorithm import fused_fit as ff
+
+        def fake_fit(ops, ebs_all):
+            raise RuntimeError("RESOURCE_EXHAUSTED: out of HBM")
+
+        class _AnyStatics:
+            def __eq__(self, other):
+                return True
+
+        fake = {"statics": _AnyStatics(), "fit": fake_fit,
+                "mat": lambda mat_ops: (_ for _ in ()).throw(
+                    TypeError("no AOT mat"))}
+        monkeypatch.setattr(
+            ff.FusedFit, "_consume_aot", lambda self: fake
+        )
+        with pytest.raises(RuntimeError, match="out of HBM"):
+            _estimator(num_iterations=1).fit(_glmix_data(rng))
 
     def test_poison_planner_thunk_propagates(self, rng):
         data = _glmix_data(rng)

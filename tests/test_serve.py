@@ -146,6 +146,26 @@ class TestTables:
         assert tables.codes_for({"userId": "4"}) == {"per-user": 4}
         assert tables.codes_for({}) == {"per-user": -1}
 
+    def test_tables_own_their_buffers(self, rng):
+        """A donating reload (``_device_swap``; donation engages only
+        off the CPU) deletes the table's old buffer. The table must not
+        alias the caller's model arrays, or the model dies with it."""
+        model = _glmix_model(rng)
+        tables = CoefficientTables.from_game_model(model)
+        pairs = [
+            (tables.fixed["global"].weights,
+             model["global"].model.coefficients.means),
+            (tables.random["per-user"].weights,
+             model["per-user"].coefficients),
+        ]
+        for table_arr, model_arr in pairs:
+            np.testing.assert_array_equal(
+                np.asarray(table_arr), np.asarray(model_arr))
+            assert (
+                table_arr.unsafe_buffer_pointer()
+                != model_arr.unsafe_buffer_pointer()
+            )
+
     def test_single_request_matches_manual_math(self, rng):
         model = _glmix_model(rng)
         tables = CoefficientTables.from_game_model(model)

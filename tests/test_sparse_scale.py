@@ -145,18 +145,6 @@ class TestScoreTableWidthCap:
 
 
 class TestFeatureAxisSharding:
-    # Quarantined, not hidden: jax 0.4.37 lacks top-level
-    # `from jax import shard_map` (parallel/mesh.py), failing since the
-    # seed. strict=False keeps tier-1 signal clean without masking the
-    # day a version-guarded import fixes these — then drop the marks.
-    @pytest.mark.xfail(
-        strict=False, reason=(
-            "diagnosed by the tier-6 SPMD auditor: divergent op "
-            "'shard_map' at stage trace (jax 0.4.37 has no "
-            "jax.shard_map; see analysis.spmd.diagnose_shard_map_path, "
-            "pinned in tests/test_analysis_spmd.py)"
-        )
-    )
     def test_sharded_matvecs_match_local(self, rng, devices):
         n, d = 64, 97  # deliberately not divisible by 8
         idx, val = _random_ell(rng, n, d, k_max=6)
@@ -183,14 +171,6 @@ class TestFeatureAxisSharding:
         # Padded feature range receives nothing.
         assert np.all(np.asarray(sharded.rmatvec(g))[d:] == 0.0)
 
-    @pytest.mark.xfail(
-        strict=False, reason=(
-            "diagnosed by the tier-6 SPMD auditor: divergent op "
-            "'shard_map' at stage trace (jax 0.4.37 has no "
-            "jax.shard_map; see analysis.spmd.diagnose_shard_map_path, "
-            "pinned in tests/test_analysis_spmd.py)"
-        )
-    )
     def test_million_feature_fit_over_mesh(self, rng, devices):
         """The SURVEY §7.3 bar: a fixed-effect fit at d >= 1M sparse
         features, coefficients sharded over the mesh, matching the
