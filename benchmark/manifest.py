@@ -1,0 +1,99 @@
+"""``BENCHMARK.json`` and the files it names, found by name.
+
+A cell, a configuration, a traffic mix, a traffic kind, a per-layer
+metric and a cell's limits each sit in a file of their own, so a later PR
+adds a file and an entry and edits nothing here.
+"""
+
+from __future__ import annotations
+
+import importlib.util
+import json
+import os
+import re
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _read_json(path: str):
+    with open(path) as f:
+        return json.load(f)
+
+
+def _load_module(path: str, tag: str):
+    """The Python file at ``path`` as a module of its own (a name of the
+    benchmark may hold dots and dashes, so it is no import name)."""
+    spec = importlib.util.spec_from_file_location(
+        tag + re.sub(r"\W", "_", os.path.basename(path)[:-3]), path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+class Manifest:
+    def __init__(self, root: str = REPO_ROOT):
+        self.root = root
+        self.bench_dir = os.path.join(root, "benchmark")
+        self.doc = _read_json(os.path.join(root, "BENCHMARK.json"))
+
+    def cell(self, name: str) -> dict:
+        for w in self.doc["workloads"]:
+            if w["name"] == name:
+                return w
+        raise KeyError(
+            f"no workload {name!r} in BENCHMARK.json "
+            f"(has {[w['name'] for w in self.doc['workloads']]})")
+
+    def config(self, name: str) -> dict:
+        for c in self.doc["configs"]:
+            if c["name"] == name:
+                return _read_json(os.path.join(self.root, c["file"]))
+        raise KeyError(f"no configuration {name!r} in BENCHMARK.json")
+
+    def traffic_path(self, name: str) -> str:
+        return os.path.join(self.bench_dir, "traffic", name + ".json")
+
+    def traffic(self, name: str) -> dict:
+        return _read_json(self.traffic_path(name))
+
+    def kind_path(self, name: str) -> str:
+        return os.path.join(self.bench_dir, "kinds", name + ".py")
+
+    def kind(self, name: str):
+        """The ``Kind`` class of ``benchmark/kinds/<name>.py``."""
+        return _load_module(self.kind_path(name), "benchmark_kind_").Kind
+
+    def limits_path(self, cell_name: str) -> str:
+        return os.path.join(self.bench_dir, "limits", cell_name + ".json")
+
+    def limits(self, cell_name: str) -> dict:
+        return _read_json(self.limits_path(cell_name))["limits"]
+
+    def metric_path(self, name: str) -> str:
+        return os.path.join(self.bench_dir, "metrics", name + ".py")
+
+    def metric_reader(self, name: str):
+        """The ``read(ctx)`` function of ``benchmark/metrics/<name>.py``."""
+        return _load_module(
+            self.metric_path(name), "benchmark_metric_").read
+
+    def _reported_in(self, metric: dict, cell_name: str) -> bool:
+        cells = metric.get("workloads")
+        return cells is None or cell_name in cells
+
+    def end_to_end(self, cell_name: str) -> list[dict]:
+        return [m for m in self.doc["end_to_end"]
+                if self._reported_in(m, cell_name)]
+
+    def per_layer(self, cell_name: str) -> list[dict]:
+        """Per-layer metrics of a cell: those that list it, and those with
+        no list whose ``moves`` metric the cell reports."""
+        reported = {m["name"] for m in self.end_to_end(cell_name)}
+        out = []
+        for m in self.doc["per_layer"]:
+            if "workloads" in m:
+                if cell_name in m["workloads"]:
+                    out.append(m)
+            elif m["moves"] in reported:
+                out.append(m)
+        return out

@@ -1,0 +1,6 @@
+"""1 - union of the device-operation intervals over the traced window
+(the first trace_units whole jobs), in percent."""
+
+
+def read(ctx):
+    return ctx.idle_share_pct()

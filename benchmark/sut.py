@@ -1,0 +1,179 @@
+"""The system under test: the one module that imports ``photon_tpu``.
+
+Everything the harness asks of the program goes through here: the
+estimator a configuration file describes, the data set, one blocking fit,
+save and load of a model, and the program's own counters.
+"""
+
+from __future__ import annotations
+
+import numpy as np
+
+
+def configure(config: dict) -> str:
+    """Process-wide settings of a configuration, before anything is
+    traced: JAX's persistent compile cache at ``<checkout>/.jax_cache``
+    (or where ``JAX_COMPILATION_CACHE_DIR`` says; the program's own rule),
+    and the matmul precision where the configuration states one. On a TPU
+    a float32 product runs in one bf16 pass unless JAX's
+    ``jax_default_matmul_precision`` says otherwise; it is the only option
+    the program has to compute in the float32 it states."""
+    import jax
+
+    from photon_tpu.utils import enable_compilation_cache
+
+    if config.get("matmul_precision"):
+        jax.config.update(
+            "jax_default_matmul_precision", config["matmul_precision"])
+    return enable_compilation_cache()
+
+
+def build_estimator(config: dict, precision: str | None = None):
+    """The GameEstimator ``config`` states. ``precision`` overrides the
+    configuration's only for the low-precision control of the check."""
+    from photon_tpu import optim
+    from photon_tpu.algorithm.problems import GLMOptimizationConfiguration
+    from photon_tpu.data.random_effect import RandomEffectDataConfiguration
+    from photon_tpu.estimators.game_estimator import (
+        FixedEffectCoordinateConfiguration,
+        GameEstimator,
+        RandomEffectCoordinateConfiguration,
+    )
+    from photon_tpu.types import TaskType
+
+    def l2(weight):
+        return GLMOptimizationConfiguration(
+            regularization=optim.RegularizationContext(
+                optim.RegularizationType.L2),
+            regularization_weight=float(weight),
+        )
+
+    coords, intercepts = {}, {}
+    for c in config["coordinates"]:
+        intercepts[c["shard"]] = int(c["features"]) - 1
+        if c["kind"] == "fixed":
+            coords[c["name"]] = FixedEffectCoordinateConfiguration(
+                c["shard"], l2(c["l2"]))
+        else:
+            coords[c["name"]] = RandomEffectCoordinateConfiguration(
+                RandomEffectDataConfiguration(
+                    c["id"], c["shard"],
+                    active_data_upper_bound=c["active_data_upper_bound"],
+                    min_bucket_entities=int(c["min_bucket_entities"]),
+                ),
+                l2(c["l2"]),
+            )
+    return GameEstimator(
+        TaskType(config["task"]),
+        coords,
+        intercept_indices=intercepts,
+        num_iterations=int(config["num_iterations"]),
+        mesh=config["mesh"],
+        precision=precision or config["precision"],
+    )
+
+
+def build_dataset(data):
+    """Host arrays -> GameDataset, raw shards resident on the device."""
+    import jax
+
+    from photon_tpu.data.dataset import DenseFeatures
+    from photon_tpu.data.game_data import make_game_dataset
+
+    ds = make_game_dataset(
+        data.labels,
+        {name: DenseFeatures(x) for name, x in data.features.items()},
+        id_tags=dict(data.ids),
+    )
+    jax.block_until_ready(
+        [f.x for f in ds.feature_shards.values()] + [ds.labels])
+    return ds
+
+
+def coefficient_arrays(model) -> dict:
+    """coordinate name -> device coefficient table."""
+    return {
+        name: (m.coefficients if hasattr(m, "coefficients")
+               else m.model.coefficients.means)
+        for name, m in model.models.items()
+    }
+
+
+def fit_blocking(est, dataset):
+    """One whole fit, ended by ``block_until_ready`` on every table."""
+    import jax
+
+    result = est.fit(dataset)[0]
+    jax.block_until_ready(list(coefficient_arrays(result.model).values()))
+    return result
+
+
+def model_tables(model, config: dict) -> dict:
+    """A model as host float32 tables in the DATA's order: coordinate
+    name -> [d] for the fixed effect, [entities, d] for a random effect
+    with row e the entity whose id is e and column j feature j. A model
+    keeps its own entity order and per-entity projectors (a loaded one
+    always does); this undoes both."""
+    out = {}
+    for c in config["coordinates"]:
+        m = model.models[c["name"]]
+        if c["kind"] == "fixed":
+            out[c["name"]] = np.asarray(
+                m.model.coefficients.means, np.float32)
+            continue
+        table = np.zeros((int(c["entities"]), int(c["features"])),
+                         np.float32)
+        coefs = np.asarray(m.coefficients, np.float32)
+        proj = np.asarray(m.proj_all)
+        keys = np.asarray([int(k) for k in m.entity_keys])
+        rows, slots = np.nonzero(proj >= 0)
+        table[keys[rows], proj[rows, slots]] = coefs[rows, slots]
+        out[c["name"]] = table
+    return out
+
+
+def _index_maps(config: dict) -> dict:
+    from photon_tpu.data.index_map import IndexMap
+
+    return {c["shard"]: IndexMap.identity(int(c["features"]))
+            for c in config["coordinates"]}
+
+
+def save_model(model, config: dict, path: str) -> None:
+    from photon_tpu.io.model_io import save_game_model
+
+    save_game_model(model, path, _index_maps(config))
+
+
+def load_model_tables(config: dict, path: str) -> dict:
+    """The saved model read back, as ``model_tables`` gives it."""
+    from photon_tpu.io.model_io import load_game_model
+
+    model, _ = load_game_model(path, _index_maps(config))
+    return model_tables(model, config)
+
+
+def plan_shapes(datasets) -> dict:
+    """coordinate -> [(entities, row cap), ...] of its bucket slabs."""
+    out = {}
+    for name, ds in datasets.items():
+        blocks = getattr(ds, "blocks", None)
+        if blocks is not None:
+            out[name] = [tuple(int(v) for v in b.row_ids.shape)
+                         for b in blocks]
+    return out
+
+
+def compile_counters() -> dict:
+    from photon_tpu.utils import cache_stats
+
+    s = cache_stats()
+    return {"hits": s["persistent_hits"], "misses": s["persistent_misses"],
+            "dir": s["dir"]}
+
+
+def pipeline_report() -> dict:
+    from photon_tpu.data.pipeline import PIPELINE_STATS
+
+    return PIPELINE_STATS.report()
+
