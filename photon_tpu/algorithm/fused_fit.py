@@ -488,7 +488,16 @@ class FusedFit:
         Per RE coordinate: slice the packed ingest buffer into the plan
         arrays (static offsets — free in-trace), rebuild the BlockPlans,
         gather the [B, R, S] slabs, and emit (EntityBlocks, scoring plan
-        arrays, projector table) — everything later fits consume."""
+        arrays, projector table) — everything later fits consume. Each
+        coordinate's operations carry the scope ``coord.<cid>/materialize``
+        (metadata only)."""
+        out = {}
+        for cid, op in mat_ops.items():
+            with jax.named_scope(f"coord.{cid}/materialize"):
+                out[cid] = self._mat_one(cid, op)
+        return out
+
+    def _mat_one(self, cid: str, op: dict) -> dict:
         from photon_tpu.data.random_effect import (
             PLAN_ARRAYS_PER_BUCKET as _PPB,
             BlockPlan,
@@ -497,70 +506,67 @@ class FusedFit:
             packed_score_inv_index,
         )
 
-        out = {}
-        for cid, op in mat_ops.items():
-            meta = self._re_meta[cid]
-            if "buf" in op:
-                arrays = []
-                for off, shape in meta["slices"]:
-                    n = int(np.prod(shape)) if shape else 1
-                    arrays.append(
-                        jax.lax.slice_in_dim(
-                            op["buf"], off, off + n).reshape(shape)
-                    )
-                plans = [
-                    BlockPlan(
-                        entity_codes=arrays[_PPB * i],
-                        row_ids=arrays[_PPB * i + 1],
-                        row_counts=arrays[_PPB * i + 2],
-                        proj=arrays[_PPB * i + 3],
-                        intercept_slots=arrays[_PPB * i + 4],
-                        raw=op["raw"],
-                        raw_labels=op["labels"],
-                        raw_offsets=op["offsets"],
-                        raw_weights=op["weights"],
-                    )
-                    for i in range(meta["n_blocks"])
-                ]
-                # Layout contract (build_random_effect_dataset): the
-                # projector sits at 5*n_blocks; trailing arrays (the
-                # score map) come AFTER it — arrays[-1] would pick those.
-                proj_dev = arrays[packed_proj_index(meta["n_blocks"])]
-            else:
-                plans = list(op["plans"])
-                proj_dev = op["proj_dev"]
-            from photon_tpu.ops import precision as precision_mod
-
-            # bf16 slab storage (mixed precision): the gather happens
-            # once per dataset generation, so the cast is amortized —
-            # every later sweep reads the slab at half HBM width.
-            ebs = tuple(
-                dataclasses.replace(
-                    eb,
-                    x_values=precision_mod.in_storage(
-                        eb.x_values, self.precision),
+        meta = self._re_meta[cid]
+        if "buf" in op:
+            arrays = []
+            for off, shape in meta["slices"]:
+                n = int(np.prod(shape)) if shape else 1
+                arrays.append(
+                    jax.lax.slice_in_dim(
+                        op["buf"], off, off + n).reshape(shape)
                 )
-                for eb in (p.materialize(None) for p in plans)
+            plans = [
+                BlockPlan(
+                    entity_codes=arrays[_PPB * i],
+                    row_ids=arrays[_PPB * i + 1],
+                    row_counts=arrays[_PPB * i + 2],
+                    proj=arrays[_PPB * i + 3],
+                    intercept_slots=arrays[_PPB * i + 4],
+                    raw=op["raw"],
+                    raw_labels=op["labels"],
+                    raw_offsets=op["offsets"],
+                    raw_weights=op["weights"],
+                )
+                for i in range(meta["n_blocks"])
+            ]
+            # Layout contract (build_random_effect_dataset): the
+            # projector sits at 5*n_blocks; trailing arrays (the
+            # score map) come AFTER it — arrays[-1] would pick those.
+            proj_dev = arrays[packed_proj_index(meta["n_blocks"])]
+        else:
+            plans = list(op["plans"])
+            proj_dev = op["proj_dev"]
+        from photon_tpu.ops import precision as precision_mod
+
+        # bf16 slab storage (mixed precision): the gather happens
+        # once per dataset generation, so the cast is amortized —
+        # every later sweep reads the slab at half HBM width.
+        ebs = tuple(
+            dataclasses.replace(
+                eb,
+                x_values=precision_mod.in_storage(
+                    eb.x_values, self.precision),
             )
-            out[cid] = {
-                "ebs": ebs,
-                "score_plans": tuple(
-                    (p.row_ids, p.row_counts, p.entity_codes)
-                    for p in plans
-                ),
-                "proj_dev": proj_dev,
-                # Inverse score map (row -> flat bucket/passive score
-                # position): present on packed layouts with the extra
-                # trailing array; enables the gather-based scorer.
-                "score_inv": (
-                    arrays[packed_score_inv_index(meta["n_blocks"])]
-                    if "buf" in op
-                    and len(meta["slices"])
-                    == packed_len_with_score_inv(meta["n_blocks"])
-                    else None
-                ),
-            }
-        return out
+            for eb in (p.materialize(None) for p in plans)
+        )
+        return {
+            "ebs": ebs,
+            "score_plans": tuple(
+                (p.row_ids, p.row_counts, p.entity_codes)
+                for p in plans
+            ),
+            "proj_dev": proj_dev,
+            # Inverse score map (row -> flat bucket/passive score
+            # position): present on packed layouts with the extra
+            # trailing array; enables the gather-based scorer.
+            "score_inv": (
+                arrays[packed_score_inv_index(meta["n_blocks"])]
+                if "buf" in op
+                and len(meta["slices"])
+                == packed_len_with_score_inv(meta["n_blocks"])
+                else None
+            ),
+        }
 
     def _zeros(self, shape, dtype) -> Array:
         key = (shape, jnp.dtype(dtype).name)
@@ -822,11 +828,12 @@ class FusedFit:
                     else jnp.zeros_like(means)
                 )
                 states.append((means, variances))
-                z = (
-                    self._fe_score(means, op["batch"]) if has_init
-                    else jnp.zeros(
-                        op["batch"].num_samples, means.dtype)
-                )
+                with jax.named_scope(f"coord.{self.seq[i]}/score"):
+                    z = (
+                        self._fe_score(means, op["batch"]) if has_init
+                        else jnp.zeros(
+                            op["batch"].num_samples, means.dtype)
+                    )
                 diags.append((
                     jnp.zeros(num_iters, jnp.int32),
                     jnp.zeros(num_iters, jnp.int32),
@@ -841,12 +848,13 @@ class FusedFit:
                     else jnp.zeros_like(w_all)
                 )
                 states.append((w_all, v_all))
-                z = (
-                    self._re_score(w_all, op, ebs_all[self.seq[i]])
-                    if has_init
-                    else jnp.zeros(
-                        op["score_codes"].shape[0], w_all.dtype)
-                )
+                with jax.named_scope(f"coord.{self.seq[i]}/score"):
+                    z = (
+                        self._re_score(w_all, op, ebs_all[self.seq[i]])
+                        if has_init
+                        else jnp.zeros(
+                            op["score_codes"].shape[0], w_all.dtype)
+                    )
                 diags.append((
                     jnp.zeros((num_iters, e), jnp.int32),
                     jnp.zeros((num_iters, e), jnp.int32),
@@ -867,105 +875,120 @@ class FusedFit:
                 kind = st[0]
                 if kind == "locked":
                     continue
-                z_old = self._read_score(scores[i], total.dtype)
-                residual = total - z_old
-                if kind == "fixed":
-                    _, task, opt_config, use_owlqn, intercept_index, \
-                        var_comp = st[:6]
-                    batch = op["batch"]
-                    batch = batch.with_offsets(batch.offsets + residual)
-                    prev_means = states[i][0]
-                    means, variances, result = _run_impl(
-                        batch,
-                        states[i][0],
-                        op["l1"], op["l2"],
-                        self._fe_norm(i),
-                        op["prior"],
-                        op["iw"],
-                        task=task,
-                        opt_config=opt_config,
-                        use_owlqn=use_owlqn,
-                        intercept_index=intercept_index,
-                        variance_computation=var_comp,
-                    )
-                    states[i] = (means, variances)
-                    z = self._fe_score(means, op["batch"])
-                    it_arr, rs_arr = diags[i]
-                    diags[i] = (
-                        it_arr.at[it].set(result.iterations),
-                        rs_arr.at[it].set(result.convergence_reason),
-                    )
-                    # Solver-final objective/gradient come free from the
-                    # OptResult — no extra passes over the batch.
-                    conv_loss = result.value
-                    conv_gnorm = result.gradient_norm
-                    conv_wd = jnp.sum((means - prev_means) ** 2)
-                    conv_wn = jnp.sum(means ** 2)
-                else:
-                    _, task, opt_config, use_owlqn, var_comp, direct, \
-                        newton = st[:7]
-                    w_prev, v_prev = states[i]
-                    w_all = jnp.zeros_like(w_prev)
-                    v_all = None if v_prev is None else jnp.zeros_like(
-                        v_prev)
-                    e = w_prev.shape[0]
-                    its_e = jnp.zeros(e, jnp.int32)
-                    rs_e = jnp.zeros(e, jnp.int32)
-                    mat = ebs_all[self.seq[i]]
-                    for (_, _, codes), eb in zip(
-                        mat["score_plans"], mat["ebs"]
-                    ):
-                        w_all, v_all, its, rs = _solve_block(
-                            eb,
-                            residual,
-                            op["factors"],
-                            op["shifts"],
-                            w_prev,
-                            op["l1"], op["l2"], op["iw"],
-                            op["prior"],
-                            w_all, v_all,
-                            sub_dim=eb.sub_dim,
-                            task=task,
-                            opt_config=opt_config,
-                            use_owlqn=use_owlqn,
-                            variance_computation=var_comp,
-                            direct=direct,
-                            newton=newton,
-                            precision=self.precision,
+                # Scopes are metadata on the operations (no operation
+                # changes): coord.<cid>, and inside it residual /
+                # solve.<route> (_solve_block names the random-effect
+                # routes, _solve_newton_batched its own two) / score.
+                with jax.named_scope(f"coord.{self.seq[i]}"):
+                    z_old = self._read_score(scores[i], total.dtype)
+                    with jax.named_scope("residual"):
+                        residual = total - z_old
+                    if kind == "fixed":
+                        _, task, opt_config, use_owlqn, intercept_index, \
+                            var_comp = st[:6]
+                        batch = op["batch"]
+                        with jax.named_scope("residual"):
+                            batch = batch.with_offsets(
+                                batch.offsets + residual)
+                        prev_means = states[i][0]
+                        route = (
+                            "owlqn" if use_owlqn
+                            else opt_config.optimizer_type.value.lower()
                         )
-                        its_e = its_e.at[codes].set(its)
-                        rs_e = rs_e.at[codes].set(rs)
-                    states[i] = (w_all, v_all)
-                    z = self._re_score(w_all, op, mat)
-                    it_arr, rs_arr = diags[i]
-                    diags[i] = (
-                        it_arr.at[it].set(its_e),
-                        rs_arr.at[it].set(rs_e),
+                        with jax.named_scope("solve." + route):
+                            means, variances, result = _run_impl(
+                                batch,
+                                states[i][0],
+                                op["l1"], op["l2"],
+                                self._fe_norm(i),
+                                op["prior"],
+                                op["iw"],
+                                task=task,
+                                opt_config=opt_config,
+                                use_owlqn=use_owlqn,
+                                intercept_index=intercept_index,
+                                variance_computation=var_comp,
+                            )
+                        states[i] = (means, variances)
+                        with jax.named_scope("score"):
+                            z = self._fe_score(means, op["batch"])
+                        it_arr, rs_arr = diags[i]
+                        diags[i] = (
+                            it_arr.at[it].set(result.iterations),
+                            rs_arr.at[it].set(result.convergence_reason),
+                        )
+                        # Solver-final objective/gradient come free from the
+                        # OptResult — no extra passes over the batch.
+                        conv_loss = result.value
+                        conv_gnorm = result.gradient_norm
+                        conv_wd = jnp.sum((means - prev_means) ** 2)
+                        conv_wn = jnp.sum(means ** 2)
+                    else:
+                        _, task, opt_config, use_owlqn, var_comp, direct, \
+                            newton = st[:7]
+                        w_prev, v_prev = states[i]
+                        w_all = jnp.zeros_like(w_prev)
+                        v_all = None if v_prev is None else jnp.zeros_like(
+                            v_prev)
+                        e = w_prev.shape[0]
+                        its_e = jnp.zeros(e, jnp.int32)
+                        rs_e = jnp.zeros(e, jnp.int32)
+                        mat = ebs_all[self.seq[i]]
+                        for (_, _, codes), eb in zip(
+                            mat["score_plans"], mat["ebs"]
+                        ):
+                            w_all, v_all, its, rs = _solve_block(
+                                eb,
+                                residual,
+                                op["factors"],
+                                op["shifts"],
+                                w_prev,
+                                op["l1"], op["l2"], op["iw"],
+                                op["prior"],
+                                w_all, v_all,
+                                sub_dim=eb.sub_dim,
+                                task=task,
+                                opt_config=opt_config,
+                                use_owlqn=use_owlqn,
+                                variance_computation=var_comp,
+                                direct=direct,
+                                newton=newton,
+                                precision=self.precision,
+                            )
+                            its_e = its_e.at[codes].set(its)
+                            rs_e = rs_e.at[codes].set(rs)
+                        states[i] = (w_all, v_all)
+                        with jax.named_scope("score"):
+                            z = self._re_score(w_all, op, mat)
+                        it_arr, rs_arr = diags[i]
+                        diags[i] = (
+                            it_arr.at[it].set(its_e),
+                            rs_arr.at[it].set(rs_e),
+                        )
+                        # The batched per-entity solvers return iteration
+                        # counts, not objective values: loss/grad_norm are 0
+                        # for random effects (obs/convergence.py documents
+                        # the column contract); the deltas below are the
+                        # convergence signal that exists for every kind.
+                        conv_loss = jnp.zeros((), total.dtype)
+                        conv_gnorm = jnp.zeros((), total.dtype)
+                        conv_wd = jnp.sum((w_all - w_prev) ** 2)
+                        conv_wn = jnp.sum(w_all ** 2)
+                    z = self._quantize_score(z)
+                    # residual_delta_sq: movement of this coordinate's score
+                    # contribution this sweep — computed on values the
+                    # residual bookkeeping already holds (no extra passes).
+                    conv = conv.at[it, conv_index[i]].set(
+                        jnp.stack([
+                            conv_loss.astype(total.dtype),
+                            conv_gnorm.astype(total.dtype),
+                            jnp.sum((z - z_old) ** 2).astype(total.dtype),
+                            conv_wd.astype(total.dtype),
+                            conv_wn.astype(total.dtype),
+                        ])
                     )
-                    # The batched per-entity solvers return iteration
-                    # counts, not objective values: loss/grad_norm are 0
-                    # for random effects (obs/convergence.py documents
-                    # the column contract); the deltas below are the
-                    # convergence signal that exists for every kind.
-                    conv_loss = jnp.zeros((), total.dtype)
-                    conv_gnorm = jnp.zeros((), total.dtype)
-                    conv_wd = jnp.sum((w_all - w_prev) ** 2)
-                    conv_wn = jnp.sum(w_all ** 2)
-                z = self._quantize_score(z)
-                # residual_delta_sq: movement of this coordinate's score
-                # contribution this sweep — computed on values the
-                # residual bookkeeping already holds (no extra passes).
-                conv = conv.at[it, conv_index[i]].set(
-                    jnp.stack([
-                        conv_loss.astype(total.dtype),
-                        conv_gnorm.astype(total.dtype),
-                        jnp.sum((z - z_old) ** 2).astype(total.dtype),
-                        conv_wd.astype(total.dtype),
-                        conv_wn.astype(total.dtype),
-                    ])
-                )
-                total = total - z_old + z
-                scores[i] = self._store_score(z)
+                    total = total - z_old + z
+                    scores[i] = self._store_score(z)
             return tuple(states), tuple(scores), total, tuple(diags), conv
 
         carry = (tuple(states), tuple(scores), total, tuple(diags), conv0)
@@ -1229,9 +1252,14 @@ class FusedFit:
         # and the per-record attribution below come from a real
         # measurement. Disabled, the span is a no-op and the dispatch
         # stays fully asynchronous — the pre-telemetry behavior.
-        with obs.span("fused_fit") as sp:
-            ops = self._operands(coords, initial_models)
-            statics = self._statics(coords, initial_models)
+        # Inside it the always-recorded stages (obs.stage): `fit` from
+        # entry to the return of the dispatch, NOT to the device's end,
+        # and its parts `fit.operands`, `compile_wait` (_consume_aot),
+        # `fit.materialize`, `fit.dispatch`. None of them syncs.
+        with obs.span("fused_fit") as sp, obs.stage("fit"):
+            with obs.stage("fit.operands"):
+                ops = self._operands(coords, initial_models)
+                statics = self._statics(coords, initial_models)
             aot = self._consume_aot()
             # Slabs materialize once per dataset generation (separate
             # cached program that also unpacks the ingest's packed plan
@@ -1243,19 +1271,18 @@ class FusedFit:
             # a run that actually gathered slabs records one — a cache
             # hit dispatched nothing.
             mat_window = None
-            if self._mat_shared is not None:
-                ebs_all = self._mat_shared.get("ebs")
-                if ebs_all is None:
-                    t_m0 = time.perf_counter()
-                    ebs_all = self._mat_shared["ebs"] = self._run_mat(
-                        coords, aot)
-                    mat_window = (t_m0, time.perf_counter())
-            else:
-                if self._mat_cache is None:
-                    t_m0 = time.perf_counter()
-                    self._mat_cache = self._run_mat(coords, aot)
-                    mat_window = (t_m0, time.perf_counter())
-                ebs_all = self._mat_cache
+            share = self._mat_shared
+            ebs_all = (
+                share.get("ebs") if share is not None else self._mat_cache
+            )
+            if ebs_all is None:
+                with obs.stage("fit.materialize") as mat_stage:
+                    ebs_all = self._run_mat(coords, aot)
+                mat_window = (mat_stage.t0, mat_stage.t1)
+                if share is not None:
+                    share["ebs"] = ebs_all
+                else:
+                    self._mat_cache = ebs_all
             # The attribution window opens HERE: operand assembly, the
             # AOT compile wait, and slab materialization above are not
             # fit work and must not be charged to coordinate records.
@@ -1310,10 +1337,13 @@ class FusedFit:
 
             from photon_tpu.resilience import retry
 
-            out = retry.call_with_retry(
-                dispatch_once, site="fused_fit.dispatch",
-                on_retry=_mark_impure,
-            )
+            # Trace, lower and cache load or compile on a first entry; a
+            # bare enqueue on a warm one.
+            with obs.stage("fit.dispatch"):
+                out = retry.call_with_retry(
+                    dispatch_once, site="fused_fit.dispatch",
+                    on_retry=_mark_impure,
+                )
             states, scores, total, packed_flat, conv = out
             if sp is not None:
                 sp.sync = out

@@ -538,136 +538,231 @@ def _solve_newton_batched(
     solver stays on the exact direct path where the solution itself is
     the answer).
     """
-    # Solver state (w, f, g, H, CG iterates) is f32; only the slab x may
-    # be stored bf16 under mixed precision — every contraction against
-    # it reads bf16 and accumulates f32 (ops/precision.py invariant).
-    dtype = labels.dtype
-    b = x.shape[0]
-    if shifts is not None:
-        x = x - precision_mod.like_storage(shifts, x)[:, None, :]
-    if factors is not None:
-        x = x * precision_mod.like_storage(factors, x)[:, None, :]
-    loss = losses_mod.get_loss(task)
-    iota = jnp.arange(sub_dim)[None, :]
-    int_onehot = (
-        None if shifts is None
-        else (iota == intercept_slots[:, None]).astype(dtype)
-    )
-
-    def to_transformed(w):
-        if shifts is not None:
-            w = w + jnp.sum(w * shifts, axis=-1, keepdims=True) * int_onehot
-        if factors is not None:
-            w = w / factors
-        return w
-
-    def to_original(w_t):
-        w = w_t if factors is None else w_t * factors
-        if shifts is not None:
-            w = w - jnp.sum(w * shifts, axis=-1, keepdims=True) * int_onehot
-        return w
-
-    if prior is not None:
-        m_t = to_transformed(prior[0])
-        f_sq = 1.0 if factors is None else factors * factors
-        inv_prior_var = optim.inverse_prior_variances(
-            prior[1] / f_sq, l2_weight) * valid_mask
-        l2_diag = incremental_weight * inv_prior_var
-    else:
-        m_t = jnp.zeros((b, sub_dim), dtype)
-        l2_diag = l2_weight * penalty_mask
-
-    def objective(w):  # w [B, S] -> f [B], g [B, S]
-        z = precision_mod.acc_einsum(
-            "brs,bs->br", x, precision_mod.like_storage(w, x)
-        ) + offsets
-        f = jnp.sum(weights * loss.loss(z, labels), axis=-1) + 0.5 * jnp.sum(
-            l2_diag * (w - m_t) ** 2, axis=-1
-        )
-        g = precision_mod.acc_einsum(
-            "brs,br->bs", x,
-            precision_mod.like_storage(weights * loss.dz(z, labels), x),
-        )
-        g = g + l2_diag * (w - m_t)
-        return f, g * valid_mask
-
-    # Per-entity absolute tolerances from the zero state
-    # (Optimizer.scala:167-170 semantics, batched).
-    f0z, g0z = objective(jnp.zeros((b, sub_dim), dtype))
-    tol = optim.Tolerances(
-        loss_abs=jnp.abs(f0z) * opt_config.tolerance,
-        gradient_abs=jnp.sqrt(jnp.sum(g0z * g0z, axis=-1))
-        * opt_config.tolerance,
-    )
-    w0 = to_transformed(w0_orig) * valid_mask
-    f0, g0 = objective(w0)
-    max_iters = opt_config.max_iterations
-
     from photon_tpu.ops import newton_kernel as nk
 
     r = x.shape[1]
     # The fused Newton kernel is f32-only: a bf16-stored slab takes the
     # batch-minor XLA path below (which reads the slab at half width —
-    # the storage win survives the fallback).
-    if nk.kernel_supported(task, x.dtype, r, sub_dim, spmd=spmd):
-        # Fused Pallas step: the [S, S] Hessians never leave VMEM (the
-        # XLA path's padded [B, S, S] HBM round trip is the traffic it
-        # removes; ops/newton_kernel.py).
-        bp = nk.pad_lanes(b)
-
-        def pad_b(a):
-            return jnp.pad(a, [(0, bp - b)] + [(0, 0)] * (a.ndim - 1))
-
-        x_l = jnp.transpose(pad_b(x), (2, 1, 0))
-        y_l = nk.to_lanes(labels, bp)
-        wt_l = nk.to_lanes(weights, bp)
-        off_l = nk.to_lanes(offsets, bp)
-        l2_l = nk.to_lanes(jnp.broadcast_to(l2_diag, (b, sub_dim)), bp)
-        mt_l = nk.to_lanes(jnp.broadcast_to(m_t, (b, sub_dim)), bp)
-        vm_l = nk.to_lanes(valid_mask, bp)
-        w_l = nk.to_lanes(w0, bp)
-        g_l = nk.to_lanes(g0, bp)
-        f_l = jnp.pad(f0, (0, bp - b))[None, :]
-        tol_p = optim.Tolerances(
-            loss_abs=jnp.pad(tol.loss_abs, (0, bp - b)),
-            gradient_abs=jnp.pad(tol.gradient_abs, (0, bp - b)),
+    # the storage win survives the fallback). The ONE place that decides
+    # the route also names it: `solve.newton_kernel` / `solve.newton_xla`
+    # on every operation of the solve (metadata only).
+    use_kernel = nk.kernel_supported(task, x.dtype, r, sub_dim, spmd=spmd)
+    with jax.named_scope(
+        "solve.newton_kernel" if use_kernel else "solve.newton_xla"
+    ):
+        # Solver state (w, f, g, H, CG iterates) is f32; only the slab x
+        # may be stored bf16 under mixed precision — every contraction
+        # against it reads bf16 and accumulates f32 (ops/precision.py
+        # invariant).
+        dtype = labels.dtype
+        b = x.shape[0]
+        if shifts is not None:
+            x = x - precision_mod.like_storage(shifts, x)[:, None, :]
+        if factors is not None:
+            x = x * precision_mod.like_storage(factors, x)[:, None, :]
+        loss = losses_mod.get_loss(task)
+        iota = jnp.arange(sub_dim)[None, :]
+        int_onehot = (
+            None if shifts is None
+            else (iota == intercept_slots[:, None]).astype(dtype)
         )
 
-        def cond_k(st):
-            return jnp.any(st[4] == 0)
+        def to_transformed(w):
+            if shifts is not None:
+                w = w + jnp.sum(
+                    w * shifts, axis=-1, keepdims=True) * int_onehot
+            if factors is not None:
+                w = w / factors
+            return w
 
-        def body_k(st):
-            w_c, f_c, g_c, it_c, code_c = st
-            active = code_c == 0
-            w_n, f_n, g_n, imp = nk.newton_step_lanes(
-                x_l, w_c, y_l, wt_l, off_l, l2_l, mt_l, vm_l, f_c,
-                r=r, s=sub_dim, task=task,
-                trials=_NEWTON_LINE_SEARCH_HALVINGS + 1,
-                interpret=nk.interpret_required(),
+        def to_original(w_t):
+            w = w_t if factors is None else w_t * factors
+            if shifts is not None:
+                w = w - jnp.sum(
+                    w * shifts, axis=-1, keepdims=True) * int_onehot
+            return w
+
+        if prior is not None:
+            m_t = to_transformed(prior[0])
+            f_sq = 1.0 if factors is None else factors * factors
+            inv_prior_var = optim.inverse_prior_variances(
+                prior[1] / f_sq, l2_weight) * valid_mask
+            l2_diag = incremental_weight * inv_prior_var
+        else:
+            m_t = jnp.zeros((b, sub_dim), dtype)
+            l2_diag = l2_weight * penalty_mask
+
+        def objective(w):  # w [B, S] -> f [B], g [B, S]
+            z = precision_mod.acc_einsum(
+                "brs,bs->br", x, precision_mod.like_storage(w, x)
+            ) + offsets
+            f = jnp.sum(
+                weights * loss.loss(z, labels), axis=-1
+            ) + 0.5 * jnp.sum(l2_diag * (w - m_t) ** 2, axis=-1)
+            g = precision_mod.acc_einsum(
+                "brs,br->bs", x,
+                precision_mod.like_storage(weights * loss.dz(z, labels), x),
             )
-            w_n = jnp.where(active[None, :], w_n, w_c)
-            f_n = jnp.where(active[None, :], f_n, f_c)
-            g_n = jnp.where(active[None, :], g_n, g_c)
-            it_n = jnp.where(active, it_c + 1, it_c)
-            code_n = optim.convergence_code(
-                iteration=it_n,
+            g = g + l2_diag * (w - m_t)
+            return f, g * valid_mask
+
+        # Per-entity absolute tolerances from the zero state
+        # (Optimizer.scala:167-170 semantics, batched).
+        f0z, g0z = objective(jnp.zeros((b, sub_dim), dtype))
+        tol = optim.Tolerances(
+            loss_abs=jnp.abs(f0z) * opt_config.tolerance,
+            gradient_abs=jnp.sqrt(jnp.sum(g0z * g0z, axis=-1))
+            * opt_config.tolerance,
+        )
+        w0 = to_transformed(w0_orig) * valid_mask
+        f0, g0 = objective(w0)
+        max_iters = opt_config.max_iterations
+
+        if use_kernel:
+            # Fused Pallas step: the [S, S] Hessians never leave VMEM (the
+            # XLA path's padded [B, S, S] HBM round trip is the traffic it
+            # removes; ops/newton_kernel.py).
+            bp = nk.pad_lanes(b)
+
+            def pad_b(a):
+                return jnp.pad(a, [(0, bp - b)] + [(0, 0)] * (a.ndim - 1))
+
+            x_l = jnp.transpose(pad_b(x), (2, 1, 0))
+            y_l = nk.to_lanes(labels, bp)
+            wt_l = nk.to_lanes(weights, bp)
+            off_l = nk.to_lanes(offsets, bp)
+            l2_l = nk.to_lanes(jnp.broadcast_to(l2_diag, (b, sub_dim)), bp)
+            mt_l = nk.to_lanes(jnp.broadcast_to(m_t, (b, sub_dim)), bp)
+            vm_l = nk.to_lanes(valid_mask, bp)
+            w_l = nk.to_lanes(w0, bp)
+            g_l = nk.to_lanes(g0, bp)
+            f_l = jnp.pad(f0, (0, bp - b))[None, :]
+            tol_p = optim.Tolerances(
+                loss_abs=jnp.pad(tol.loss_abs, (0, bp - b)),
+                gradient_abs=jnp.pad(tol.gradient_abs, (0, bp - b)),
+            )
+
+            def cond_k(st):
+                return jnp.any(st[4] == 0)
+
+            def body_k(st):
+                w_c, f_c, g_c, it_c, code_c = st
+                active = code_c == 0
+                w_n, f_n, g_n, imp = nk.newton_step_lanes(
+                    x_l, w_c, y_l, wt_l, off_l, l2_l, mt_l, vm_l, f_c,
+                    r=r, s=sub_dim, task=task,
+                    trials=_NEWTON_LINE_SEARCH_HALVINGS + 1,
+                    interpret=nk.interpret_required(),
+                )
+                w_n = jnp.where(active[None, :], w_n, w_c)
+                f_n = jnp.where(active[None, :], f_n, f_c)
+                g_n = jnp.where(active[None, :], g_n, g_c)
+                it_n = jnp.where(active, it_c + 1, it_c)
+                code_n = optim.convergence_code(
+                    iteration=it_n,
+                    max_iterations=max_iters,
+                    loss_delta=f_c[0] - f_n[0],
+                    gradient_norm=jnp.sqrt(jnp.sum(g_n * g_n, axis=0)),
+                    tol=tol_p,
+                    not_improving=~(imp[0] > 0),
+                )
+                code_n = jnp.where(active, code_n, code_c)
+                return w_n, f_n, g_n, it_n, code_n
+
+            w_lk, _, _, iters_k, reason_k = lax.while_loop(
+                cond_k, body_k,
+                (w_l, f_l, g_l, jnp.zeros(bp, jnp.int32),
+                 jnp.zeros(bp, jnp.int32)),
+            )
+            w_t = jnp.transpose(w_lk)[:b] * valid_mask
+            iters = iters_k[:b]
+            reason = reason_k[:b]
+            if variance_computation != VarianceComputationType.NONE:
+                variances = _batched_variances(
+                    x, labels, offsets, weights, w_t, l2_diag, valid_mask,
+                    factors, shifts, loss, variance_computation,
+                )
+            else:
+                variances = jnp.zeros_like(w_t)
+            w_orig = to_original(w_t) * valid_mask
+            return w_orig, variances, iters, reason
+
+        trial_ts = 0.5 ** jnp.arange(
+            _NEWTON_LINE_SEARCH_HALVINGS + 1, dtype=dtype
+        )  # [T]
+
+        def cond(s):
+            _, _, _, _, code = s
+            return jnp.any(code == 0)
+
+        def body(s):
+            w, f, g, it, code = s
+            active = code == 0
+            z = precision_mod.acc_einsum(
+                "brs,bs->br", x, precision_mod.like_storage(w, x)
+            ) + offsets
+            curvature = weights * loss.dzz(z, labels)
+            h = precision_mod.acc_einsum(
+                "brs,brt->bst",
+                x * precision_mod.like_storage(curvature, x)[:, :, None], x,
+            )
+            h = h + (
+                l2_diag[:, :, None] * jnp.eye(sub_dim, dtype=dtype)[None]
+                + (1.0 - valid_mask)[:, :, None]
+                * jnp.eye(sub_dim, dtype=dtype)[None]
+            )
+            # ONE compact transpose; CG then re-reads the dense [S, S, B]
+            # copy instead of the tiling-padded MXU output.
+            h_sb = jnp.transpose(h, (1, 2, 0))
+            d = jnp.transpose(
+                _spd_solve_cg_sb(h_sb, -jnp.transpose(g), sub_dim, active)
+            ) * valid_mask
+            gd = jnp.sum(g * d, axis=-1)
+            # Descent guard (same as the vmapped path): fp32 CG on a
+            # near-singular Hessian can return a non-descent direction.
+            bad = gd >= 0.0
+            d = jnp.where(bad[:, None], -g, d)
+            gd = jnp.where(bad, -jnp.sum(g * g, axis=-1), gd)
+
+            zd = precision_mod.acc_einsum(
+                "brs,bs->br", x, precision_mod.like_storage(d, x)
+            )
+            z_t = z[None] + trial_ts[:, None, None] * zd[None]  # [T, B, R]
+            w_t_trials = w[None] + trial_ts[:, None, None] * d[None]  # [T,B,S]
+            f_t = jnp.sum(
+                weights[None] * loss.loss(z_t, labels[None]), axis=-1
+            ) + 0.5 * jnp.sum(
+                l2_diag[None] * (w_t_trials - m_t[None]) ** 2, axis=-1
+            )  # [T, B]
+            armijo = f_t <= f[None] + 1e-4 * trial_ts[:, None] * gd[None]
+            first = jnp.argmax(armijo, axis=0)  # [B]
+            any_ok = jnp.any(armijo, axis=0)
+            t = trial_ts[first]
+            f_t_sel = jnp.take_along_axis(f_t, first[None], axis=0)[0]
+            improved = any_ok & (f_t_sel < f)
+            step_ok = active & improved
+            w_new = jnp.where(step_ok[:, None], w + t[:, None] * d, w)
+            f_new, g_new = objective(w_new)
+            f_new = jnp.where(active, f_new, f)
+            g_new = jnp.where(active[:, None], g_new, g)
+            it_new = jnp.where(active, it + 1, it)
+            code_new = optim.convergence_code(
+                iteration=it_new,
                 max_iterations=max_iters,
-                loss_delta=f_c[0] - f_n[0],
-                gradient_norm=jnp.sqrt(jnp.sum(g_n * g_n, axis=0)),
-                tol=tol_p,
-                not_improving=~(imp[0] > 0),
+                loss_delta=f - f_new,
+                gradient_norm=jnp.sqrt(jnp.sum(g_new * g_new, axis=-1)),
+                tol=tol,
+                not_improving=~improved,
             )
-            code_n = jnp.where(active, code_n, code_c)
-            return w_n, f_n, g_n, it_n, code_n
+            code_new = jnp.where(active, code_new, code)
+            return w_new, f_new, g_new, it_new, code_new
 
-        w_lk, _, _, iters_k, reason_k = lax.while_loop(
-            cond_k, body_k,
-            (w_l, f_l, g_l, jnp.zeros(bp, jnp.int32),
-             jnp.zeros(bp, jnp.int32)),
+        w_t, f_fin, g_fin, iters, reason = lax.while_loop(
+            cond, body,
+            (w0, f0, g0, jnp.zeros(b, jnp.int32), jnp.zeros(b, jnp.int32)),
         )
-        w_t = jnp.transpose(w_lk)[:b] * valid_mask
-        iters = iters_k[:b]
-        reason = reason_k[:b]
+        w_t = w_t * valid_mask
+
         if variance_computation != VarianceComputationType.NONE:
             variances = _batched_variances(
                 x, labels, offsets, weights, w_t, l2_diag, valid_mask,
@@ -675,95 +770,9 @@ def _solve_newton_batched(
             )
         else:
             variances = jnp.zeros_like(w_t)
+
         w_orig = to_original(w_t) * valid_mask
         return w_orig, variances, iters, reason
-
-    trial_ts = 0.5 ** jnp.arange(
-        _NEWTON_LINE_SEARCH_HALVINGS + 1, dtype=dtype
-    )  # [T]
-
-    def cond(s):
-        _, _, _, _, code = s
-        return jnp.any(code == 0)
-
-    def body(s):
-        w, f, g, it, code = s
-        active = code == 0
-        z = precision_mod.acc_einsum(
-            "brs,bs->br", x, precision_mod.like_storage(w, x)
-        ) + offsets
-        curvature = weights * loss.dzz(z, labels)
-        h = precision_mod.acc_einsum(
-            "brs,brt->bst",
-            x * precision_mod.like_storage(curvature, x)[:, :, None], x,
-        )
-        h = h + (
-            l2_diag[:, :, None] * jnp.eye(sub_dim, dtype=dtype)[None]
-            + (1.0 - valid_mask)[:, :, None]
-            * jnp.eye(sub_dim, dtype=dtype)[None]
-        )
-        # ONE compact transpose; CG then re-reads the dense [S, S, B]
-        # copy instead of the tiling-padded MXU output.
-        h_sb = jnp.transpose(h, (1, 2, 0))
-        d = jnp.transpose(
-            _spd_solve_cg_sb(h_sb, -jnp.transpose(g), sub_dim, active)
-        ) * valid_mask
-        gd = jnp.sum(g * d, axis=-1)
-        # Descent guard (same as the vmapped path): fp32 CG on a
-        # near-singular Hessian can return a non-descent direction.
-        bad = gd >= 0.0
-        d = jnp.where(bad[:, None], -g, d)
-        gd = jnp.where(bad, -jnp.sum(g * g, axis=-1), gd)
-
-        zd = precision_mod.acc_einsum(
-            "brs,bs->br", x, precision_mod.like_storage(d, x)
-        )
-        z_t = z[None] + trial_ts[:, None, None] * zd[None]  # [T, B, R]
-        w_t_trials = w[None] + trial_ts[:, None, None] * d[None]  # [T,B,S]
-        f_t = jnp.sum(
-            weights[None] * loss.loss(z_t, labels[None]), axis=-1
-        ) + 0.5 * jnp.sum(
-            l2_diag[None] * (w_t_trials - m_t[None]) ** 2, axis=-1
-        )  # [T, B]
-        armijo = f_t <= f[None] + 1e-4 * trial_ts[:, None] * gd[None]
-        first = jnp.argmax(armijo, axis=0)  # [B]
-        any_ok = jnp.any(armijo, axis=0)
-        t = trial_ts[first]
-        f_t_sel = jnp.take_along_axis(f_t, first[None], axis=0)[0]
-        improved = any_ok & (f_t_sel < f)
-        step_ok = active & improved
-        w_new = jnp.where(step_ok[:, None], w + t[:, None] * d, w)
-        f_new, g_new = objective(w_new)
-        f_new = jnp.where(active, f_new, f)
-        g_new = jnp.where(active[:, None], g_new, g)
-        it_new = jnp.where(active, it + 1, it)
-        code_new = optim.convergence_code(
-            iteration=it_new,
-            max_iterations=max_iters,
-            loss_delta=f - f_new,
-            gradient_norm=jnp.sqrt(jnp.sum(g_new * g_new, axis=-1)),
-            tol=tol,
-            not_improving=~improved,
-        )
-        code_new = jnp.where(active, code_new, code)
-        return w_new, f_new, g_new, it_new, code_new
-
-    w_t, f_fin, g_fin, iters, reason = lax.while_loop(
-        cond, body,
-        (w0, f0, g0, jnp.zeros(b, jnp.int32), jnp.zeros(b, jnp.int32)),
-    )
-    w_t = w_t * valid_mask
-
-    if variance_computation != VarianceComputationType.NONE:
-        variances = _batched_variances(
-            x, labels, offsets, weights, w_t, l2_diag, valid_mask,
-            factors, shifts, loss, variance_computation,
-        )
-    else:
-        variances = jnp.zeros_like(w_t)
-
-    w_orig = to_original(w_t) * valid_mask
-    return w_orig, variances, iters, reason
 
 
 def _batched_variances(x_t, labels, offsets, weights, w_t, l2_diag,
@@ -1127,12 +1136,13 @@ def _solve_block(
     else:
         offsets = block.offsets
         if residuals is not None:
-            # Padding rows alias canonical row 0; mask their gather.
-            offsets = offsets + jnp.where(
-                block.weights > 0,
-                jnp.take(residuals, block.row_ids, mode="clip"),
-                0.0,
-            )
+            with jax.named_scope("residual"):
+                # Padding rows alias canonical row 0; mask their gather.
+                offsets = offsets + jnp.where(
+                    block.weights > 0,
+                    jnp.take(residuals, block.row_ids, mode="clip"),
+                    0.0,
+                )
     if precision_mod.is_mixed(precision):
         # bf16 SLAB STORAGE (the mixed-precision policy): the design
         # slab — the dominant per-iteration HBM read — is held and read
@@ -1209,6 +1219,22 @@ def _solve_block(
         block = dataclasses.replace(
             block, x_values=block.x_values.astype(dtype)
         )
+    # `solve.<route>`: the solver the statics select (scope names are
+    # metadata on the operations; no operation changes). A dense Newton
+    # bucket is named where its route is decided, in
+    # `_solve_newton_batched`. The scope is entered around each solver
+    # call HERE: with the solve moved into a helper function the logistic
+    # fit program traced 7 s slower on the chip's host (PERF.md section
+    # 6, PR 25; cause not found).
+    if direct:
+        route = "direct"
+    elif newton:
+        route = "newton_xla"  # sparse buckets: the vmapped XLA step
+    elif use_owlqn:
+        route = "owlqn"
+    else:
+        route = opt_config.optimizer_type.value.lower()
+    solve_scope = jax.named_scope("solve." + route)
     s = sub_dim
     codes = block.entity_codes
     proj = block.proj  # [B, S]; -1 pad
@@ -1239,17 +1265,18 @@ def _solve_block(
         )
     if direct:
         if gram_route:
-            w, v, it, reason = _solve_direct_gram(
-                block,
-                offsets,
-                factors_sub,
-                prior,
-                sub_dim=sub_dim,
-                l2_weight=l2_weight,
-                incremental_weight=incremental_weight,
-                gram_mults=gram_mults,
-            )
-            return _scatter_results(w_all, v_all, codes, w, v, it, reason)
+            with solve_scope:
+                w, v, it, reason = _solve_direct_gram(
+                    block,
+                    offsets,
+                    factors_sub,
+                    prior,
+                    sub_dim=sub_dim,
+                    l2_weight=l2_weight,
+                    incremental_weight=incremental_weight,
+                    gram_mults=gram_mults,
+                )
+                return _scatter_results(w_all, v_all, codes, w, v, it, reason)
 
         def direct_solver(xi, xv, lb, off, wt, pm, vm, f, sh, islot, prior_e):
             return _solve_one_entity_direct(
@@ -1261,20 +1288,21 @@ def _solve_block(
                 task=task,
             )
 
-        w, v, it, reason = jax.vmap(direct_solver)(
-            block.x_indices,
-            block.x_values,
-            block.labels,
-            offsets,
-            block.weights,
-            block.penalty_mask,
-            block.valid_mask,
-            factors_sub,
-            shifts_sub,
-            block.intercept_slots,
-            prior,
-        )
-        return _scatter_results(w_all, v_all, codes, w, v, it, reason)
+        with solve_scope:
+            w, v, it, reason = jax.vmap(direct_solver)(
+                block.x_indices,
+                block.x_values,
+                block.labels,
+                offsets,
+                block.weights,
+                block.penalty_mask,
+                block.valid_mask,
+                factors_sub,
+                shifts_sub,
+                block.intercept_slots,
+                prior,
+            )
+            return _scatter_results(w_all, v_all, codes, w, v, it, reason)
 
     if newton:
         if block.x_indices is None:
@@ -1315,21 +1343,22 @@ def _solve_block(
                 incremental_weight=incremental_weight,
             )
 
-        w, v, it, reason = jax.vmap(newton_solver)(
-            block.x_indices,
-            block.x_values,
-            block.labels,
-            offsets,
-            block.weights,
-            block.penalty_mask,
-            block.valid_mask,
-            factors_sub,
-            shifts_sub,
-            block.intercept_slots,
-            w0,
-            prior,
-        )
-        return _scatter_results(w_all, v_all, codes, w, v, it, reason)
+        with solve_scope:
+            w, v, it, reason = jax.vmap(newton_solver)(
+                block.x_indices,
+                block.x_values,
+                block.labels,
+                offsets,
+                block.weights,
+                block.penalty_mask,
+                block.valid_mask,
+                factors_sub,
+                shifts_sub,
+                block.intercept_slots,
+                w0,
+                prior,
+            )
+            return _scatter_results(w_all, v_all, codes, w, v, it, reason)
 
     def solver(xi, xv, lb, off, wt, pm, vm, f, sh, islot, w0_e, prior_e):
         return _solve_one_entity(
@@ -1344,21 +1373,22 @@ def _solve_block(
             incremental_weight=incremental_weight,
         )
 
-    w, v, it, reason = jax.vmap(solver)(
-        block.x_indices,
-        block.x_values,
-        block.labels,
-        offsets,
-        block.weights,
-        block.penalty_mask,
-        block.valid_mask,
-        factors_sub,
-        shifts_sub,
-        block.intercept_slots,
-        w0,
-        prior,
-    )
-    return _scatter_results(w_all, v_all, codes, w, v, it, reason)
+    with solve_scope:
+        w, v, it, reason = jax.vmap(solver)(
+            block.x_indices,
+            block.x_values,
+            block.labels,
+            offsets,
+            block.weights,
+            block.penalty_mask,
+            block.valid_mask,
+            factors_sub,
+            shifts_sub,
+            block.intercept_slots,
+            w0,
+            prior,
+        )
+        return _scatter_results(w_all, v_all, codes, w, v, it, reason)
 
 
 def _scatter_results(w_all, v_all, codes, w, v, it, reason):

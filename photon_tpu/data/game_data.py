@@ -188,6 +188,20 @@ def make_game_dataset(
     uids=None,
     dtype=jnp.float32,
 ) -> GameDataset:
+    """Host arrays -> GameDataset with the raw shards put to the device.
+    An always-recorded stage, ``dataset`` (the dtype conversions, the id
+    vocabularies, and inside it ``raw_transfer``, the enqueue of the one
+    ``device_put``; the copy itself is asynchronous and outlasts it)."""
+    from photon_tpu import obs
+
+    with obs.stage("dataset"):
+        return _make_game_dataset(
+            labels, feature_shards, offsets, weights, id_tags, uids, dtype)
+
+
+def _make_game_dataset(
+    labels, feature_shards, offsets, weights, id_tags, uids, dtype
+) -> GameDataset:
     np_dtype = np.dtype(dtype)
     labels_np = np.asarray(labels, dtype=np_dtype)
     n = labels_np.shape[0]

@@ -278,28 +278,28 @@ class PipelineStats:
 
     @contextlib.contextmanager
     def stage(self, name: str):
-        # Every stage also lands in the unified telemetry layer: a
-        # "pipeline/<stage>" span (worker-thread stages root their own
-        # subtree, labeled by thread) plus a per-stage histogram. Both
-        # are no-ops while telemetry is disabled; this accounting stays
+        # Every stage IS a record of the unified telemetry layer: an
+        # always-recorded ``obs.stage`` of the same name (worker-thread
+        # stages root their own subtree, labeled by thread), which also
+        # puts it on the profiler's clock. The per-stage histogram is
+        # fed only while telemetry is enabled; this accounting stays
         # authoritative either way.
         from photon_tpu import obs
 
         with self._stats_lock:
             gen = self._generation
-        t0 = time.perf_counter()
         try:
-            with obs.span(f"pipeline/{name}"):
+            with obs.stage(name) as sp:
                 yield
         finally:
-            t1 = time.perf_counter()
+            t0, t1 = sp.t0, sp.t1
             with self._stats_lock:
                 # A stale generation token (reset() ran mid-stage, e.g.
                 # an orphaned background compile) records nothing — it
                 # must not pollute the new generation's report. The
                 # telemetry histogram below follows the SAME rule so the
-                # two absorbed views never diverge (the span above still
-                # records: spans are a faithful trace of wall events,
+                # two absorbed views never diverge (the stage above still
+                # records: the ring is a faithful trace of wall events,
                 # not generation accounting).
                 if gen == self._generation:
                     if obs.enabled():

@@ -1093,7 +1093,7 @@ class GameEstimator:
             )
         from photon_tpu import obs
 
-        with obs.span("prepare"):
+        with obs.stage("prepare"):
             datasets = self._build_datasets(data, initial_model)
             val_ctx = (
                 self._build_validation(datasets, validation)

@@ -13,7 +13,9 @@ with the reference's readers bit-compatibly.
 
 from __future__ import annotations
 
+import contextlib
 import io
+import itertools
 import json
 import os
 import struct
@@ -297,48 +299,63 @@ def write_container(
     *,
     codec: str = "deflate",
     sync_interval: int = 4000,
+    encoding=None,
+    writing=None,
 ) -> None:
-    """Write records to an Avro object container file."""
-    schema = Schema(schema_json)
-    sync = os.urandom(SYNC_SIZE)
-    with open(path, "wb") as f:
-        f.write(MAGIC)
+    """Write records to an Avro object container file.
+
+    ``encoding`` and ``writing`` are context managers the caller may hand
+    over to time the call's two halves; each is entered once per piece:
+    ``encoding`` around datum -> the block's bytes (deflate included),
+    ``writing`` around open, the block's bytes -> file, close. The two
+    interleave block by block (``save_game_model`` hands over two
+    ``obs.stage_sum``). What is written, and when, is the same either
+    way."""
+    if encoding is None:
+        encoding = contextlib.nullcontext()
+    if writing is None:
+        writing = contextlib.nullcontext()
+    with encoding:
+        schema = Schema(schema_json)
+        sync = os.urandom(SYNC_SIZE)
         meta = io.BytesIO()
         _encode(meta, _META_SCHEMA, {
             "avro.schema": json.dumps(schema_json).encode(),
             "avro.codec": codec.encode(),
         })
-        f.write(meta.getvalue())
-        f.write(sync)
-
-        block = io.BytesIO()
-        count = 0
-
-        def flush():
-            nonlocal block, count
-            if count == 0:
-                return
-            data = block.getvalue()
-            if codec == "deflate":
-                co = zlib.compressobj(wbits=-15)  # raw deflate stream
-                data = co.compress(data) + co.flush()
-            elif codec != "null":
-                raise ValueError(f"unsupported codec {codec!r}")
-            head = io.BytesIO()
-            _write_long(head, count)
-            _write_long(head, len(data))
-            f.write(head.getvalue())
-            f.write(data)
+    with writing:
+        f = open(path, "wb")
+    try:
+        with writing:
+            f.write(MAGIC)
+            f.write(meta.getvalue())
             f.write(sync)
-            block = io.BytesIO()
-            count = 0
-
-        for rec in records:
-            _encode(block, schema.root, rec)
-            count += 1
-            if count >= sync_interval:
-                flush()
-        flush()
+        records = iter(records)
+        while True:
+            with encoding:
+                block = io.BytesIO()
+                count = 0
+                for rec in itertools.islice(records, sync_interval):
+                    _encode(block, schema.root, rec)
+                    count += 1
+                if count == 0:
+                    break
+                data = block.getvalue()
+                if codec == "deflate":
+                    co = zlib.compressobj(wbits=-15)  # raw deflate stream
+                    data = co.compress(data) + co.flush()
+                elif codec != "null":
+                    raise ValueError(f"unsupported codec {codec!r}")
+                head = io.BytesIO()
+                _write_long(head, count)
+                _write_long(head, len(data))
+            with writing:
+                f.write(head.getvalue())
+                f.write(data)
+                f.write(sync)
+    finally:
+        with writing:
+            f.close()
 
 
 _PROGRAM_OPS = {

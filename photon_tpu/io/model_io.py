@@ -196,7 +196,28 @@ def save_game_model(
     optimization_configurations: dict | None = None,
     sparsity_threshold: float = 0.0,
 ) -> None:
-    """saveGameModelToHDFS equivalent (ModelProcessingUtils.scala:77-130)."""
+    """saveGameModelToHDFS equivalent (ModelProcessingUtils.scala:77-130).
+
+    Always-recorded stages (``obs.stage``): ``save`` around the call and,
+    per coordinate (attr ``coordinate``), ``save.records`` (device ->
+    host pull, per-entity GLMs, Avro datums) and ``save.encode`` /
+    ``save.write``, summed over the blocks ``avro.write_container``
+    interleaves."""
+    from photon_tpu import obs
+
+    with obs.stage("save"):
+        _save_game_model(
+            model, output_dir, index_maps, task,
+            optimization_configurations, sparsity_threshold,
+        )
+
+
+def _save_game_model(
+    model, output_dir, index_maps, task, optimization_configurations,
+    sparsity_threshold,
+) -> None:
+    from photon_tpu import obs
+
     os.makedirs(output_dir, exist_ok=True)
     task = task if task is not None else model.task
     with open(os.path.join(output_dir, METADATA_FILE), "w") as f:
@@ -213,22 +234,19 @@ def save_game_model(
             with open(os.path.join(base, ID_INFO), "w") as f:
                 f.write(sub.feature_shard_id + "\n")
             imap = index_maps[sub.feature_shard_id]
-            coefs = sub.model.coefficients
-            means = np.asarray(coefs.means)
-            rec = _glm_to_record(
-                name,
-                sub.model.task,
-                means,
-                None if coefs.variances is None else np.asarray(coefs.variances),
-                np.arange(means.shape[0]),
-                imap,
-                sparsity_threshold,
-            )
-            avro.write_container(
-                os.path.join(base, COEFFICIENTS, DEFAULT_AVRO_FILE),
-                BAYESIAN_LINEAR_MODEL_SCHEMA,
-                [rec],
-            )
+            with obs.stage("save.records", coordinate=name):
+                coefs = sub.model.coefficients
+                means = np.asarray(coefs.means)
+                records = [_glm_to_record(
+                    name,
+                    sub.model.task,
+                    means,
+                    None if coefs.variances is None
+                    else np.asarray(coefs.variances),
+                    np.arange(means.shape[0]),
+                    imap,
+                    sparsity_threshold,
+                )]
         elif isinstance(sub, RandomEffectModel):
             base = os.path.join(output_dir, RANDOM_EFFECT, name)
             os.makedirs(os.path.join(base, COEFFICIENTS), exist_ok=True)
@@ -236,26 +254,37 @@ def save_game_model(
                 f.write(sub.random_effect_type + "\n")
                 f.write(sub.feature_shard_id + "\n")
             imap = index_maps[sub.feature_shard_id]
-            records = [
-                _glm_to_record(
-                    entity_id,
-                    sub.task,
-                    coefs.means,
-                    coefs.variances,
-                    coefs.feature_indices,
-                    imap,
-                    sparsity_threshold,
-                )
-                for entity_id, coefs in
-                random_effect_model_to_glms(sub).items()
-            ]
+            with obs.stage("save.records", coordinate=name):
+                records = [
+                    _glm_to_record(
+                        entity_id,
+                        sub.task,
+                        coefs.means,
+                        coefs.variances,
+                        coefs.feature_indices,
+                        imap,
+                        sparsity_threshold,
+                    )
+                    for entity_id, coefs in
+                    random_effect_model_to_glms(sub).items()
+                ]
+        else:
+            raise TypeError(f"unknown sub-model type for {name!r}")
+        # Encode and write interleave block by block inside the writer:
+        # one record each per coordinate, summed over the blocks.
+        encoding = obs.stage_sum("save.encode", coordinate=name)
+        writing = obs.stage_sum("save.write", coordinate=name)
+        try:
             avro.write_container(
                 os.path.join(base, COEFFICIENTS, DEFAULT_AVRO_FILE),
                 BAYESIAN_LINEAR_MODEL_SCHEMA,
                 records,
+                encoding=encoding,
+                writing=writing,
             )
-        else:
-            raise TypeError(f"unknown sub-model type for {name!r}")
+        finally:
+            encoding.close()
+            writing.close()
 
 
 def model_feature_shard_ids(model_dir: str) -> set[str]:

@@ -1,7 +1,7 @@
 """photon_tpu.obs — unified runtime telemetry.
 
 One coherent layer over what used to be four unconnected surfaces
-(``utils/timed.py`` section logs, ``data/pipeline.py::PIPELINE_STATS``,
+(ad-hoc section logs, ``data/pipeline.py::PIPELINE_STATS``,
 ``utils/compile_cache.cache_stats()``, and the ``events.py`` listener
 bus): hierarchical **spans** with a host/device split measured only at
 span roots (``obs/spans.py``), a labeled **metrics registry**
@@ -13,7 +13,10 @@ OBSERVABILITY.md).
 
 Telemetry is OFF by default and enabling it is a host-side decision
 only: the device programs are identical either way. That is not a
-promise but an audited contract — see PROGRAM_AUDIT below.
+promise but an audited contract — see PROGRAM_AUDIT below. The one part
+that is always on is ``obs.stage``: the few dozen coarse sections of a
+job (prepare, plan, fit, save, ...) land in the span ring and, as
+``photon.<path>`` annotations, in any profiler session, enabled or not.
 
 Usage::
 
@@ -70,6 +73,11 @@ from photon_tpu.obs.trace import profile_session, write_chrome_trace
 
 TRACER = SpanTracer()
 span = TRACER.span
+# The always-recorded class (obs/spans.py): coarse sections of a job,
+# also TraceAnnotations, never a sync. `stage_sum` is the same for a
+# section that runs in interleaved pieces.
+stage = TRACER.stage
+stage_sum = TRACER.stage_sum
 
 # Program contracts (audited by `python -m photon_tpu.analysis
 # --semantic`; machinery in analysis/program.py build_telemetry /
@@ -187,8 +195,8 @@ def logged_span(msg: str, log: logging.Logger | None = None):
     """A span that also keeps the reference's ``Timed`` logging contract
     ("<msg>: begin execution" / "<msg>: executed in <t> s",
     util/Timed.scala:53-80) — THE one logged-section helper; the CLI
-    drivers and the deprecated ``utils.Timed`` shim all route here so the
-    log contract and the span naming live in a single place."""
+    drivers route here so the log contract and the span naming live in
+    a single place."""
     log = log or logging.getLogger("photon_tpu.timed")
     log.info("%s: begin execution", msg)
     t0 = time.perf_counter()
@@ -259,6 +267,8 @@ __all__ = [
     "set_span_retention",
     "snapshot",
     "span",
+    "stage",
+    "stage_sum",
     "summary_table",
     "trace",
     "validate_jsonl",

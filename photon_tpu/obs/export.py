@@ -16,8 +16,8 @@ JSONL SCHEMA (version 1) — one JSON object per line, discriminated by
 
   {"type": "telemetry", "version": 1, "spans_dropped": 0,
    "host": {...}}  # header, first record; host = fleet identity block
-  {"type": "span", "path", "name", "thread", "seconds",
-   "device_wait_seconds": float|null, "attrs": {}}
+  {"type": "span", "kind": "span"|"stage"|"event", "path", "name",
+   "thread", "seconds", "device_wait_seconds": float|null, "attrs": {}}
   {"type": "counter", "series", "value"}
   {"type": "gauge", "series", "value"}
   {"type": "histogram", "series", "count", "sum", "min", "max"}

@@ -2,7 +2,7 @@
 
 The reference scatters runtime visibility across the Spark UI plus ad-hoc
 ``Timed{}`` wall-clock logging (util/Timed.scala:33); our rebuild had
-grown the same scatter — ``utils/timed.py``, ``PIPELINE_STATS.stage``,
+grown the same scatter — ``Timed`` shims, ``PIPELINE_STATS.stage``,
 per-update ``time.perf_counter()`` in the descent loops. This module is
 the one surface they all feed: thread-safe, hierarchical spans recording
 wall seconds and — at span ROOTS only — the host-vs-device split.
@@ -22,6 +22,16 @@ Design constraints (the audited zero-overhead contract,
   anyway.
 - **Disabled == free.** With the tracer disabled, ``span()`` is a single
   flag check yielding ``None``; no allocation, no lock, no sync.
+- **Stages are always recorded.** ``stage()`` is the one class of span
+  that records whether or not telemetry is enabled: the coarse sections
+  of a job (prepare, plan, fit, save, ...), a few dozen a job. It costs
+  two clock reads, one ring append and one ``TraceAnnotation``; it never
+  syncs. Anything per solver iteration, per coordinate update or per
+  serving batch stays a gated ``span()``.
+- **On the profiler's clock.** Every stage (and every enabled span) holds
+  a ``jax.profiler.TraceAnnotation("photon." + path)`` for its length, so
+  a profiler session shows the program's sections in the xplane's host
+  plane beside the device's operations.
 
 Hierarchy is per thread: each thread keeps its own span stack, and a
 span's ``path`` is its ancestors' names joined with ``/`` (worker-pool
@@ -67,13 +77,34 @@ CONCURRENCY_AUDIT = dict(
 )
 
 
+_annotation_cls = None
+
+
+def _annotation(path: str):
+    """``TraceAnnotation("photon.<path>")``: free while no profiler
+    session runs (jax resolved on first use; obs imports stay jax-free)."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation_cls = TraceAnnotation
+    return _annotation_cls("photon." + path)
+
+
 class Span:
-    """One completed (or in-flight) timed section."""
+    """One completed (or in-flight) timed section.
+
+    ``kind`` tells the three writers of the ring apart: ``span`` (gated
+    by ``obs.enable()``), ``stage`` (always recorded) and ``event`` (a
+    finished record handed over with its duration: compile durations,
+    sums over the blocks of one file). An event's ``seconds`` may be a
+    SUM of intervals inside its [t0, t1] envelope."""
 
     __slots__ = (
         "name",
         "path",
         "thread",
+        "kind",
         "t0",
         "t1",
         "seconds",
@@ -82,10 +113,12 @@ class Span:
         "attrs",
     )
 
-    def __init__(self, name: str, path: str, thread: str):
+    def __init__(self, name: str, path: str, thread: str,
+                 kind: str = "span"):
         self.name = name
         self.path = path
         self.thread = thread
+        self.kind = kind
         self.t0 = 0.0
         self.t1 = 0.0
         self.seconds = 0.0
@@ -101,6 +134,7 @@ class Span:
     def to_json(self) -> dict:
         return {
             "type": "span",
+            "kind": self.kind,
             "path": self.path,
             "name": self.name,
             "thread": self.thread,
@@ -112,6 +146,47 @@ class Span:
             ),
             "attrs": self.attrs or {},
         }
+
+
+class StageSum:
+    """A section that runs in pieces interleaved with another (encode and
+    write, block by block, in one file): each ``with`` adds one interval,
+    held under the profiler annotation like a stage's; ``close()`` leaves
+    ONE record whose ``seconds`` is the sum and whose [t0, t1] is the
+    envelope. Recorded always, as a stage is. One thread."""
+
+    __slots__ = ("_tracer", "_annotation", "_start", "name", "path",
+                 "attrs", "seconds", "intervals", "t0", "t1")
+
+    def __init__(self, tracer: "SpanTracer", name: str, attrs: dict):
+        self._tracer = tracer
+        self.name = name
+        self.path = tracer._path(name)
+        self.attrs = attrs
+        self.seconds = 0.0
+        self.intervals = 0
+        self.t0 = self.t1 = None
+
+    def __enter__(self):
+        self._annotation = _annotation(self.path)
+        self._annotation.__enter__()
+        self._start = time.perf_counter()
+        if self.t0 is None:
+            self.t0 = self._start
+        return self
+
+    def __exit__(self, *exc):
+        self.t1 = time.perf_counter()
+        self.seconds += self.t1 - self._start
+        self.intervals += 1
+        self._annotation.__exit__(*exc)
+
+    def close(self) -> None:
+        if self.intervals:
+            self._tracer.record(
+                self.name, self.seconds, t0=self.t0, t1=self.t1,
+                intervals=self.intervals, **self.attrs)
+            self.intervals = 0
 
 
 class SpanTracer:
@@ -164,6 +239,76 @@ class SpanTracer:
             stack = self._local.stack = []
         return stack
 
+    def _path(self, name: str) -> str:
+        stack = self._stack()
+        return f"{stack[-1].path}/{name}" if stack else name
+
+    def _open(self, name: str, kind: str, attrs: dict | None) -> Span:
+        sp = Span(
+            name, self._path(name), threading.current_thread().name, kind)
+        if attrs:
+            sp.attrs = dict(attrs)
+        self._stack().append(sp)
+        return sp
+
+    def _append(self, sp: Span) -> None:
+        evicted = False
+        with self._lock:
+            if len(self._spans) == self._spans.maxlen:
+                self.dropped += 1
+                evicted = True
+            self._spans.append(sp)
+        if evicted:
+            # Outside the tracer lock (never nest it with the
+            # registry's): retention pressure is a REAL metric — the
+            # snapshot header's spans_dropped only says what was lost,
+            # the counter makes it alertable.
+            from photon_tpu.obs.metrics import REGISTRY
+
+            REGISTRY.counter("spans_dropped_total").inc()
+
+    @contextlib.contextmanager
+    def stage(self, name: str, **attrs):
+        """Record a coarse section of a job ALWAYS, telemetry enabled or
+        not; yields the live Span. No sync: a stage that dispatches
+        device work ends when the dispatch returns."""
+        sp = self._open(name, "stage", attrs)
+        try:
+            with _annotation(sp.path):
+                sp.t0 = time.perf_counter()
+                try:
+                    yield sp
+                finally:
+                    sp.t1 = time.perf_counter()
+                    sp.seconds = sp.t1 - sp.t0
+                    self._append(sp)
+        finally:
+            # Also when the annotation itself could not be built (a jax
+            # that fails to import): the thread's stack must not keep a
+            # dead stage.
+            self._stack().pop()
+
+    def stage_sum(self, name: str, **attrs) -> StageSum:
+        """A stage made of several intervals (see ``StageSum``)."""
+        return StageSum(self, name, attrs)
+
+    def record(self, name: str, seconds: float, *, t0: float | None = None,
+               t1: float | None = None, **attrs) -> Span:
+        """Append a FINISHED record (kind ``event``) under the calling
+        thread's open section: ``seconds`` long, ending at ``t1`` (now
+        when not given) and starting at ``t0`` (``t1 - seconds`` when not
+        given; an earlier ``t0`` makes [t0, t1] the envelope of a sum)."""
+        sp = Span(
+            name, self._path(name), threading.current_thread().name,
+            "event")
+        sp.t1 = time.perf_counter() if t1 is None else t1
+        sp.t0 = sp.t1 - seconds if t0 is None else t0
+        sp.seconds = seconds
+        if attrs:
+            sp.attrs = attrs
+        self._append(sp)
+        return sp
+
     @contextlib.contextmanager
     def span(self, name: str, *, sync=None, attrs: dict | None = None):
         """Record a named section; yields the live Span (or None when
@@ -176,52 +321,41 @@ class SpanTracer:
         if not self.enabled:
             yield None
             return
-        stack = self._stack()
-        path = f"{stack[-1].path}/{name}" if stack else name
-        sp = Span(name, path, threading.current_thread().name)
-        if attrs:
-            sp.attrs = dict(attrs)
+        sp = self._open(name, "span", attrs)
         sp.sync = sync
-        stack.append(sp)
-        sp.t0 = time.perf_counter()
         try:
-            yield sp
+            with _annotation(sp.path):
+                sp.t0 = time.perf_counter()
+                try:
+                    yield sp
+                finally:
+                    t1 = time.perf_counter()
+                    try:
+                        if sp.sync is not None:
+                            import jax
+
+                            # Clear before blocking: don't pin device
+                            # arrays in the record, and a raising sync
+                            # (async device failure surfacing here) must
+                            # not leave them held.
+                            sync, sp.sync = sp.sync, None
+                            jax.block_until_ready(sync)
+                            t_done = time.perf_counter()
+                            sp.device_wait_seconds = t_done - t1
+                            t1 = t_done
+                    finally:
+                        # Record UNCONDITIONALLY: if block_until_ready
+                        # raised, the exception propagates with the span
+                        # recorded.
+                        sp.t1 = t1
+                        sp.seconds = t1 - sp.t0
+                        self._append(sp)
         finally:
-            t1 = time.perf_counter()
-            try:
-                if sp.sync is not None:
-                    import jax
-
-                    # Clear before blocking: don't pin device arrays in
-                    # the record, and a raising sync (async device
-                    # failure surfacing here) must not leave them held.
-                    sync, sp.sync = sp.sync, None
-                    jax.block_until_ready(sync)
-                    t_done = time.perf_counter()
-                    sp.device_wait_seconds = t_done - t1
-                    t1 = t_done
-            finally:
-                # Pop + record UNCONDITIONALLY: if block_until_ready
-                # raised, the exception propagates, but the thread's
-                # span stack must not keep the dead span (every later
-                # span on this thread would inherit its path prefix).
-                sp.t1 = t1
-                sp.seconds = t1 - sp.t0
-                stack.pop()
-                evicted = False
-                with self._lock:
-                    if len(self._spans) == self._spans.maxlen:
-                        self.dropped += 1
-                        evicted = True
-                    self._spans.append(sp)
-                if evicted:
-                    # Outside the tracer lock (never nest it with the
-                    # registry's): retention pressure is a REAL metric —
-                    # the snapshot header's spans_dropped only says what
-                    # was lost, the counter makes it alertable.
-                    from photon_tpu.obs.metrics import REGISTRY
-
-                    REGISTRY.counter("spans_dropped_total").inc()
+            # Pop UNCONDITIONALLY (a raising sync, an annotation that
+            # could not be built): the thread's span stack must not keep
+            # the dead span (every later span on this thread would
+            # inherit its path prefix).
+            self._stack().pop()
 
 
 def aggregate(spans: list[Span]) -> dict[str, dict]:

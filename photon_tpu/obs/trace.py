@@ -32,11 +32,11 @@ zero-overhead guarantee extends to this layer as an audited contract
 (the tier-2 ``trace`` PROGRAM_AUDIT in ``photon_tpu/obs/__init__.py``):
 tracing on vs off leaves every fused program byte-identical.
 
-``profile_session`` is THE device-profiling entry point (it replaces the
-deprecated ``utils/timed.py`` ``profile_trace`` shim): it wraps a block
+``profile_session`` is THE device-profiling entry point: it wraps a block
 in ``jax.profiler.trace`` and brackets it with an obs span + start/stop
-instants, so a captured xplane profile is correlated with the fit-level
-spans by construction.
+instants. Every stage and enabled span is also a ``photon.<path>``
+``TraceAnnotation`` (obs/spans.py), so the captured xplane holds the
+program's sections on the profiler's own clock.
 
 Retention is bounded (``set_retention``; default 8192 events, oldest
 drop first, ``dropped()`` counts the evicted) — the same concern that
@@ -469,8 +469,7 @@ def write_request_jsonl(path: str) -> int:
 
 @contextlib.contextmanager
 def profile_session(trace_dir: str | None, *, name: str = "jax_profiler"):
-    """THE device-profiling entry point (replaces the deprecated
-    ``utils.timed.profile_trace`` shim).
+    """THE device-profiling entry point.
 
     A falsy ``trace_dir`` is a no-op that never touches jax — call sites
     wire it unconditionally. With a directory, the block runs under

@@ -1,16 +1,13 @@
-"""Cross-cutting utilities: section timing + device profiling hooks."""
+"""Cross-cutting utilities: the persistent compile cache and its counters."""
 
 from photon_tpu.utils.compile_cache import (
     cache_stats,
     compile_event_count,
     enable_compilation_cache,
 )
-from photon_tpu.utils.timed import Timed, profile_trace
 
 __all__ = [
-    "Timed",
     "cache_stats",
     "compile_event_count",
     "enable_compilation_cache",
-    "profile_trace",
 ]

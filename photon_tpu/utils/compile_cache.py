@@ -41,7 +41,8 @@ CONCURRENCY_AUDIT = dict(
     locks={
         "_lock": ("_stats", "_listener_installed", "_dir_in_effect"),
     },
-    thread_entries=("_on_event", "aot_compile"),
+    thread_entries=(
+        "_on_event", "_on_trace_start", "_on_duration", "aot_compile"),
     jax_dispatch_ok={
         "aot_compile": "the whole point of the entry: XLA compiles in "
         "C++ with the GIL released on the pipeline's dedicated compile "
@@ -60,9 +61,29 @@ _EVENTS = {
     "/jax/compilation_cache/cache_misses": "persistent_misses",
 }
 
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+
+# Monitoring duration event -> (counter key, record name). JAX publishes
+# them through `record_event_duration_secs` only when something traces,
+# lowers or compiles: a warm call fires none. `backend_compile_duration`
+# wraps the cache lookup, so a cache load is inside it.
+_DURATIONS = {
+    _TRACE_EVENT: ("trace_seconds", "compile.trace"),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration":
+        ("lower_seconds", "compile.lower"),
+    "/jax/core/compile/backend_compile_duration":
+        ("backend_compile_seconds", "compile.backend"),
+    "/jax/compilation_cache/cache_retrieval_time_sec":
+        ("cache_load_seconds", "compile.cache_load"),
+}
+
 _stats = {
     "persistent_hits": 0,
     "persistent_misses": 0,
+    "trace_seconds": 0.0,
+    "lower_seconds": 0.0,
+    "backend_compile_seconds": 0.0,
+    "cache_load_seconds": 0.0,
     # Ingest pipeline's overlapped warm compiles (data/pipeline.py): how
     # many AOT compiles ran in the background and their total seconds —
     # compile work that e2e wall-clock should NOT see when the overlap
@@ -137,6 +158,42 @@ def _on_event(event: str, **kwargs) -> None:
             pass
 
 
+# Open traces of the calling thread: JAX traces a jit met inside another
+# jit's trace within the outer one's duration (hundreds in one fused
+# program), so only the outermost is counted and recorded.
+_tracing = threading.local()
+
+
+def _on_trace_start(event: str, value, **kwargs) -> None:
+    # JAX announces the start of a timed section as a scalar (the start
+    # time) under the section's own event name.
+    if event == _TRACE_EVENT:
+        _tracing.depth = getattr(_tracing, "depth", 0) + 1
+
+
+def _on_duration(event: str, duration: float, **kwargs) -> None:
+    known = _DURATIONS.get(event)
+    if known is None:
+        return
+    if event == _TRACE_EVENT:
+        _tracing.depth = depth = max(getattr(_tracing, "depth", 1) - 1, 0)
+        if depth:
+            return
+    key, name = known
+    with _lock:
+        _stats[key] += duration
+    # A finished record in the tracer's ring, ending now, under the
+    # calling thread's name (the background AOT thread's compiles are
+    # told from the training thread's), JAX's own labels (`fun_name`) as
+    # its attrs. Always recorded, as a stage is.
+    try:
+        from photon_tpu import obs
+
+        obs.TRACER.record(name, duration, **kwargs)
+    except Exception:  # pragma: no cover — telemetry must never abort
+        pass
+
+
 def _install_listener() -> None:
     global _listener_installed
     with _lock:
@@ -149,6 +206,8 @@ def _install_listener() -> None:
         # under the lock so two racing enable calls cannot register
         # the listener (and double-count every event) twice.
         jax.monitoring.register_event_listener(_on_event)
+        jax.monitoring.register_scalar_listener(_on_trace_start)
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
         _listener_installed = True
 
 
@@ -262,4 +321,10 @@ def cache_stats() -> dict:
         "bytes": size,
         "aot_compiles": snap["aot_compiles"],
         "aot_compile_seconds": round(snap["aot_compile_seconds"], 4),
+        # Summed over every thread, from JAX's duration events; a cache
+        # load is inside backend_compile_seconds as well.
+        "trace_seconds": snap["trace_seconds"],
+        "lower_seconds": snap["lower_seconds"],
+        "backend_compile_seconds": snap["backend_compile_seconds"],
+        "cache_load_seconds": snap["cache_load_seconds"],
     }

@@ -625,10 +625,17 @@ class TestEndToEnd:
         from photon_tpu.cli import profile
 
         out = tmp_path / "profile.json"
-        rc = profile.main([
-            "--rows", "128", "--entities", "6", "--fits", "2",
-            "--json", str(out), "--chip", CHIP,
-        ])
+        # The CLI arms telemetry for its process; in-process, hand the
+        # flags back (a later file on this worker asserts they are off).
+        was_obs, was_ledger = obs.enabled(), ledger.enabled()
+        try:
+            rc = profile.main([
+                "--rows", "128", "--entities", "6", "--fits", "2",
+                "--json", str(out), "--chip", CHIP,
+            ])
+        finally:
+            obs.TRACER.enabled = was_obs
+            (ledger.enable if was_ledger else ledger.disable)()
         assert rc == 0
         doc = json.loads(out.read_text())
         assert doc["failures"] == []

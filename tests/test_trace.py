@@ -16,8 +16,7 @@ Covers the PR-8 acceptance surface:
   faults (the `faults.on_crash` listener), chained excepthook,
   uninstall restoring every hook, and the real-subprocess SIGTERM dump
   through `photon train` (the PR-7 pattern);
-- `profile_session` as THE profiling entry point (and the deprecated
-  `utils.profile_trace` shim over it);
+- `profile_session` as THE profiling entry point;
 - the `measured_vs_roofline` bench gate tripping on a deliberately
   slowed fixture (ROADMAP item 2's gating half).
 """
@@ -606,27 +605,6 @@ class TestProfileSession:
             pass
         assert trace.events() == []
         assert obs.TRACER.completed() == []
-
-    def test_deprecated_shim_routes_here(self, telemetry, monkeypatch):
-        import jax
-
-        from photon_tpu.utils import profile_trace
-
-        calls = []
-
-        @contextlib.contextmanager
-        def fake_trace(trace_dir):
-            calls.append(trace_dir)
-            yield
-
-        monkeypatch.setattr(jax.profiler, "trace", fake_trace)
-        with pytest.warns(DeprecationWarning, match="profile_session"):
-            with profile_trace("/tmp/photon-prof"):
-                pass
-        assert calls == ["/tmp/photon-prof"]
-        # the shim inherits the correlation contract
-        assert any(s.name == "jax_profiler"
-                   for s in obs.TRACER.completed())
 
 
 # --------------------------------------------------------------------------
