@@ -292,6 +292,93 @@ def _decode(buf, schema):
 _META_SCHEMA = {"type": "map", "values": "bytes"}
 
 
+def write_blocks(
+    path: str,
+    schema_json: dict,
+    blocks,
+    *,
+    codec: str = "deflate",
+    encoding=None,
+    writing=None,
+) -> dict:
+    """The framing of an Avro object container file around ``blocks``, an
+    iterable of ``(count, raw)``: ``raw`` is the concatenated binary
+    encoding of a block's ``count`` records, before the codec.
+
+    ``encoding`` and ``writing`` are context managers the caller may hand
+    over to time the call's two halves; each is entered once per piece:
+    ``encoding`` around the next block out of ``blocks`` (the end of the
+    iterable included) -> its bytes after the codec, ``writing`` around
+    open, the block's bytes -> file, close. The two interleave block by
+    block (``save_game_model`` hands over two ``obs.stage_sum``). What is
+    written, and when, is the same either way.
+
+    Returns what was written: ``records``, ``bytes_raw`` (the blocks
+    before the codec) and ``bytes_written`` (after it)."""
+    if encoding is None:
+        encoding = contextlib.nullcontext()
+    if writing is None:
+        writing = contextlib.nullcontext()
+    with encoding:
+        sync = os.urandom(SYNC_SIZE)
+        meta = io.BytesIO()
+        _encode(meta, _META_SCHEMA, {
+            "avro.schema": json.dumps(schema_json).encode(),
+            "avro.codec": codec.encode(),
+        })
+    written = {"records": 0, "bytes_raw": 0, "bytes_written": 0}
+    with writing:
+        f = open(path, "wb")
+    try:
+        with writing:
+            f.write(MAGIC)
+            f.write(meta.getvalue())
+            f.write(sync)
+        blocks = iter(blocks)
+        while True:
+            with encoding:
+                block = next(blocks, None)
+                if block is None:
+                    break
+                count, raw = block
+                if codec == "deflate":
+                    co = zlib.compressobj(wbits=-15)  # raw deflate stream
+                    data = co.compress(raw) + co.flush()
+                elif codec == "null":
+                    data = raw
+                else:
+                    raise ValueError(f"unsupported codec {codec!r}")
+                head = io.BytesIO()
+                _write_long(head, count)
+                _write_long(head, len(data))
+            with writing:
+                f.write(head.getvalue())
+                f.write(data)
+                f.write(sync)
+            written["records"] += count
+            written["bytes_raw"] += len(raw)
+            written["bytes_written"] += len(data)
+    finally:
+        with writing:
+            f.close()
+    return written
+
+
+def _interpret_blocks(schema, records, sync_interval: int):
+    """``(count, raw)`` blocks of ``sync_interval`` records, each datum
+    walked through the interpreter ``_encode``."""
+    records = iter(records)
+    while True:
+        block = io.BytesIO()
+        count = 0
+        for rec in itertools.islice(records, sync_interval):
+            _encode(block, schema, rec)
+            count += 1
+        if count == 0:
+            return
+        yield count, block.getvalue()
+
+
 def write_container(
     path: str,
     schema_json: dict,
@@ -302,60 +389,13 @@ def write_container(
     encoding=None,
     writing=None,
 ) -> None:
-    """Write records to an Avro object container file.
-
-    ``encoding`` and ``writing`` are context managers the caller may hand
-    over to time the call's two halves; each is entered once per piece:
-    ``encoding`` around datum -> the block's bytes (deflate included),
-    ``writing`` around open, the block's bytes -> file, close. The two
-    interleave block by block (``save_game_model`` hands over two
-    ``obs.stage_sum``). What is written, and when, is the same either
-    way."""
-    if encoding is None:
-        encoding = contextlib.nullcontext()
-    if writing is None:
-        writing = contextlib.nullcontext()
-    with encoding:
-        schema = Schema(schema_json)
-        sync = os.urandom(SYNC_SIZE)
-        meta = io.BytesIO()
-        _encode(meta, _META_SCHEMA, {
-            "avro.schema": json.dumps(schema_json).encode(),
-            "avro.codec": codec.encode(),
-        })
-    with writing:
-        f = open(path, "wb")
-    try:
-        with writing:
-            f.write(MAGIC)
-            f.write(meta.getvalue())
-            f.write(sync)
-        records = iter(records)
-        while True:
-            with encoding:
-                block = io.BytesIO()
-                count = 0
-                for rec in itertools.islice(records, sync_interval):
-                    _encode(block, schema.root, rec)
-                    count += 1
-                if count == 0:
-                    break
-                data = block.getvalue()
-                if codec == "deflate":
-                    co = zlib.compressobj(wbits=-15)  # raw deflate stream
-                    data = co.compress(data) + co.flush()
-                elif codec != "null":
-                    raise ValueError(f"unsupported codec {codec!r}")
-                head = io.BytesIO()
-                _write_long(head, count)
-                _write_long(head, len(data))
-            with writing:
-                f.write(head.getvalue())
-                f.write(data)
-                f.write(sync)
-    finally:
-        with writing:
-            f.close()
+    """Write records (datums as Python values) to an Avro object container
+    file: the interpreter's blocks in ``write_blocks``' framing."""
+    write_blocks(
+        path, schema_json,
+        _interpret_blocks(Schema(schema_json).root, records, sync_interval),
+        codec=codec, encoding=encoding, writing=writing,
+    )
 
 
 _PROGRAM_OPS = {

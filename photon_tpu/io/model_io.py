@@ -39,7 +39,6 @@ from photon_tpu.models.game import (
     FixedEffectModel,
     GameModel,
     RandomEffectModel,
-    random_effect_model_to_glms,
 )
 from photon_tpu.models.glm import Coefficients, GeneralizedLinearModel
 from photon_tpu.types import TaskType, make_feature_key, split_feature_key
@@ -126,43 +125,124 @@ def _resolve_index(index_map: IndexMap, name: str, term: str) -> int | None:
     return idx
 
 
-def _ntv_list(values: np.ndarray, indices, index_map: IndexMap,
-              sparsity_threshold: float) -> list[dict]:
-    out = []
-    for idx, v in zip(indices, values):
-        if abs(float(v)) <= sparsity_threshold:
-            continue
-        key = index_map.get_feature_name(int(idx))
-        if key is None:
-            raise KeyError(f"feature index {idx} not in index map")
-        name, term = split_feature_key(key)
-        out.append({"name": name, "term": term, "value": float(v)})
-    return out
+def _varints(n: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """Avro longs (zigzag varints) of non-negative ``n [N]``: their bytes
+    ``[N, W]`` padded to the widest, and their widths ``[N]``."""
+    z = n.astype(np.int64) << 1
+    width = max(1, -(-int(z.max(initial=0)).bit_length() // 7))
+    groups = z[:, None] >> (7 * np.arange(width))
+    widths = np.maximum((groups != 0).sum(axis=1), 1)
+    more = np.arange(width) < widths[:, None] - 1
+    return ((groups & 0x7F) | (more << 7)).astype(np.uint8), widths
 
 
-def _glm_to_record(
-    model_id: str,
-    task: TaskType,
+def _model_blocks(
+    entity_ids,
     means: np.ndarray,
     variances: np.ndarray | None,
     indices: np.ndarray,
     index_map: IndexMap,
+    task: TaskType,
     sparsity_threshold: float,
-) -> dict:
-    rec = {
-        "modelId": model_id,
-        "modelClass": _MODEL_CLASS[task],
-        "means": _ntv_list(means, indices, index_map, sparsity_threshold),
-        "variances": None,
-        "lossFunction": _LOSS_CLASS[task],
-    }
-    if variances is not None:
-        # Variances keep the full support (threshold -1), including
-        # coefficients whose mean is exactly zero (L1 solutions).
-        rec["variances"] = _ntv_list(
-            variances, indices, index_map, -1.0
-        )
-    return rec
+    sync_interval: int = 4000,
+):
+    """Raw Avro blocks of BayesianLinearModelAvro records, straight from a
+    coordinate's arrays, for ``avro.write_blocks``.
+
+    ``entity_ids`` (E str), ``means [E, S]``, ``variances [E, S]`` or None,
+    ``indices [E, S]``: the feature index of each slot, -1 for an empty
+    one. A mean is written unless ``abs(v) <= sparsity_threshold`` (a NaN
+    is); variances keep every valid slot, so that an exact-zero mean (L1)
+    keeps its variance. An entity with no valid slot gets no record.
+
+    The call itself prepares what is per coordinate: every entity's
+    encoded id, the encoded name + term of every distinct feature index (a
+    ``KeyError`` for one the index map lacks), the constant runs. The
+    returned iterator then lays one block of ``sync_interval`` records at
+    a time out as a padded byte matrix ``[records, L]`` with a validity
+    mask, so memory is bounded by the block."""
+    rows = np.flatnonzero((indices >= 0).any(axis=1))
+    ids = [entity_ids[e].encode("utf-8") for e in rows]
+    id_lens = np.fromiter(map(len, ids), np.int64, len(ids))
+    id_starts = np.cumsum(id_lens) - id_lens
+    id_bytes = np.frombuffer(b"".join(ids), np.uint8)
+
+    features = np.unique(indices)
+    features = features[features >= 0]
+    prefixes = []
+    for idx in features.tolist():
+        key = index_map.get_feature_name(idx)
+        if key is None:
+            raise KeyError(f"feature index {idx} not in index map")
+        prefixes.append(avro.encode_records("string", split_feature_key(key)))
+    prefix_lens = np.fromiter(map(len, prefixes), np.int64, len(prefixes))
+    prefix_width = int(prefix_lens.max(initial=0))
+    prefix_bytes = np.zeros((len(prefixes), prefix_width), np.uint8)
+    for k, raw in enumerate(prefixes):
+        prefix_bytes[k, :len(raw)] = np.frombuffer(raw, np.uint8)
+
+    optional_string = ["null", "string"]
+    model_class = avro.encode_records(optional_string, [_MODEL_CLASS[task]])
+    loss_class = avro.encode_records(optional_string, [_LOSS_CLASS[task]])
+
+    def constant(raw: bytes, count: int):
+        run = np.broadcast_to(np.frombuffer(raw, np.uint8), (count, len(raw)))
+        return run, np.ones(run.shape, bool)
+
+    def varints(n, keep=True):
+        raw, widths = _varints(n)
+        return raw, (np.arange(raw.shape[1]) < widths[:, None]) & keep
+
+    def items(values, keep, slot_prefix, slot_prefix_lens):
+        """One array of NameTermValueAvro a record: count (none when the
+        array is empty), each kept slot's name + term + double, end."""
+        count, slots = keep.shape
+        kept = keep.sum(axis=1)
+        doubles = values.view(np.uint8).reshape(count, slots, 8)
+        body = np.concatenate([slot_prefix, doubles], axis=2)
+        body_mask = np.concatenate([
+            np.arange(prefix_width) < slot_prefix_lens[:, :, None],
+            np.ones((count, slots, 8), bool)], axis=2) & keep[:, :, None]
+        return [
+            varints(kept, (kept > 0)[:, None]),
+            (body.reshape(count, -1), body_mask.reshape(count, -1)),
+            constant(b"\0", count),
+        ]
+
+    def blocks():
+        for lo in range(0, len(rows), sync_interval):
+            block = rows[lo:lo + sync_interval]
+            count = len(block)
+            slot = indices[block]
+            valid = slot >= 0
+            which = np.searchsorted(features, np.where(valid, slot, 0))
+            slot_prefix = prefix_bytes[which]
+            slot_prefix_lens = prefix_lens[which]
+            # Widened first: the rule compares, and the file holds, float64.
+            mean = means[block].astype("<f8")
+            lens = id_lens[lo:lo + count]
+            at = np.arange(int(lens.max()))
+            id_mask = at < lens[:, None]
+            id_at = np.where(id_mask, id_starts[lo:lo + count, None] + at, 0)
+            parts = [
+                varints(lens),
+                (id_bytes[id_at], id_mask),
+                constant(model_class, count),
+                *items(mean, valid & ~(np.abs(mean) <= sparsity_threshold),
+                       slot_prefix, slot_prefix_lens),
+            ]
+            if variances is None:
+                parts.append(constant(b"\0", count))
+            else:
+                parts.append(constant(b"\2", count))
+                parts += items(variances[block].astype("<f8"), valid,
+                               slot_prefix, slot_prefix_lens)
+            parts.append(constant(loss_class, count))
+            layout = np.concatenate([raw for raw, _ in parts], axis=1)
+            mask = np.concatenate([keep for _, keep in parts], axis=1)
+            yield count, layout[mask].tobytes()
+
+    return blocks()
 
 
 def _record_to_coefficients(
@@ -200,9 +280,10 @@ def save_game_model(
 
     Always-recorded stages (``obs.stage``): ``save`` around the call and,
     per coordinate (attr ``coordinate``), ``save.records`` (device ->
-    host pull, per-entity GLMs, Avro datums) and ``save.encode`` /
-    ``save.write``, summed over the blocks ``avro.write_container``
-    interleaves."""
+    host pull, the encoded entity ids and feature names) and
+    ``save.encode`` / ``save.write``, summed over the blocks
+    ``avro.write_blocks`` interleaves; ``save.encode`` also carries what
+    was written: ``records``, ``bytes_raw``, ``bytes_written``."""
     from photon_tpu import obs
 
     with obs.stage("save"):
@@ -233,41 +314,38 @@ def _save_game_model(
             os.makedirs(os.path.join(base, COEFFICIENTS), exist_ok=True)
             with open(os.path.join(base, ID_INFO), "w") as f:
                 f.write(sub.feature_shard_id + "\n")
-            imap = index_maps[sub.feature_shard_id]
             with obs.stage("save.records", coordinate=name):
+                # One entity, the coordinate's name, with every feature.
                 coefs = sub.model.coefficients
-                means = np.asarray(coefs.means)
-                records = [_glm_to_record(
-                    name,
-                    sub.model.task,
+                means = np.asarray(coefs.means)[None]
+                blocks = _model_blocks(
+                    [name],
                     means,
                     None if coefs.variances is None
-                    else np.asarray(coefs.variances),
-                    np.arange(means.shape[0]),
-                    imap,
+                    else np.asarray(coefs.variances)[None],
+                    np.arange(means.shape[1])[None],
+                    index_maps[sub.feature_shard_id],
+                    sub.model.task,
                     sparsity_threshold,
-                )]
+                )
         elif isinstance(sub, RandomEffectModel):
             base = os.path.join(output_dir, RANDOM_EFFECT, name)
             os.makedirs(os.path.join(base, COEFFICIENTS), exist_ok=True)
             with open(os.path.join(base, ID_INFO), "w") as f:
                 f.write(sub.random_effect_type + "\n")
                 f.write(sub.feature_shard_id + "\n")
-            imap = index_maps[sub.feature_shard_id]
             with obs.stage("save.records", coordinate=name):
-                records = [
-                    _glm_to_record(
-                        entity_id,
-                        sub.task,
-                        coefs.means,
-                        coefs.variances,
-                        coefs.feature_indices,
-                        imap,
-                        sparsity_threshold,
-                    )
-                    for entity_id, coefs in
-                    random_effect_model_to_glms(sub).items()
-                ]
+                blocks = _model_blocks(
+                    [str(key) for key in sub.entity_keys]
+                    or [str(e) for e in range(sub.num_entities)],
+                    np.asarray(sub.coefficients),
+                    None if sub.variances is None
+                    else np.asarray(sub.variances),
+                    sub.proj_all,
+                    index_maps[sub.feature_shard_id],
+                    sub.task,
+                    sparsity_threshold,
+                )
         else:
             raise TypeError(f"unknown sub-model type for {name!r}")
         # Encode and write interleave block by block inside the writer:
@@ -275,13 +353,13 @@ def _save_game_model(
         encoding = obs.stage_sum("save.encode", coordinate=name)
         writing = obs.stage_sum("save.write", coordinate=name)
         try:
-            avro.write_container(
+            encoding.attrs.update(avro.write_blocks(
                 os.path.join(base, COEFFICIENTS, DEFAULT_AVRO_FILE),
                 BAYESIAN_LINEAR_MODEL_SCHEMA,
-                records,
+                blocks,
                 encoding=encoding,
                 writing=writing,
-            )
+            ))
         finally:
             encoding.close()
             writing.close()

@@ -14,6 +14,7 @@ import hashlib
 import os
 import re
 import threading
+import zlib
 
 import jax
 import jax.numpy as jnp
@@ -249,6 +250,7 @@ def _save_model():
 def test_save_leaves_its_split_per_coordinate_and_the_parents_bytes(
         ring, tmp_path, monkeypatch):
     from photon_tpu.data.index_map import IndexMap
+    from photon_tpu.io import avro
     from photon_tpu.io.model_io import save_game_model
 
     monkeypatch.setattr(os, "urandom", lambda n: bytes(range(n)))
@@ -276,6 +278,16 @@ def test_save_leaves_its_split_per_coordinate_and_the_parents_bytes(
                 if r.attrs["coordinate"] == "per-user"}
     assert per_user["save.encode"].attrs["intervals"] == 1 + 3 + 1
     assert per_user["save.write"].attrs["intervals"] == 2 + 3 + 1
+    # What the one path wrote, on each coordinate's encode record.
+    for rec in got["save.encode"]:
+        kind = "fixed" if rec.attrs["coordinate"] == "global" else "random"
+        raw = [data for _, _, data in avro.iter_container_block_bytes(
+            os.path.join(out, f"{kind}-effect", rec.attrs["coordinate"],
+                         "coefficients", "part-00000.avro"))]
+        assert rec.attrs["bytes_raw"] == sum(map(len, raw))
+        assert rec.attrs["bytes_written"] == sum(
+            len(zlib.compress(data, wbits=-15)) for data in raw)
+    assert [r.attrs["records"] for r in got["save.encode"]] == [1, 9001]
 
 
 @pytest.mark.parametrize("case", sorted(PARENT_CONTAINERS))
