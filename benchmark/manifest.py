@@ -1,16 +1,19 @@
 """``BENCHMARK.json`` and the files it names, found by name.
 
 A cell, a configuration, a traffic mix, a traffic kind, a per-layer
-metric and a cell's limits each sit in a file of their own, so a later PR
-adds a file and an entry and edits nothing here.
+metric, a cell's limits, and a configuration's plain reference and data
+generator each sit in a file of their own, so a later PR adds a file and
+an entry and edits nothing here.
 """
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import os
 import re
+import sys
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -20,12 +23,15 @@ def _read_json(path: str):
         return json.load(f)
 
 
+@functools.lru_cache(maxsize=None)
 def _load_module(path: str, tag: str):
     """The Python file at ``path`` as a module of its own (a name of the
-    benchmark may hold dots and dashes, so it is no import name)."""
+    benchmark may hold dots and dashes, so it is no import name), loaded
+    once a process."""
     spec = importlib.util.spec_from_file_location(
         tag + re.sub(r"\W", "_", os.path.basename(path)[:-3]), path)
     module = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = module  # a dataclass looks its module up
     spec.loader.exec_module(module)
     return module
 
@@ -50,6 +56,25 @@ class Manifest:
                 return _read_json(os.path.join(self.root, c["file"]))
         raise KeyError(f"no configuration {name!r} in BENCHMARK.json")
 
+    def _named_module(self, config_name: str, key: str):
+        """The module a configuration names under ``key`` (``reference``
+        or ``generator``): ``benchmark/<key>s/<name>.py``; without the key,
+        ``benchmark/<key>.py``, the one every configuration had so far.
+        Either way a file of THIS checkout, loaded by its path."""
+        name = self.config(config_name).get(key)
+        path = (os.path.join(self.bench_dir, key + ".py") if name is None
+                else os.path.join(self.bench_dir, key + "s", name + ".py"))
+        return _load_module(path, f"benchmark_{key}_")
+
+    def reference(self, config_name: str):
+        """The configuration's plain reference: ``fit(config, data)`` and
+        ``predict(config, data, tables)``."""
+        return self._named_module(config_name, "reference")
+
+    def generator(self, config_name: str):
+        """The configuration's data generator: ``generate(config, seed)``."""
+        return self._named_module(config_name, "generator")
+
     def traffic_path(self, name: str) -> str:
         return os.path.join(self.bench_dir, "traffic", name + ".json")
 
@@ -67,6 +92,9 @@ class Manifest:
         return os.path.join(self.bench_dir, "limits", cell_name + ".json")
 
     def limits(self, cell_name: str) -> dict:
+        """The cell's limits at its own size; the file's ``tiny_limits``
+        are the ones at the configuration's ``tiny`` size, which only the
+        tests' CPU rehearsal reads."""
         return _read_json(self.limits_path(cell_name))["limits"]
 
     def metric_path(self, name: str) -> str:

@@ -101,14 +101,15 @@ def run_cell(man, cell: dict, seed: int, seconds: float, trace: bool,
     object. ``device`` is what ``look_for_chip`` returned."""
     import jax
 
-    from benchmark import check, costs, generator, reference, sut, windows
-    from benchmark import xplane
+    from benchmark import check, costs, sut, windows, xplane
 
     process_start = (time.perf_counter() if process_start is None
                      else process_start)
     config = man.config(cell["config"])
     traffic = man.traffic(cell["traffic"])
     limits = man.limits(cell["name"])
+    generator = man.generator(cell["config"])
+    reference = man.reference(cell["config"])
     sut.configure(config)
 
     # ---- set-up: data from the seed, then the kind's own warm-up
@@ -165,7 +166,8 @@ def run_cell(man, cell: dict, seed: int, seconds: float, trace: bool,
     # ---- the plain reference, once the window has closed
     check_start = time.perf_counter()
     ref_tables = reference.fit(config, data)
-    numbers = check.compare(config, data, answer, ref_tables)
+    numbers = check.compare(config, data, answer, ref_tables,
+                            reference.predict)
     correct, compared = check.verdict(numbers, limits)
     check_s = time.perf_counter() - check_start
 
@@ -227,12 +229,13 @@ def main(argv=None) -> int:
 
     man = Manifest()
     cell = man.cell(args.workload)
-    try:
-        import photon_tpu  # noqa: F401
-    except ImportError as exc:
+    from benchmark import sut
+
+    missing = sut.program_missing()
+    if missing:
         return _refuse(
             EXIT_NO_PROGRAM,
-            f"no program to measure beside the benchmark ({exc})")
+            f"no program to measure beside the benchmark ({missing})")
     device = look_for_chip(int(cell["chips"]))
     out = run_cell(man, cell, args.seed, args.seconds, bool(args.trace),
                    device, process_start=_PROCESS_START)

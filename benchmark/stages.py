@@ -1,16 +1,10 @@
 """The program's own records of the window, for the readers that share
-them: ``photon_tpu.obs.TRACER.completed()`` holds every stage the program
+them: ``sut.stage_records()`` hands over the program's ring, every stage it
 recorded (``obs.stage``: prepare, plan, pack, fit, save, ...) and every
 compile duration JAX published, each with a name, a thread, ``seconds``
 and ``[t0, t1]`` on ``time.perf_counter``, the clock of
-``ctx.window_start``. A program without such records (the ring is empty
-unless its telemetry is switched on) gives every reader here nothing.
-
-This module reads ``photon_tpu.obs`` directly, the one exception to
-``benchmark/sut.py``'s rule that sut alone imports the program: sut may
-not be edited by the PR that brought the stages (a ``benchmark`` issue
-moves the import there). Only a program WITHOUT the ring (ImportError,
-AttributeError) reads as nothing; any other fault raises.
+``ctx.window_start``. A program without such records gives every reader
+here nothing.
 
 The window: a record belongs to it when it starts at or after
 ``ctx.window_start`` and no later than the end of the window's last unit
@@ -26,6 +20,8 @@ The trace's clock: ``run.py`` reads ``window_start`` and enters the
 
 from __future__ import annotations
 
+from benchmark import sut
+
 
 def window_end(ctx):
     """End of the window's last unit on ``time.perf_counter``: the last
@@ -40,17 +36,11 @@ def window_end(ctx):
 def records(ctx, *names: str) -> list:
     """The window's records of those names (all names when none given),
     in the order they were recorded; gated spans are left out."""
-    try:
-        from photon_tpu import obs
-
-        done = obs.TRACER.completed()
-    except (ImportError, AttributeError):  # a program without the ring
-        return []
     end = window_end(ctx)
     if end is None:
         return []
     return [
-        r for r in done
+        r for r in sut.stage_records()
         if ctx.window_start <= r.t0 <= end
         and getattr(r, "kind", "span") != "span"
         and (not names or r.name in names)

@@ -10,6 +10,15 @@ from __future__ import annotations
 import numpy as np
 
 
+def program_missing() -> str | None:
+    """Why there is no program to measure, or None where there is one."""
+    try:
+        import photon_tpu  # noqa: F401
+    except ImportError as exc:
+        return str(exc)
+    return None
+
+
 def configure(config: dict) -> str:
     """Process-wide settings of a configuration, before anything is
     traced: JAX's persistent compile cache at ``<checkout>/.jax_cache``
@@ -170,6 +179,18 @@ def compile_counters() -> dict:
     s = cache_stats()
     return {"hits": s["persistent_hits"], "misses": s["persistent_misses"],
             "dir": s["dir"]}
+
+
+def stage_records() -> list:
+    """Everything in the program's ring of stages and compile durations
+    (``photon_tpu.obs``), oldest first; a program without the ring gives
+    nothing, any other fault raises."""
+    try:
+        from photon_tpu import obs
+
+        return list(obs.TRACER.completed())
+    except (ImportError, AttributeError):
+        return []
 
 
 def pipeline_report() -> dict:

@@ -7,6 +7,8 @@ import shutil
 
 import pytest
 
+from benchmark.manifest import Manifest
+
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
@@ -15,29 +17,48 @@ REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 # number).
 FAKE_DEVICE = {"platform": "cpu", "kind": "TPU v5 lite", "count": 1}
 
-# Limits at the TEST size, from readings on the CPU (x64 on, as the suite
-# runs): the program reads 1e-4..5e-4 on the float32 configuration and the
-# bf16 control 2e-3 on the random effects; the bf16 configuration reads
-# 1e-3..3e-3 and its fp8 control 2e-2..5e-2.
-TINY_LIMITS = {
-    "glmix_ml_logistic": {"coef.global": 2e-3, "coef.per-user": 8e-4,
-                          "coef.per-movie": 8e-4, "score_rms": 6e-4,
-                          "entity_max.per-user": 0.02,
-                          "entity_max.per-movie": 0.02},
-    "glmix_ml_linear": {"coef.global": 6e-4, "coef.per-user": 8e-3,
-                        "coef.per-movie": 6e-3, "score_rms": 4e-3,
-                        "entity_max.per-user": 0.05,
-                        "entity_max.per-movie": 0.05},
-}
-TINY_SIZES = {"rows": 12000, "per-user": 200, "per-movie": 40}
-
 
 def shrink(config: dict) -> dict:
-    config["rows"] = TINY_SIZES["rows"]
+    """The configuration at the CPU size its own ``tiny`` block states:
+    rows and, by coordinate name, entities."""
+    tiny = config["tiny"]
+    config["rows"] = tiny["rows"]
     for c in config["coordinates"]:
-        if c["name"] in TINY_SIZES:
-            c["entities"] = TINY_SIZES[c["name"]]
+        if c["name"] in tiny["entities"]:
+            c["entities"] = tiny["entities"][c["name"]]
     return config
+
+
+def _rewrite(path: str, change) -> None:
+    with open(path) as f:
+        doc = json.load(f)
+    with open(path, "w") as f:
+        json.dump(change(doc), f)
+
+
+def copy_benchmark(source_root: str, root: str) -> str:
+    """``source_root``'s BENCHMARK.json and benchmark files in a new
+    checkout-shaped directory ``root``."""
+    os.makedirs(root)
+    shutil.copy(os.path.join(source_root, "BENCHMARK.json"), root)
+    shutil.copytree(
+        os.path.join(source_root, "benchmark"),
+        os.path.join(root, "benchmark"),
+        ignore=shutil.ignore_patterns("__pycache__"))
+    return root
+
+
+def tiny_copy(source_root: str, root: str) -> str:
+    """``copy_benchmark`` with every configuration cut to its ``tiny``
+    size and every cell's limits replaced by its ``tiny_limits``: all of
+    it read from the data files, whatever they are named."""
+    man = Manifest(copy_benchmark(source_root, root))
+    for entry in man.doc["configs"]:
+        _rewrite(os.path.join(root, entry["file"]), shrink)
+    for cell in man.doc["workloads"]:
+        _rewrite(man.limits_path(cell["name"]),
+                 lambda limits: dict(limits, limits=limits["tiny_limits"]))
+    return root
 
 
 @pytest.fixture(autouse=True)
@@ -54,32 +75,5 @@ def _restore_matmul_precision():
 
 @pytest.fixture()
 def tiny_root(tmp_path):
-    """A checkout-shaped directory with BENCHMARK.json and the benchmark's
-    data files, its configurations cut to TINY_SIZES and its limits to
-    TINY_LIMITS."""
-    root = str(tmp_path / "checkout")
-    os.makedirs(root)
-    shutil.copy(os.path.join(REPO_ROOT, "BENCHMARK.json"), root)
-    shutil.copytree(
-        os.path.join(REPO_ROOT, "benchmark"),
-        os.path.join(root, "benchmark"),
-        ignore=shutil.ignore_patterns("__pycache__"))
-    with open(os.path.join(root, "BENCHMARK.json")) as f:
-        doc = json.load(f)
-    for entry in doc["configs"]:
-        path = os.path.join(root, entry["file"])
-        with open(path) as f:
-            config = shrink(json.load(f))
-        with open(path, "w") as f:
-            json.dump(config, f)
-    for cell in doc["workloads"]:
-        path = os.path.join(root, "benchmark", "limits",
-                            cell["name"] + ".json")
-        with open(path) as f:
-            limits = json.load(f)
-        exact = {k: v for k, v in limits["limits"].items()
-                 if k.endswith("_max_abs")}
-        limits["limits"] = dict(TINY_LIMITS[cell["config"]], **exact)
-        with open(path, "w") as f:
-            json.dump(limits, f)
-    return root
+    """``tiny_copy`` of this repository's benchmark."""
+    return tiny_copy(REPO_ROOT, str(tmp_path / "checkout"))

@@ -40,7 +40,8 @@ def test_the_control_is_not_correct_and_the_program_is(
     control = reference.fit(config, data,
                             storage=CONTROL_STORAGE[config_name])
     ok, compared = check.verdict(
-        check.compare(config, data, {"tables": control}, ref), limits)
+        check.compare(config, data, {"tables": control}, ref,
+                      reference.predict), limits)
     assert not ok, compared
 
     est = sut.build_estimator(config)
@@ -48,7 +49,7 @@ def test_the_control_is_not_correct_and_the_program_is(
     ok, compared = check.verdict(
         check.compare(config, data,
                       {"tables": sut.model_tables(fitted.model, config)},
-                      ref), limits)
+                      ref, reference.predict), limits)
     assert ok, compared
 
 

@@ -57,3 +57,20 @@ def test_the_controls_readings_fail_and_the_programs_pass(cell):
     assert failed and all(
         r["upper_from"] == "control" for name, _, r in numbers
         if name in failed), failed
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_tiny_limits_have_the_keys_of_limits_and_keep_exact_ones_exact(cell):
+    """The limits of the CPU rehearsal sit beside the chip's in the same
+    file (tests/benchmark/conftest.py reads them), number for number."""
+    doc = _read_json(MAN.limits_path(cell))
+    assert list(doc["tiny_limits"]) == list(doc["limits"])
+    for name, limit in doc["limits"].items():
+        if name.endswith("_max_abs"):
+            assert doc["tiny_limits"][name] == limit == 0.0
+        else:
+            assert doc["tiny_limits"][name] > 0.0
+    config = MAN.config(MAN.cell(cell)["config"])
+    assert config["tiny"]["rows"] < config["rows"]
+    assert set(config["tiny"]["entities"]) <= {
+        c["name"] for c in config["coordinates"]}
