@@ -12,7 +12,11 @@ import pytest
 from benchmark import run
 from benchmark.manifest import Manifest
 
-from conftest import FAKE_DEVICE, REPO_ROOT
+from conftest import (
+    FAKE_DEVICE,
+    REPO_ROOT,
+    check_a_configuration_has_a_reference_and_a_generator,
+)
 
 IMPORTS_THE_PROGRAM = re.compile(
     r"^\s*(import photon_tpu|from photon_tpu)\b", re.MULTILINE)
@@ -51,10 +55,8 @@ def test_sut_is_the_one_module_that_imports_the_program():
 @pytest.mark.parametrize(
     "config_name", [c["name"] for c in Manifest().doc["configs"]])
 def test_every_configuration_has_a_reference_and_a_generator(config_name):
-    man = Manifest()
-    reference = man.reference(config_name)
-    assert callable(reference.fit) and callable(reference.predict)
-    assert callable(man.generator(config_name).generate)
+    check_a_configuration_has_a_reference_and_a_generator(
+        Manifest(), config_name)
 
 
 def _write_named(root, key):
@@ -101,10 +103,15 @@ def test_a_named_module_is_its_file_and_no_name_is_the_one_that_is_there(
     find = getattr(man, key)
     assert find("named").MARK == "mine"
     assert find("named").__file__ == path
-    # Without the key: the file of THIS checkout, not sys.path's.
-    assert find("glmix_ml_linear").__file__ == os.path.join(
-        tiny_root, "benchmark", key + ".py")
-    assert find("glmix_ml_linear") is find("glmix_ml_logistic")
+    # Without the key, whatever the configuration is called: the file of
+    # THIS checkout, not sys.path's, and one module for all of them.
+    plain = [c["name"] for c in man.doc["configs"]
+             if key not in man.config(c["name"])]
+    assert len(plain) >= 2 and "named" not in plain
+    for name in plain:
+        assert find(name).__file__ == os.path.join(
+            tiny_root, "benchmark", key + ".py")
+        assert find(name) is find(plain[0])
 
 
 @pytest.mark.parametrize("key", sorted(NAMED))

@@ -1,7 +1,6 @@
 """A CPU rehearsal of each cell at a tiny size, and the refusals: no
 device metric is printed without a chip."""
 
-import json
 import os
 import subprocess
 import sys
@@ -11,7 +10,7 @@ import pytest
 from benchmark import run
 from benchmark.manifest import Manifest
 
-from conftest import FAKE_DEVICE, REPO_ROOT
+from conftest import REPO_ROOT, check_rehearsal_of_a_cell, rehearse
 
 CELLS = [w["name"] for w in Manifest().doc["workloads"]]
 
@@ -44,28 +43,5 @@ def test_the_command_refuses_a_directory_without_the_program(tiny_root):
 @pytest.mark.parametrize("cell", CELLS)
 def test_rehearsal_of_a_cell(tiny_root, cell, trace):
     man = Manifest(tiny_root)
-    out = run.run_cell(man, man.cell(cell), seed=2**31 + 5, seconds=0.5,
-                       trace=bool(trace), device=dict(FAKE_DEVICE))
-    assert list(out)[-1] == "compared"
-    assert out["correct"] is True, out["compared"]
-    assert out["failed"] == 0
-    traffic = man.traffic(man.cell(cell)["traffic"])
-    assert out["attempted"] >= traffic["min_units"]
-    json.dumps(out)
-    names = set(out["metrics"])
-    if not trace:
-        assert names == {m["name"] for m in man.end_to_end(cell)}
-        assert all(v["value"] > 0 for v in out["metrics"].values())
-        assert "busy_s" not in out["device"]
-    else:
-        declared = {m["name"] for m in man.per_layer(cell)}
-        assert names <= declared
-        # No device plane on the CPU: a share of the device is left out of
-        # the line, never printed as 0 or 100.
-        assert not any(n.startswith(("device.idle_share", "kernel."))
-                       for n in names)
-        assert declared - names <= {
-            n for n in declared
-            if n.startswith(("device.idle_share", "kernel."))}
-        assert {"busy_s", "window_s"} <= set(out["device"])
-        assert set(out["breakdown"]) == {"device_ops", "idle_gaps"}
+    check_rehearsal_of_a_cell(
+        man, cell, trace, rehearse(man, cell, trace, seed=2**31 + 5))

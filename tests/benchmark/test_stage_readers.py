@@ -4,10 +4,16 @@ that every cell prints every one of them."""
 
 import pytest
 
-from benchmark import run, stages, xplane
+from benchmark import stages, xplane
 from benchmark.manifest import Manifest
 
-from conftest import FAKE_DEVICE
+from conftest import (
+    cell_names,
+    check_a_metric_of_one_kind_lists_no_cell_of_another,
+    check_a_traced_rehearsal_prints_every_metric_that_lists_the_cell,
+    kinds_of_metric,
+    rehearse,
+)
 
 
 class Rec:
@@ -174,14 +180,16 @@ def test_a_record_after_the_last_unit_moves_no_reader(
 
 
 def test_every_new_metric_is_declared_with_its_reader():
+    """Each is declared, has its reader and is a metric of the kind whose
+    window it reads: it lists at least the cells that kind came with, and
+    no cell of the other kind (conftest.py: the rule for a new cell)."""
     man = Manifest()
     declared = {m["name"]: m for m in man.doc["per_layer"]}
-    assert set(EXPECTED) <= set(declared)
-    refit = {"logistic.refit", "linear.refit"}
-    for name in EXPECTED:
-        cells = set(declared[name]["workloads"])
-        assert cells == (refit if name.endswith(".refit")
-                         else {"linear.retrain"}), name
+    for name in sorted(EXPECTED):
+        assert name in declared and callable(man.metric_reader(name)), name
+        kind = "refit" if name == "fit.host_s.refit" else "retrain_job"
+        assert kinds_of_metric(declared[name]) == {kind}, name
+    check_a_metric_of_one_kind_lists_no_cell_of_another(man)
 
 
 def test_each_moment_goes_to_the_deepest_open_stage(ring):
@@ -214,25 +222,9 @@ def test_each_moment_goes_to_the_deepest_open_stage(ring):
     assert sum(by.values()) == pytest.approx(15.5)
 
 
-@pytest.mark.parametrize(
-    "cell", [w["name"] for w in Manifest().doc["workloads"]])
+@pytest.mark.parametrize("cell", cell_names(Manifest()))
 def test_a_traced_rehearsal_prints_every_new_metric_of_the_cell(
         tiny_root, cell):
     man = Manifest(tiny_root)
-    out = run.run_cell(man, man.cell(cell), seed=2**31 + 11, seconds=0.5,
-                       trace=True, device=dict(FAKE_DEVICE))
-    new = {m["name"] for m in man.per_layer(cell)} & set(EXPECTED)
-    assert new == ({"fit.host_s.refit"} if cell.endswith(".refit")
-                   else set(EXPECTED) - {"fit.host_s.refit"})
-    assert new <= set(out["metrics"])
-    got = {n: out["metrics"][n]["value"] for n in new}
-    assert all(v >= 0.0 for v in got.values())
-    if cell == "linear.retrain":
-        # The split adds up inside what the harness times from outside.
-        m = {n: v["value"] for n, v in out["metrics"].items()}
-        assert (m["save.records_s"] + m["save.encode_s"] + m["save.write_s"]
-                <= m["save.model_s"])
-        assert m["fit.host_s.retrain"] <= m["job.fit_s"]
-        assert m["fit.operands_s"] + m["compile.wait_s"] <= (
-            m["fit.host_s.retrain"])
-        assert m["ingest.plan_wall_s"] <= m["ingest.prepare_s"]
+    check_a_traced_rehearsal_prints_every_metric_that_lists_the_cell(
+        man, cell, rehearse(man, cell, True, seed=2**31 + 11))
