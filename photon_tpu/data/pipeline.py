@@ -290,7 +290,7 @@ class PipelineStats:
             gen = self._generation
         try:
             with obs.stage(name) as sp:
-                yield
+                yield sp
         finally:
             t0, t1 = sp.t0, sp.t1
             with self._stats_lock:

@@ -72,9 +72,23 @@ Array = jax.Array
 
 # Row-count caps for entity size buckets: entities are padded up to the next
 # cap, so worst-case padding waste is bounded within a bucket (SURVEY §7.3).
-# The ratio-4 ladder keeps the number of distinct solver shapes (one jit
-# compile each) small; padding rows carry weight 0 and cost only flops.
-DEFAULT_BUCKET_CAPS = (16, 64, 256, 1024, 4096)
+# Ratio 2: a slab holds under twice its entity's rows, the rule
+# ``_assign_buckets`` applies above the largest cap too. Padding rows carry
+# weight 0 but are not free: every fit gathers the row residuals into the
+# padded slabs, and the slab build the features, and on the chip (TPU v5e)
+# those gathers run at 29-38 M slab rows/s whatever a row holds, so their
+# time goes with the SLAB rows (PERF.md section 6, PR 29: a ratio-4 ladder
+# held 1.75 x the slab rows on the benchmark's GLMix and a fit took 1.45-1.6 x
+# as long).
+DEFAULT_BUCKET_CAPS = (16, 32, 64, 128, 256, 512, 1024, 2048, 4096)
+# The price of a fine ladder: each occupied rung is a solver instance of the
+# fused program, 2-3.5 s of trace on the chip's host in EVERY process (a
+# Newton-kernel rung of 9 / 17 features) and a compile in every new checkout.
+# So ``_assign_buckets`` merges a rung upward when that adds under
+# 1 / _THIN_RUNG of the coordinate's slab rows: 1 / 256 more slab rows cost a
+# 1.3 s fit about 3 ms of gathers, and a daily job is one fit a process, a
+# tuning sweep 16-32.
+_THIN_RUNG = 256
 
 
 @dataclasses.dataclass(frozen=True)
@@ -101,8 +115,9 @@ class RandomEffectDataConfiguration:
     # this merge UPWARD into the next-larger row cap (more padding, but
     # fewer/fatter solver programs — a bucket-tail of a handful of
     # entities otherwise dispatches its own program per warm refit and
-    # instantiates its own solver inside the fused sweep). 0 = off (one
-    # bucket per occupied cap, the historical layout). Shared with the
+    # instantiates its own solver inside the fused sweep). 0 leaves the
+    # planner's own rule alone (``_assign_buckets``: a rung whose merge
+    # pads the slabs by under 1 / ``_THIN_RUNG`` rides up). Shared with the
     # ingest pipeline's shape oracle through ``_assign_buckets`` so
     # predicted block shapes can never drift from built ones.
     min_bucket_entities: int = 0
@@ -1040,11 +1055,13 @@ def _assign_buckets(
     and the ingest pipeline's shape oracle (``predict_plan_shapes``) so
     predicted block shapes can never drift from the built ones.
 
-    ``min_bucket_entities`` > 0 merges undersized buckets UPWARD into
-    the next occupied (or next configured) cap: a warm refit then
-    dispatches fewer, fatter programs instead of paying one launch per
-    bucket-tail. The largest bucket never merges (nothing above holds
-    its rows); merging only ever widens padding, never drops rows."""
+    Undersized buckets merge UPWARD into the next occupied cap: those
+    with fewer than ``min_bucket_entities`` members, and always those
+    whose merge pads the slabs by under 1 / ``_THIN_RUNG`` of the
+    coordinate's slab rows. A warm refit then dispatches fewer, fatter
+    programs instead of paying one solver instance per bucket-tail. The
+    largest bucket never merges (nothing above holds its rows); merging
+    only ever widens padding, never drops rows."""
     caps = np.asarray(sorted(bucket_caps), dtype=np.int64)
     active_ids = np.nonzero(active)[0]
     r = counts[active_ids]
@@ -1063,9 +1080,13 @@ def _assign_buckets(
     members = {
         int(c): active_ids[cap_of == c] for c in np.unique(cap_of)
     }
-    floor = int(min_bucket_entities or 0)
-    if floor > 0 and len(members) > 1:
+    if len(members) > 1:
+        floor = int(min_bucket_entities or 0)
         occupied = sorted(members)
+        # The planner's own tail rule: a rung that saves the coordinate
+        # under 1 / _THIN_RUNG of its slab rows does not pay for the
+        # solver instance it is, and rides up.
+        budget = sum(c * members[c].size for c in occupied) // _THIN_RUNG
         merged: dict[int, np.ndarray] = {}
         pending: np.ndarray | None = None
         for i, cap in enumerate(occupied):
@@ -1073,7 +1094,10 @@ def _assign_buckets(
             if pending is not None:
                 ids = np.union1d(pending, ids)
                 pending = None
-            if ids.size < floor and i < len(occupied) - 1:
+            if i < len(occupied) - 1 and (
+                ids.size < floor
+                or ids.size * (occupied[i + 1] - cap) < budget
+            ):
                 pending = ids  # tail rides up into the next bucket
             else:
                 # The largest bucket always lands here (its cap holds
@@ -1703,10 +1727,21 @@ def build_random_effect_dataset(
         requested_dtype is None
         or jnp.dtype(requested_dtype) == jnp.dtype(game_data.labels.dtype)
     )
-    with PIPELINE_STATS.stage("plan"):
+    with PIPELINE_STATS.stage("plan") as plan_stage:
         plan = _plan_random_effect(
             game_data, config,
             intercept_index=intercept_index, extra_features=extra_features,
+        )
+        # How the bucket ladder engaged, for a trace's reader: slab_rows
+        # over real_rows is this coordinate's padding ratio.
+        buckets = [
+            [cap, int(plan.bucket_members[cap].size)]
+            for cap in sorted(plan.bucket_members)
+        ]
+        plan_stage.attrs = dict(
+            buckets=buckets,
+            slab_rows=sum(cap * b for cap, b in buckets),
+            real_rows=int(plan.counts[plan.active].sum()),
         )
     if lazy is None:
         # An explicit score-table width cap is a signal that max_sub_dim is

@@ -194,8 +194,8 @@ def test_aot_compile_thread_vs_fit_consumption(monkeypatch):
         def stage_hook(name):
             if name == "compile_wait":
                 release.set()
-            with real_stage(name):
-                yield
+            with real_stage(name) as sp:
+                yield sp
 
         monkeypatch.setattr(est, "_warm_compile", gated_warm)
         monkeypatch.setattr(pipeline.PIPELINE_STATS, "stage", stage_hook)

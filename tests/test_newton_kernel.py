@@ -163,6 +163,22 @@ def test_kernel_supported_gates(rng):
         TaskType.LOGISTIC_REGRESSION, jnp.float32, 4096, 17)
 
 
+# (row cap, sub_dim) of the benchmark's GLMix buckets, engaged as on the
+# chip: users x 17 at 32 / 64 / 128 rows and movies x 9 at 256 / 512 under
+# the ratio-2 ladder are all served; the movies' old bucket, 1024 x 9
+# (17.9 MB estimated against the 14 MiB budget), was the one shape the
+# gate refused, and it went to the XLA step.
+@pytest.mark.parametrize("r,s,served", [
+    (32, 17, True), (64, 17, True), (128, 17, True), (256, 17, True),
+    (256, 9, True), (512, 9, True), (1024, 9, False),
+])
+def test_gate_on_the_benchmarks_bucket_shapes(monkeypatch, r, s, served):
+    monkeypatch.setenv("PHOTON_NEWTON_KERNEL", "force")
+    assert nk.kernel_supported(
+        TaskType.LOGISTIC_REGRESSION, jnp.float32, r, s) is served
+    assert (nk._vmem_estimate_bytes(r, s) <= nk._VMEM_BUDGET_BYTES) is served
+
+
 def test_force_flag_on_cpu_selects_kernel_with_interpret(monkeypatch, rng):
     """A force-flagged CPU run must route through interpret=True rather
     than crashing in Mosaic lowering (TPU-only). kernel_supported says

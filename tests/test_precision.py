@@ -23,8 +23,11 @@ from photon_tpu.algorithm.problems import GLMOptimizationConfiguration
 from photon_tpu.data.dataset import DenseFeatures
 from photon_tpu.data.game_data import make_game_dataset
 from photon_tpu.data.random_effect import (
+    DEFAULT_BUCKET_CAPS,
     RandomEffectDataConfiguration,
     _assign_buckets,
+    build_random_effect_dataset,
+    predict_plan_shapes,
 )
 from photon_tpu.estimators.game_estimator import (
     FixedEffectCoordinateConfiguration,
@@ -393,6 +396,203 @@ class TestBucketBatching:
             np.asarray(m_base.models["per-user"].coefficients),
             rtol=1e-4, atol=1e-5,
         )
+
+
+def _pow2_cap(rows):
+    """The ladder's rule, written independently: the next power of two
+    that holds the rows, 16 at least."""
+    return max(16, 1 << (int(rows) - 1).bit_length())
+
+
+# case -> (row counts, min_bucket_entities, expected cap per entity; 0: the
+# entity is in no bucket). All under DEFAULT_BUCKET_CAPS.
+_TAIL = [20] * 3 + [40] * 5 + [100] * 2
+_BODY = [50] * 2000 + [100] * 1000  # 256 000 slab rows: 1 / 256 is 1 000
+LADDER_CASES = {
+    "smallest_rung_is_16": (
+        [1, 8, 16, 17], 0, [16, 16, 16, 32]),
+    # the configured rungs end at 4096; above it the planner rounds to
+    # the next power of two: one rule on both sides
+    "same_rule_both_sides_of_4096": (
+        [2048, 2049, 4096, 4097, 8192, 8193], 0,
+        [2048, 4096, 4096, 8192, 8192, 16384]),
+    "inactive_entities_in_no_bucket": (
+        [0, 5, 300, 0], 0, [0, 16, 512, 0]),
+    # 3 x 32 is under the floor and rides into 64 (8 there: enough);
+    # the largest bucket (2 x 128) never merges
+    "tail_merges_upward": (_TAIL, 4, [64] * 8 + [128] * 2),
+    "tail_cascades_to_the_largest": (_TAIL, 9, [128] * 10),
+    # the planner's own rule, whatever the floor: 4 x 32 rides into 64
+    # (it pads 128 rows more, under 1 / 256 of the slabs), 40 x 32 stays
+    # (1 280 rows more)
+    "thin_rung_rides_up": (
+        [30] * 4 + _BODY, 0, [64] * 2004 + [128] * 1000),
+    "rung_worth_its_padding_stays": (
+        [30] * 40 + _BODY, 0, [32] * 40 + [64] * 2000 + [128] * 1000),
+}
+
+
+class TestDefaultLadder:
+    @pytest.mark.parametrize("case", sorted(LADDER_CASES))
+    def test_default_ladder(self, case):
+        counts, floor, expected = LADDER_CASES[case]
+        counts = np.asarray(counts)
+        members = _assign_buckets(
+            counts, counts >= 1, DEFAULT_BUCKET_CAPS,
+            min_bucket_entities=floor,
+        )
+        cap_of = np.zeros(counts.size, np.int64)
+        for cap, ids in members.items():
+            assert np.all(cap_of[ids] == 0)  # an entity has one bucket
+            cap_of[ids] = cap
+        np.testing.assert_array_equal(cap_of, np.asarray(expected))
+        live = counts >= 1
+        assert np.all(cap_of[live] >= counts[live])  # the slab holds it
+
+    @pytest.mark.parametrize("octave", range(3, 15))
+    def test_a_slab_is_under_twice_its_entitys_rows(self, octave):
+        # Every size of one octave, 2^k < rows <= 2^(k+1), for 8 .. 20 000
+        # rows: one rung, so no merge rule engages, and it is the next
+        # power of two (16 for the smallest).
+        counts = np.arange(8 if octave == 3 else 2 ** octave + 1,
+                           min(20_000, 2 ** (octave + 1)) + 1)
+        members = _assign_buckets(
+            counts, counts >= 1, DEFAULT_BUCKET_CAPS)
+        (cap,) = members
+        assert cap == _pow2_cap(counts[-1]) == _pow2_cap(counts[0])
+        assert members[cap].size == counts.size
+        assert counts[-1] <= cap and (cap < 2 * counts[0] or cap == 16)
+
+    def test_the_default_is_the_powers_of_two(self):
+        assert DEFAULT_BUCKET_CAPS == tuple(2 ** k for k in range(4, 13))
+        assert (RandomEffectDataConfiguration("userId", "u").bucket_caps
+                == DEFAULT_BUCKET_CAPS)
+
+
+RATIO_4_CAPS = (16, 64, 256, 1024, 4096)
+
+
+def _ladder_workload(task, seed=0, dtype=jnp.float32):
+    """A small GLMix whose two random coordinates spread over many rungs:
+    users of 9 .. 600 rows (seven rungs of the default ladder, four of
+    the ratio-4 one), items of 30 .. 330."""
+    rng = np.random.default_rng(seed)
+    user_sizes = np.repeat(
+        [9, 12, 20, 27, 40, 55, 70, 100, 150, 200, 300, 600], 2)
+    n = int(user_sizes.sum())
+    uid = rng.permutation(np.repeat(np.arange(user_sizes.size), user_sizes))
+    item_sizes = rng.multinomial(n - 30 * 18, np.arange(1, 19) / 171) + 30
+    iid = rng.permutation(np.repeat(np.arange(18), item_sizes))
+    d, du, di = 8, 5, 3
+    x, xu, xi = (rng.normal(size=(n, k)).astype(np.float32)
+                 for k in (d, du, di))
+    for a in (x, xu, xi):
+        a[:, -1] = 1.0
+    w = 0.3 * rng.normal(size=d).astype(np.float32)
+    wu = 0.3 * rng.normal(size=(user_sizes.size, du)).astype(np.float32)
+    wi = 0.2 * rng.normal(size=(18, di)).astype(np.float32)
+    z = (x @ w + np.einsum("nd,nd->n", xu, wu[uid])
+         + np.einsum("nd,nd->n", xi, wi[iid]))
+    if task == TaskType.LOGISTIC_REGRESSION:
+        y = (rng.uniform(size=n) < 1 / (1 + np.exp(-z))).astype(np.float32)
+    else:
+        y = (z + 0.2 * rng.normal(size=n)).astype(np.float32)
+    return make_game_dataset(
+        y, {"g": DenseFeatures(x), "u": DenseFeatures(xu),
+            "i": DenseFeatures(xi)},
+        id_tags={"userId": uid, "itemId": iid},
+        dtype=dtype,
+    )
+
+
+def _ladder_fit(task, data, precision, **re_kwargs):
+    est = GameEstimator(
+        task,
+        {
+            "global": FixedEffectCoordinateConfiguration("g", _l2(1e-2)),
+            "per-user": RandomEffectCoordinateConfiguration(
+                RandomEffectDataConfiguration("userId", "u", **re_kwargs),
+                _l2(1.0)),
+            "per-item": RandomEffectCoordinateConfiguration(
+                RandomEffectDataConfiguration("itemId", "i", **re_kwargs),
+                _l2(1.0)),
+        },
+        num_iterations=2,
+        mesh="off",
+        precision=precision,
+    )
+    datasets, _ = est.prepare(data)
+    caps = {
+        cid: sorted(int(b.row_ids.shape[1]) for b in datasets[cid].blocks)
+        for cid in ("per-user", "per-item")
+    }
+    model = est.fit(data)[0].model
+    assert est._fused_cache  # the fused whole-fit program ran
+    return model, caps
+
+
+class TestLadderParity:
+    """The ladder decides how much padding a slab carries, never what is
+    fitted: padding rows carry weight 0, so the default ladder and the
+    ratio-4 one it replaced give the same model."""
+
+    # (task, precision, data dtype, coefficient atol, score atol). Padding
+    # only lengthens sums by zeros: the closed form reads alike to float32
+    # rounding, and in float64 so does the Newton route. In float32 a
+    # Newton loop ends where its float32 OBJECTIVE stops improving, which
+    # leaves a coefficient within about the root of that rounding (some
+    # 1e-3 here and under either ladder: the benchmark's limits on
+    # ``coef.*`` are of that size); the summation length moves the point.
+    @pytest.mark.parametrize(
+        "task,precision,dtype,coef_atol,score_atol",
+        [(TaskType.LINEAR_REGRESSION, "bfloat16", jnp.float32, 1e-6, 1e-6),
+         (TaskType.LOGISTIC_REGRESSION, "float32", jnp.float64, 1e-12,
+          1e-12),
+         (TaskType.LOGISTIC_REGRESSION, "float32", jnp.float32, 3e-3,
+          1e-2)],
+        ids=["linear_bf16_direct", "logistic_f64_newton",
+             "logistic_f32_newton"],
+    )
+    def test_same_model_under_both_ladders(
+            self, task, precision, dtype, coef_atol, score_atol):
+        from photon_tpu.transformers import GameTransformer
+
+        data = _ladder_workload(task, dtype=dtype)
+        m2, caps2 = _ladder_fit(task, data, precision)
+        m4, caps4 = _ladder_fit(
+            task, data, precision, bucket_caps=RATIO_4_CAPS)
+        assert caps2["per-user"] == [16, 32, 64, 128, 256, 512, 1024]
+        assert caps4["per-user"] == [16, 64, 256, 1024]
+        assert len(caps2["per-item"]) > len(caps4["per-item"])
+        np.testing.assert_allclose(
+            np.asarray(m2.models["global"].model.coefficients.means),
+            np.asarray(m4.models["global"].model.coefficients.means),
+            rtol=0, atol=coef_atol)
+        for cid in ("per-user", "per-item"):
+            np.testing.assert_allclose(
+                np.asarray(m2.models[cid].coefficients),
+                np.asarray(m4.models[cid].coefficients),
+                rtol=0, atol=coef_atol, err_msg=cid)
+        np.testing.assert_allclose(
+            np.asarray(GameTransformer(m2).score(data)),
+            np.asarray(GameTransformer(m4).score(data)),
+            rtol=0, atol=score_atol)
+
+    @pytest.mark.parametrize("min_bucket_entities", [0, 3])
+    def test_shape_oracle_equals_the_built_shapes(self, min_bucket_entities):
+        data = _ladder_workload(TaskType.LINEAR_REGRESSION)
+        cfg = RandomEffectDataConfiguration(
+            "userId", "u", min_bucket_entities=min_bucket_entities)
+        pred = predict_plan_shapes(data, cfg)
+        ds = build_random_effect_dataset(data, cfg, intercept_index=None)
+        built = [(int(b.row_ids.shape[1]), int(b.row_ids.shape[0]))
+                 for b in ds.blocks]
+        assert [(cap, b) for cap, b, _ in pred["buckets"]] == built
+        assert len(built) >= 4  # at least four rungs occupied
+        assert pred["packed_shapes"] == ds.packed_view.shapes
+        # and the padded slabs hold under twice the rows they pad
+        counts = np.bincount(np.asarray(data.id_tags["userId"].host_codes()))
+        assert sum(c * b for c, b in built) < 2 * counts.sum()
 
 
 class TestDonationSafety:

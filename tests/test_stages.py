@@ -11,6 +11,7 @@ per coordinate without changing a byte of what it writes.
 from __future__ import annotations
 
 import hashlib
+import json
 import os
 import re
 import threading
@@ -185,6 +186,29 @@ def test_pipeline_report_is_what_the_recorded_stages_add_up_to(ring):
         max(r.t1 for r in plans) - min(r.t0 for r in plans), abs=1e-4)
     assert all(r.kind == "stage" for r in _done(ring)
                if not r.name.startswith("compile."))
+
+
+def test_a_plan_stage_says_how_the_bucket_ladder_engaged(ring):
+    from photon_tpu.analysis import program
+
+    with jax.enable_x64(False):
+        est, data = program._tiny_glmix()
+        datasets, _ = est.prepare(data)
+    built = {
+        cid: [[int(b.row_ids.shape[1]), int(b.row_ids.shape[0])]
+              for b in ds.blocks]
+        for cid, ds in datasets.items() if hasattr(ds, "blocks")
+    }
+    plans = _by_name(ring)["plan"]
+    assert len(plans) == len(built) >= 1
+    for rec in plans:
+        assert set(rec.attrs) == {"buckets", "slab_rows", "real_rows"}
+        assert rec.attrs["buckets"] in built.values()
+        assert rec.attrs["slab_rows"] == sum(
+            cap * b for cap, b in rec.attrs["buckets"])
+        assert 0 < rec.attrs["real_rows"] <= rec.attrs["slab_rows"]
+        assert rec.attrs["real_rows"] <= data.num_samples
+        json.dumps(rec.to_json())  # attributes an exporter can write
 
 
 # ---------------------------------------------------------------------------
