@@ -118,8 +118,8 @@ class Smoke:
 
 def synth_arrays(rows, features, users, user_features, movies,
                  movie_features, seed=SEED):
-    """MovieLens-shaped logistic GLMix data from a seed (bench.py's
-    generator: entities drawn uniformly, last column the intercept)."""
+    """MovieLens-shaped logistic GLMix data from a seed (entities drawn
+    uniformly, last column the intercept)."""
     rng = np.random.default_rng(seed)
 
     def shard(d):
@@ -160,9 +160,9 @@ def game_dataset(a):
 
 
 def estimator(sizes: Sizes, mesh):
-    """bench.py's GLMix estimator (bench.py build_estimator) at the
-    package defaults: precision float32, so all three kernels are
-    eligible."""
+    """The GLMix estimator of the benchmark's configurations
+    (``benchmark/sut.py``) at the package defaults: precision float32,
+    so all three kernels are eligible."""
     from photon_tpu import optim
     from photon_tpu.algorithm.problems import GLMOptimizationConfiguration
     from photon_tpu.data.random_effect import RandomEffectDataConfiguration

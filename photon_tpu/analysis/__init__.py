@@ -14,7 +14,8 @@ points are traced under abstract shapes (no device execution — CPU CI is
 enough) and the jaxprs/lowered HLO are checked against contracts each
 audited module declares (dispatch census, recompile-key stability,
 host-boundary and f64 audits, mesh sharding, and a static FLOP/HBM cost
-model for the roofline numbers bench.py compares against).
+model for the roofline numbers ``obs/ledger.py`` sets measured seconds
+against).
 
 Tier 3 (``--concurrency``; analysis/concurrency.py) audits the THREADED
 HOST RUNTIME: a pure-``ast`` lockset lint (Eraser-style) checked against

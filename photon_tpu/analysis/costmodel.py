@@ -4,13 +4,14 @@
 the *unoptimized* module — no compilation, no device — and returns FLOP
 and bytes-accessed counts per program. Dividing by the target chip's
 peaks gives a roofline lower bound on runtime per dispatch, which is the
-number ``bench.py`` compares measured throughput against
-(measured-vs-predicted utilization).
+number ``obs/ledger.py`` sets a program's measured seconds against
+(``photon profile``'s measured-vs-predicted rows).
 
 These are COMPILER counts, not the analytic model-FLOP counts in
-``bench.py`` (which exclude padding): the two deliberately bracket the
-truth — cost_analysis counts every padded lane the program will really
-execute, the analytic count only the useful model work.
+``benchmark/costs.py`` (which count real rows only): the two
+deliberately bracket the truth — cost_analysis counts every padded lane
+the program will really execute, the analytic count only the useful
+model work.
 """
 
 from __future__ import annotations
@@ -130,7 +131,7 @@ def roofline(
     ``min_seconds`` is the per-dispatch lower bound at the chip's peaks;
     ``bound`` names the resource that sets it. Arithmetic intensity below
     the chip's ridge point (peak_flops / peak_hbm) means HBM-bound — the
-    expected regime for GLM training (bench.py module docstring).
+    expected regime for GLM training.
     """
     peaks = CHIP_PEAKS[chip]
     flops = float(cost.get("flops", 0.0))
@@ -150,27 +151,11 @@ def roofline(
 def program_report(
     lowered: Any, chip: str = TARGET_CHIP
 ) -> dict[str, Any]:
-    """cost + roofline for one lowered program (bench/report entry)."""
+    """cost + roofline for one lowered program."""
     cost = program_cost(lowered)
     out = dict(cost)
     out["roofline"] = roofline(cost, chip)
     return out
-
-
-def fused_fit_report(
-    fused: Any, coords: dict, chip: str = TARGET_CHIP
-) -> dict[str, Any]:
-    """Per-program predicted cost of one FusedFit generation.
-
-    Lowers (never executes) the whole-fit program and the slab
-    materialization program for the given coordinate structure — the two
-    dispatches of a fused fit — and returns
-    ``{program_name: {flops, hbm_bytes, roofline}}``.
-    """
-    return {
-        "fused_fit": program_report(fused.lower(coords), chip),
-        "materialize": program_report(fused.lower_materialize(coords), chip),
-    }
 
 
 # --------------------------------------------------------------------------

@@ -35,8 +35,8 @@ execution — CPU CI is enough:
 - **The admission oracle**: :func:`predict_resident_bytes` — the
   static "will this model + ladder + precision fit" half that ROADMAP
   items 3/5 call, keyed to match the ledger's ``table/<coordinate>``
-  owners byte-for-byte (pinned by tests and by bench's
-  ``predicted_vs_measured_hbm`` join against the measured watermark).
+  owners byte-for-byte (pinned by ``tests/test_analysis_memory.py``
+  against the ledger's measured resident rows).
 
 Run it: ``python -m photon_tpu.analysis --memory``. Exit codes follow
 the other tiers: 0 clean, 1 unsuppressed findings, 2 usage error.
@@ -444,7 +444,7 @@ def predict_resident_bytes(
     Keys under ``"tables"`` are exactly the ledger's resident owners
     (``table/<coordinate>``; serve/tables.account_resident), so the
     prediction can be joined byte-for-byte against the measured
-    watermark — bench.py's ``predicted_vs_measured_hbm``.
+    watermark.
 
     ``rebuild_peak_bytes`` is the transient high-water mark of a
     structure-changing ``rebuild_from``: the new generation is built

@@ -94,7 +94,7 @@ def _tiny_workload(rows: int, entities: int, iterations: int):
 
 def _fit_once(est, data):
     """One blocking fit (checksum-forced completion — enqueue times
-    are not measurements; same idiom as bench.py)."""
+    are not measurements)."""
     import jax.numpy as jnp
     import numpy as np
 

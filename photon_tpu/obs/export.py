@@ -3,8 +3,7 @@
 Three views over the same state (span tracer + metrics registry +
 convergence traces + the pipeline/compile-cache reports they absorb):
 
-- ``snapshot()``: one JSON-ready dict — what ``bench.py`` embeds under
-  ``"telemetry"`` and what the train CLI folds into
+- ``snapshot()``: one JSON-ready dict — what the train CLI folds into
   ``training-summary.json``;
 - ``write_jsonl(path)``: the documented line-per-record stream
   (schema: OBSERVABILITY.md; ``validate_jsonl`` is the shared validator

@@ -737,7 +737,7 @@ def crosscheck_collective_census(report: dict, census_ops) -> dict:
     presenting exactly the deadlock signature the ``--spmd``
     collective-order rule proves against. The entry is stored under
     ``report["collective_census"]`` (read by :func:`multichip_row` for
-    the benchtrend ``multichip_collective_count`` gauge) and returned.
+    the row's ``multichip_collective_count``) and returned.
     """
     ops = [str(o) for o in census_ops]
     mismatches: list[str] = []
@@ -763,11 +763,12 @@ def multichip_row(report: dict, *, n_devices: int | None = None) -> dict:
     """Flatten a straggler report into the MULTICHIP_r*.json row shape.
 
     Schema 2 keeps the driver-era keys (``n_devices``, ``ok``) and adds
-    the structured attribution benchtrend tracks (the ``multichip_*``
-    gauges — since PR 20 also the dryrun wall clock, the hosts-reporting
+    the structured attribution (the ``multichip_*`` keys: skew,
+    collective fraction, the dryrun wall clock, the hosts-reporting
     count, and the static collective count when
     :func:`crosscheck_collective_census` ran); the full report rides
-    along under ``"report"``."""
+    along under ``"report"``. ``__graft_entry__.py``'s multichip dryrun
+    writes it."""
     row = {
         "schema": 2,
         "n_devices": n_devices,

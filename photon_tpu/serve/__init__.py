@@ -26,8 +26,7 @@ GameScoringDriver); this package is its low-latency twin for the TPU build:
 - **Synchronous driver** (``serve/driver.py``): feeds requests from a
   dataset or a synthetic generator (no network dependency) and reports
   p50/p99 latency, QPS, batch-fill fraction, and cold-entity rate —
-  the fields ``bench.py``'s ``serving`` scenario and
-  ``python -m photon_tpu.cli.serve`` emit.
+  the fields ``python -m photon_tpu.cli.serve`` emits.
 
 Architecture, tuning knobs, and the zero-recompile contract: SERVING.md.
 """

@@ -741,7 +741,7 @@ class MicroBatchQueue:
         """One consistent degraded-mode snapshot: queue depth, breaker
         state, shed/deadline/error/retry counters, and the coefficient
         tables' reload generation — what a load balancer's health probe
-        (and ``cli.serve`` / ``bench.py``) reads."""
+        (and ``cli.serve``) reads."""
         with self._cond:
             per_coord = {
                 nm: dict(cs) for nm, cs in self._coord_stats.items()

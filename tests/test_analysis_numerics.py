@@ -408,34 +408,3 @@ def test_repo_audit_reports_flow_facts():
     assert {"score_b1", "score_b8"} <= set(serving)
     assert report["waivers"] == N.TIER2_WAIVERS
 
-
-# ---------------------------------------------------------------------------
-# satellite: the bf16-vs-f32 parity gap rides the bench trend gate
-# ---------------------------------------------------------------------------
-
-
-def test_parity_gap_metrics_are_tracked():
-    from photon_tpu.cli import benchtrend
-
-    for fam in ("linear", "logistic", "poisson", "smoothed_hinge"):
-        name = f"parity_gap_{fam}"
-        assert name in benchtrend.TRACKED
-        direction, tol, _ = benchtrend.TRACKED[name]
-        assert direction == "lower"
-        assert tol == 1.5
-
-
-def test_parity_gap_trend_gates_and_passes():
-    from photon_tpu.cli import benchtrend
-
-    history = [
-        ("r1", {"parity_gap_poisson": 0.0034}),
-        ("r2", {"parity_gap_poisson": 0.0031}),
-    ]
-    ok = benchtrend.analyze(history + [("r3", {"parity_gap_poisson": 0.0040})])
-    assert not [r for r in ok["regressions"] if "parity_gap" in r]
-    bad = benchtrend.analyze(history + [("r3", {"parity_gap_poisson": 0.0060})])
-    assert any(
-        "parity_gap_poisson" in r and "lower is better" in r
-        for r in bad["regressions"]
-    )

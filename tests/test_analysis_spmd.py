@@ -19,8 +19,8 @@ Layout mirrors the tier-4/5 test files:
   citation the 6 xfailed column-sharding tests now carry;
 - the gate: ``python -m photon_tpu.analysis --spmd`` exits 0 over the
   repo's declared contracts, and the satellite plumbing (costmodel
-  pricing, fleet census join, benchtrend multichip gauges) is pinned
-  here too since tier 6 feeds all three.
+  pricing, fleet census join) is pinned here too since tier 6 feeds
+  both.
 """
 
 from __future__ import annotations
@@ -35,7 +35,6 @@ from photon_tpu.analysis import costmodel  # noqa: E402
 from photon_tpu.analysis import program as program_mod  # noqa: E402
 from photon_tpu.analysis import spmd as S  # noqa: E402
 from photon_tpu.analysis.__main__ import main as cli_main  # noqa: E402
-from photon_tpu.cli import benchtrend  # noqa: E402
 from photon_tpu.obs import fleet  # noqa: E402
 
 P = pytest.importorskip("jax.sharding").PartitionSpec
@@ -486,7 +485,7 @@ class TestContractHygiene:
 
 
 # --------------------------------------------------------------------------
-# the fleet census join + benchtrend gauges (satellite plumbing)
+# the fleet census join (satellite plumbing)
 # --------------------------------------------------------------------------
 
 
@@ -527,50 +526,6 @@ class TestFleetCensusJoin:
     def test_row_without_census_omits_the_gauge(self):
         row = fleet.multichip_row(self._report(), n_devices=8)
         assert "multichip_collective_count" not in row
-
-
-class TestBenchtrendMultichip:
-    def test_dotted_fallback_reaches_nested_report(self):
-        parsed = {"report": {"wall_seconds": 4.5}, "bundles": 2}
-        assert benchtrend.metric_value(
-            parsed, "multichip_wall_seconds", benchtrend.MULTICHIP_TRACKED
-        ) == 4.5
-        assert benchtrend.metric_value(
-            parsed, "multichip_hosts_reporting",
-            benchtrend.MULTICHIP_TRACKED,
-        ) == 2.0
-
-    def test_hosts_reporting_drop_regresses(self):
-        rounds = [
-            ("r01", {"multichip_hosts_reporting": 2}),
-            ("r02", {"multichip_hosts_reporting": 1}),
-        ]
-        rep = benchtrend.analyze(
-            rounds, tracked=benchtrend.MULTICHIP_TRACKED
-        )
-        assert any(
-            "multichip_hosts_reporting" in r for r in rep["regressions"]
-        )
-
-    def test_collective_count_growth_regresses(self):
-        rounds = [
-            ("r01", {"multichip_collective_count": 1}),
-            ("r02", {"multichip_collective_count": 3}),
-        ]
-        rep = benchtrend.analyze(
-            rounds, tracked=benchtrend.MULTICHIP_TRACKED
-        )
-        assert any(
-            "multichip_collective_count" in r for r in rep["regressions"]
-        )
-
-    def test_absent_gauge_is_skipped_not_regressed(self):
-        rounds = [("r01", {"bundles": 2}), ("r02", {"bundles": 2})]
-        rep = benchtrend.analyze(
-            rounds, tracked=benchtrend.MULTICHIP_TRACKED
-        )
-        assert "multichip_collective_count" not in rep["metrics"]
-        assert rep["regressions"] == []
 
 
 # --------------------------------------------------------------------------

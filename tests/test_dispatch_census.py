@@ -9,7 +9,8 @@ operand promoted to a static — fails loudly here, not silently on the
 TPU bill.
 
 Also the first coverage for utils/compile_cache.cache_stats (the
-hit/miss instrumentation aimed at the BENCH_r05 warm-cache anomaly).
+hit/miss counters ``benchmark/sut.py`` reads for ``compile.events.refit``
+and ``compile.cache_loads.retrain``).
 """
 
 from __future__ import annotations

@@ -7,9 +7,8 @@ commit-point discipline), the fleet merge (synthetic two-host bundles
 with a KNOWN injected clock offset landing monotonic on one timeline
 within the reported skew bound), degradation (torn spans.jsonl, missing
 rank — named gaps, never a crash), the straggler/collective rollup,
-monitor-port arbitration (two in-process exporters coexisting), the
-MULTICHIP row artifact, and the benchtrend multichip gauge series
-(old rc/tail rounds tolerated).
+monitor-port arbitration (two in-process exporters coexisting) and the
+MULTICHIP row artifact.
 """
 
 from __future__ import annotations
@@ -482,7 +481,7 @@ def test_two_rank_exporters_coexist_on_offset_ports(telemetry):
 
 
 # ---------------------------------------------------------------------------
-# MULTICHIP row + benchtrend multichip series
+# MULTICHIP row
 # ---------------------------------------------------------------------------
 
 
@@ -509,74 +508,15 @@ def test_multichip_row_not_ok_with_gaps(tmp_path):
     assert fleet.multichip_row(report)["ok"] is False
 
 
-def test_write_multichip_row_takes_next_slot(tmp_path):
-    (tmp_path / "MULTICHIP_r01.json").write_text("{}")
+@pytest.mark.parametrize(
+    "present, slot",
+    [(["MULTICHIP_r01.json"], "MULTICHIP_r02.json"),
+     ([], "MULTICHIP_r01.json")],  # the repo's own state: no row committed
+    ids=["after-r01", "empty-dir"],
+)
+def test_write_multichip_row_takes_next_slot(tmp_path, present, slot):
+    for name in present:
+        (tmp_path / name).write_text("{}")
     path = fleet.write_multichip_row({"ok": True}, root=str(tmp_path))
-    assert os.path.basename(path) == "MULTICHIP_r02.json"
+    assert os.path.basename(path) == slot
     assert json.load(open(path)) == {"ok": True}
-
-
-def _old_schema_row(path, rc=0):
-    path.write_text(json.dumps({
-        "n_devices": 8, "rc": rc, "ok": rc == 0, "skipped": False,
-        "tail": ["connecting to gloo", "all done"],
-    }))
-
-
-def test_benchtrend_multichip_series_tolerates_old_schema(tmp_path, capsys):
-    from photon_tpu.cli import benchtrend
-
-    # a bench round so the primary table has history
-    (tmp_path / "BENCH_r01.json").write_text(
-        json.dumps({"logistic_rows_per_sec": 1e6})
-    )
-    # rounds 1-2: driver-era rc/tail blobs with no tracked key
-    _old_schema_row(tmp_path / "MULTICHIP_r01.json")
-    _old_schema_row(tmp_path / "MULTICHIP_r02.json")
-    # round 3: the fleet row
-    (tmp_path / "MULTICHIP_r03.json").write_text(json.dumps({
-        "schema": 2, "ok": True,
-        "multichip_straggler_skew_seconds": 0.07,
-        "multichip_collective_fraction": 0.006,
-    }))
-    rc = benchtrend.main(["--dir", str(tmp_path)])
-    out = capsys.readouterr().out
-    assert rc == 0
-    assert "multichip_straggler_skew_seconds" in out
-    assert "new" in out
-
-
-def test_benchtrend_multichip_regression_gates(tmp_path, capsys):
-    from photon_tpu.cli import benchtrend
-
-    (tmp_path / "BENCH_r01.json").write_text(
-        json.dumps({"logistic_rows_per_sec": 1e6})
-    )
-    (tmp_path / "MULTICHIP_r01.json").write_text(json.dumps(
-        {"multichip_straggler_skew_seconds": 0.05,
-         "multichip_collective_fraction": 0.005}
-    ))
-    (tmp_path / "MULTICHIP_r02.json").write_text(json.dumps(
-        {"multichip_straggler_skew_seconds": 5.0,   # 100x worse
-         "multichip_collective_fraction": 0.005}
-    ))
-    rc = benchtrend.main(["--dir", str(tmp_path)])
-    out = capsys.readouterr().out
-    assert rc == 1
-    assert "multichip: multichip_straggler_skew_seconds" in out
-
-
-def test_benchtrend_fallback_keys_read_plain_report_names(tmp_path, capsys):
-    from photon_tpu.cli import benchtrend
-
-    (tmp_path / "BENCH_r01.json").write_text(
-        json.dumps({"logistic_rows_per_sec": 1e6})
-    )
-    # a row carrying only the un-prefixed report keys still lands
-    (tmp_path / "MULTICHIP_r01.json").write_text(json.dumps(
-        {"straggler_skew_seconds": 0.05, "collective_fraction": 0.005}
-    ))
-    rc = benchtrend.main(["--dir", str(tmp_path)])
-    out = capsys.readouterr().out
-    assert rc == 0
-    assert "0.05" in out

@@ -484,8 +484,8 @@ def test_stale_shape_prediction_falls_back_to_jit():
 
 def test_declined_warm_compile_records_no_compile_stage():
     """A declined prediction (sparse shard) must leave compile_seconds at
-    0 — a truthy near-zero stage would fake an overlap fraction and let
-    bench.py under-report compile_seconds past its regression floor."""
+    0 — a truthy near-zero stage would fake an overlap fraction in
+    ``PIPELINE_STATS.report()``."""
     from photon_tpu.estimators.game_estimator import (
         GameEstimator,
         FixedEffectCoordinateConfiguration,

@@ -647,14 +647,3 @@ class TestEndToEnd:
         ]
         assert named
 
-
-class TestBenchtrendTracksAttribution:
-    def test_tracked_metrics_registered(self):
-        from photon_tpu.cli import benchtrend
-
-        assert "logistic_attributed_fraction" in benchtrend.TRACKED
-        assert "linear_attributed_fraction" in benchtrend.TRACKED
-        direction, tol, _ = benchtrend.TRACKED[
-            "logistic_attributed_fraction"]
-        assert direction == "higher"
-        assert tol < 1.5  # a [0,1]-bounded fraction needs a tight ratchet

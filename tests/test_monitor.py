@@ -16,16 +16,13 @@ Covers the acceptance surface:
 - queue integration: per-coordinate cold counters, window quantiles
   and SLO burn in health(), the hammer — concurrent scrapes while the
   queue serves, with ZERO compile events (the runtime half of the
-  tier-2 `monitor` contract);
-- the bench trend gate: passes the repo's real BENCH_r*.json history,
-  flags a synthetic regression, flags a dead gauge.
+  tier-2 `monitor` contract).
 """
 
 from __future__ import annotations
 
 import json
 import math
-import os
 import threading
 import urllib.error
 import urllib.request
@@ -34,7 +31,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from photon_tpu.cli import benchtrend
 from photon_tpu.models.game import (
     FixedEffectModel,
     GameModel,
@@ -54,8 +50,6 @@ from photon_tpu.serve.programs import ScorePrograms, ShapeLadder
 from photon_tpu.serve.queue import MicroBatchQueue
 from photon_tpu.serve.tables import CoefficientTables
 from photon_tpu.types import TaskType
-
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 D, DU, E, S = 6, 5, 9, 3
 
@@ -621,157 +615,3 @@ class TestQueueMonitoring:
             obs.reset()
             obs.TRACER.enabled = was
 
-
-# ---------------------------------------------------------------------------
-# the bench trend gate
-# ---------------------------------------------------------------------------
-
-
-class TestBenchTrend:
-    def test_real_history_passes(self, capsys):
-        # r01-r04 were records of a backend that no longer exists and
-        # are deleted; r05 is the history that remains.
-        assert os.path.exists(
-            os.path.join(REPO_ROOT, "BENCH_r05.json")
-        ), "bench history missing from the repo"
-        rc = benchtrend.main(["--dir", REPO_ROOT])
-        out = capsys.readouterr().out
-        assert rc == 0, out
-        assert "trend OK" in out
-
-    def test_synthetic_regression_fixture_flagged(self, tmp_path, capsys):
-        hist = [
-            {"logistic_rows_per_sec": 1e6,
-             "logistic_compile_seconds": 20.0},
-            {"logistic_rows_per_sec": 2e6,
-             "logistic_compile_seconds": 18.0},
-            {"logistic_rows_per_sec": 0.9e6,  # > 1.5x below best
-             "logistic_compile_seconds": 60.0},  # > 1.5x above best
-        ]
-        for i, parsed in enumerate(hist, 1):
-            (tmp_path / f"BENCH_r{i:02d}.json").write_text(
-                json.dumps({"parsed": parsed})
-            )
-        rc = benchtrend.main(["--dir", str(tmp_path)])
-        out = capsys.readouterr().out
-        assert rc == 1
-        assert "logistic_rows_per_sec" in out
-        assert out.count("REGRESSION:") == 2
-
-    def test_within_tolerance_passes(self, tmp_path, capsys):
-        hist = [
-            {"logistic_rows_per_sec": 2e6},
-            {"logistic_rows_per_sec": 1.5e6},  # down, within 1.5x
-        ]
-        for i, parsed in enumerate(hist, 1):
-            (tmp_path / f"BENCH_r{i:02d}.json").write_text(
-                json.dumps({"parsed": parsed})
-            )
-        assert benchtrend.main(["--dir", str(tmp_path)]) == 0
-        capsys.readouterr()
-
-    def test_dead_gauge_flagged(self, tmp_path, capsys):
-        hist = [
-            {"logistic_rows_per_sec": 1e6, "serving_qps": 100.0},
-            {"logistic_rows_per_sec": 1.1e6},  # serving_qps vanished
-        ]
-        for i, parsed in enumerate(hist, 1):
-            (tmp_path / f"BENCH_r{i:02d}.json").write_text(
-                json.dumps({"parsed": parsed})
-            )
-        rc = benchtrend.main(["--dir", str(tmp_path)])
-        out = capsys.readouterr().out
-        assert rc == 1
-        assert "dead gauge" in out
-
-    def test_unparseable_round_skipped_not_fatal(self, tmp_path, capsys):
-        (tmp_path / "BENCH_r01.json").write_text("not json{")
-        (tmp_path / "BENCH_r02.json").write_text(
-            json.dumps({"parsed": {"logistic_rows_per_sec": 1e6}})
-        )
-        assert benchtrend.main(["--dir", str(tmp_path)]) == 0
-        capsys.readouterr()
-
-    def test_json_report_written(self, tmp_path, capsys):
-        (tmp_path / "BENCH_r01.json").write_text(
-            json.dumps({"parsed": {"logistic_rows_per_sec": 1e6}})
-        )
-        report_path = tmp_path / "trend.json"
-        benchtrend.main([
-            "--dir", str(tmp_path), "--json", str(report_path)
-        ])
-        capsys.readouterr()
-        report = json.loads(report_path.read_text())
-        assert report["metrics"]["logistic_rows_per_sec"]["status"] in (
-            "new", "ok"
-        )
-
-
-class TestBenchTrendEmbeddedRegressions:
-    """Bench-reported regressions GATE (round 13): a populated
-    ``regressions`` list in the latest round fails the trend check
-    unless each entry carries a reasoned waiver."""
-
-    def _write(self, tmp_path, *parsed_list):
-        for i, parsed in enumerate(parsed_list, 1):
-            (tmp_path / f"BENCH_r{i:02d}.json").write_text(
-                json.dumps({"parsed": parsed})
-            )
-
-    def test_populated_list_fails(self, tmp_path, capsys):
-        self._write(
-            tmp_path,
-            {"logistic_rows_per_sec": 1e6, "regressions": []},
-            {"logistic_rows_per_sec": 1e6,
-             "regressions": ["serving_errors 3 != 0"]},
-        )
-        rc = benchtrend.main(["--dir", str(tmp_path)])
-        out = capsys.readouterr().out
-        assert rc == 1
-        assert "bench-reported: serving_errors 3 != 0" in out
-
-    def test_only_latest_round_gates(self, tmp_path, capsys):
-        # An OLD round's violation was that round's problem; the gate
-        # judges the latest state of the world.
-        self._write(
-            tmp_path,
-            {"logistic_rows_per_sec": 1e6,
-             "regressions": ["old floor trip"]},
-            {"logistic_rows_per_sec": 1e6, "regressions": []},
-        )
-        assert benchtrend.main(["--dir", str(tmp_path)]) == 0
-        capsys.readouterr()
-
-    def test_waiver_requires_reason_and_passes(self, tmp_path, capsys):
-        self._write(
-            tmp_path,
-            {"logistic_rows_per_sec": 1e6,
-             "regressions": ["ingest_rows_per_sec 9 < 10"]},
-        )
-        rc = benchtrend.main([
-            "--dir", str(tmp_path),
-            "--waive", "ingest_rows_per_sec 9=rebaselined, see notes",
-        ])
-        out = capsys.readouterr().out
-        assert rc == 0
-        assert "waived: ingest_rows_per_sec 9 < 10" in out
-        # A reasonless waiver is refused (the analysis-tier convention).
-        with pytest.raises(SystemExit):
-            benchtrend.main([
-                "--dir", str(tmp_path), "--waive", "ingest_rows_per_sec",
-            ])
-        capsys.readouterr()
-
-    def test_seeded_r05_waiver_covers_real_history(self):
-        # The repo's own BENCH_r05 carries the ingest-floor entry; the
-        # WAIVED_REGRESSIONS seed (with its written justification) is
-        # what keeps the real-history gate green — pin that the seed
-        # actually matches the historical entry.
-        entry = "ingest_rows_per_sec 510028 < 1000000"
-        assert any(
-            pat in entry for pat in benchtrend.WAIVED_REGRESSIONS
-        )
-        assert all(
-            reason.strip()
-            for reason in benchtrend.WAIVED_REGRESSIONS.values()
-        )

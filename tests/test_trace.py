@@ -16,9 +16,7 @@ Covers the PR-8 acceptance surface:
   faults (the `faults.on_crash` listener), chained excepthook,
   uninstall restoring every hook, and the real-subprocess SIGTERM dump
   through `photon train` (the PR-7 pattern);
-- `profile_session` as THE profiling entry point;
-- the `measured_vs_roofline` bench gate tripping on a deliberately
-  slowed fixture (ROADMAP item 2's gating half).
+- `profile_session` as THE profiling entry point.
 """
 
 from __future__ import annotations
@@ -605,45 +603,6 @@ class TestProfileSession:
             pass
         assert trace.events() == []
         assert obs.TRACER.completed() == []
-
-
-# --------------------------------------------------------------------------
-# the roofline gate
-# --------------------------------------------------------------------------
-
-
-class TestRooflineGate:
-    def _bench(self):
-        if str(REPO_ROOT) not in sys.path:
-            sys.path.insert(0, str(REPO_ROOT))
-        import bench
-
-        return bench
-
-    def test_floor_trips_on_slowed_fixture(self):
-        bench = self._bench()
-        ceiling = bench.FLOORS["logistic_measured_vs_roofline_max"]
-        # a deliberately slowed fit: twice the allowed distance from
-        # the roofline must fail the bench
-        slow = {"measured_vs_roofline": ceiling * 2}
-        out = bench.roofline_regressions("logistic", slow)
-        assert len(out) == 1
-        assert "measured_vs_roofline" in out[0]
-
-    def test_floor_passes_at_or_under_ceiling(self):
-        bench = self._bench()
-        ceiling = bench.FLOORS["logistic_measured_vs_roofline_max"]
-        assert bench.roofline_regressions(
-            "logistic", {"measured_vs_roofline": ceiling}) == []
-        # skipped/errored cost model never false-positives the gate
-        assert bench.roofline_regressions(
-            "logistic", {"skipped": "mesh path"}) == []
-        assert bench.roofline_regressions("logistic", {}) == []
-
-    def test_ungated_variant_reports_without_gating(self):
-        bench = self._bench()
-        assert bench.roofline_regressions(
-            "linear", {"measured_vs_roofline": 10_000.0}) == []
 
 
 # --------------------------------------------------------------------------
