@@ -43,6 +43,9 @@ class IdTag:
     # reservoir sampling) is host-side numpy; keeping the codes it was built
     # from avoids a device->host round trip per dataset build.
     codes_np: np.ndarray | None = None
+    # How ``from_raw`` found the vocabulary ("count" | "sort"); the
+    # ``dataset`` stage reports it.
+    grouping: str | None = None
 
     @property
     def num_groups(self) -> int:
@@ -59,22 +62,48 @@ class IdTag:
         # stores modelId as a string (BayesianLinearModelAvro), so keeping
         # numeric keys here would make every vocab lookup after a model
         # reload miss silently ('5' vs np.int64(5)).
-        raw = np.asarray(raw_ids)
-        uniq, codes = np.unique(raw, return_inverse=True)
-        keys = tuple(
-            str(k.item() if hasattr(k, "item") else k) for k in uniq
-        )
+        uniq, codes, how = _unique_inverse(np.asarray(raw_ids))
+        if uniq.dtype.kind in "iu":  # ``tolist`` is ``item`` in bulk
+            keys = tuple(map(str, uniq.tolist()))
+        else:
+            keys = tuple(
+                str(k.item() if hasattr(k, "item") else k) for k in uniq
+            )
         if len(set(keys)) != len(keys):
             raise ValueError(
                 "id tag keys collide after str normalization"
             )
-        codes = codes.astype(np.int32)
         return IdTag(
             codes=jnp.asarray(codes),
             vocab={k: i for i, k in enumerate(keys)},
             inverse=keys,
             codes_np=codes,
+            grouping=how,
         )
+
+
+# Integer ids are coded by counting while their maximum is under this
+# multiple of the row count (or under 2**20): the transient is then at
+# most that many int64 counts.
+_COUNT_IDS_SPAN = 4
+
+
+def _unique_inverse(raw: np.ndarray) -> tuple[np.ndarray, np.ndarray, str]:
+    """``np.unique(raw, return_inverse=True)`` with int32 codes, and how it
+    was found: ``count`` where the ids are small non-negative integers
+    (grouping by a bounded integer key is a counting problem, O(n)), else
+    ``sort`` (strings, negative or hashed ids, an empty column)."""
+    n = raw.size
+    if raw.ndim == 1 and n and raw.dtype.kind in "iu":
+        top = int(raw.max())
+        if raw.min() >= 0 and top < max(_COUNT_IDS_SPAN * n, 1 << 20):
+            ids = raw.astype(np.intp, copy=False)
+            seen = np.bincount(ids, minlength=top + 1) > 0
+            code_of = np.cumsum(seen, dtype=np.int32)
+            code_of -= 1
+            return np.flatnonzero(seen), code_of[ids], "count"
+    uniq, codes = np.unique(raw, return_inverse=True)
+    return uniq, codes.astype(np.int32), "sort"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -194,9 +223,12 @@ def make_game_dataset(
     ``device_put``; the copy itself is asynchronous and outlasts it)."""
     from photon_tpu import obs
 
-    with obs.stage("dataset"):
-        return _make_game_dataset(
+    with obs.stage("dataset") as stage:
+        data = _make_game_dataset(
             labels, feature_shards, offsets, weights, id_tags, uids, dtype)
+        stage.attrs = dict(
+            id_grouping={k: t.grouping for k, t in data.id_tags.items()})
+        return data
 
 
 def _make_game_dataset(

@@ -13,9 +13,11 @@ rows/s ingest floor). This module owns the machinery that overlaps them:
   within-coordinate elementwise passes chunk over rows
   (``map_chunked`` / ``bincount_chunked`` — exact, order-preserving, so
   results are BIT-IDENTICAL to the serial path; the deterministic
-  reservoir hash order is the contract). Two separate pools: coordinate
-  tasks block on their own chunk tasks, so running both levels on one
-  bounded pool could deadlock (all workers waiting on queued chunks).
+  reservoir hash order is the contract). ``group_rows`` groups the rows
+  by entity by counting, the stable permutation a sort would give. Two
+  separate pools: coordinate tasks block on their own chunk tasks, so
+  running both levels on one bounded pool could deadlock (all workers
+  waiting on queued chunks).
 - **Chunked double-buffered transfer** (``packed_device_put``): the single
   packed plan buffer is pushed as granule-aligned chunks with each
   ``jax.device_put`` enqueued ASYNCHRONOUSLY while the host fills the
@@ -26,7 +28,7 @@ rows/s ingest floor). This module owns the machinery that overlaps them:
   the legacy single-shot path — byte-identical layout either way.
 - **PIPELINE_STATS**: per-stage seconds (plan / pack / transfer /
   compile / compile_wait) + the measured compile-overlap fraction, reset
-  per prepare and reported by ``bench.py``.
+  per prepare; the benchmark's ``ingest.*`` metrics read its report.
 
 ``PHOTON_TPU_SERIAL_INGEST=1`` forces everything back to the serial
 in-line path (the determinism property tests diff the two);
@@ -89,7 +91,7 @@ CONCURRENCY_AUDIT = dict(
             "PipelineStats._counts",
         ),
     },
-    thread_entries=("map_chunked.run",),
+    thread_entries=("map_chunked.run", "group_rows.sort_part"),
     jax_dispatch_ok={},
 )
 
@@ -327,7 +329,8 @@ class PipelineStats:
             return self._seconds.get(name, 0.0)
 
     def report(self) -> dict:
-        """The JSON-ready stage breakdown ``bench.py`` embeds.
+        """The JSON-ready stage breakdown (``obs.snapshot()["pipeline"]``;
+        the benchmark's ``ingest.plan_s`` / ``ingest.transfer_s``).
 
         ``compile_overlap_fraction`` is measured, not inferred: the AOT
         warm compile's duration minus the time the first fit actually
@@ -427,6 +430,47 @@ def bincount_chunked(codes: np.ndarray, minlength: int) -> np.ndarray:
     for p in parts[1:]:
         total += p
     return total
+
+
+# A high digit's rows are found by one pass over all the codes for each of
+# its values, so the split form stops at this many values (2**20 groups).
+_SPLIT_MAX_PARTS = 16
+
+
+def group_rows(codes: np.ndarray, num_groups: int) -> tuple[np.ndarray, str]:
+    """``(perm, how)``: ``perm`` is ``np.argsort(codes, kind="stable")``
+    for codes in ``[0, num_groups)``, found by counting: numpy's stable
+    argsort is a radix sort for 8- and 16-bit keys ONLY (wider integers
+    take a merge sort), so the codes are sorted a 16-bit digit at a time.
+    ``how`` names the form: ``radix16`` (one digit), ``radix16x2`` (two:
+    the rows of each high digit, ascending, sorted by the low one, the
+    parts side by side on the chunk pool; past ``_SPLIT_MAX_PARTS`` high
+    digits, low digit first and the high one over that order) or ``sort``
+    (over 2**32 groups: the stable argsort itself)."""
+    n = codes.shape[0]
+    if num_groups <= 1 << 16:
+        return np.argsort(codes.astype(np.uint16), kind="stable"), "radix16"
+    if num_groups > 1 << 32:
+        return np.argsort(codes, kind="stable"), "sort"
+    low = codes.astype(np.uint16)
+    high = codes >> 16
+    parts = ((num_groups - 1) >> 16) + 1
+    if parts > _SPLIT_MAX_PARTS:
+        by_low = np.argsort(low, kind="stable")
+        by_high = np.argsort(
+            high.astype(np.uint16)[by_low], kind="stable")
+        return by_low[by_high], "radix16x2"
+    perm = np.empty(n, dtype=np.intp)
+    ends = np.cumsum(bincount_chunked(high, parts))
+
+    def sort_part(k: int) -> None:
+        rows = np.flatnonzero(high == k)  # ascending: the gather streams
+        perm[ends[k] - rows.size:ends[k]] = rows[
+            np.argsort(low[rows], kind="stable")]
+
+    consume_futures(
+        [chunk_executor.submit(sort_part, k) for k in range(parts)])
+    return perm, "radix16x2"
 
 
 # --------------------------------------------------------------------------

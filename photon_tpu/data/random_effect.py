@@ -62,6 +62,7 @@ from photon_tpu.ops import segment_reduce
 from photon_tpu.data.pipeline import (
     PIPELINE_STATS,
     bincount_chunked,
+    group_rows,
     chunk_executor,
     consume_futures,
     map_chunked,
@@ -763,6 +764,78 @@ def _compact_left(
     return slot_c[:, :k_out].astype(np.int32), val_c[:, :k_out]
 
 
+# A dense shard with at most n / _FEW_ZEROS exact zeros is planned from the
+# zeros alone; above that their sort would cost what the gather does.
+_FEW_ZEROS = 16
+
+
+def _dense_presence_by_scan(
+    values: np.ndarray, codes: np.ndarray, kept: np.ndarray,
+    all_rows_kept: bool,
+) -> np.ndarray | None:
+    """[E, d] per-entity feature presence of a dense shard from ONE
+    streaming pass over it, or None where that cannot say. ``kept`` [E] is
+    how many of an entity's rows count (0: it trains no model). With no
+    exact zero in the shard, every entity with a row has every feature;
+    with a few, and ``all_rows_kept`` (no cap binds, so each row of such
+    an entity counts), an entity lacks a feature only where it has as many
+    zeros there as rows."""
+    n, d = values.shape
+    zero = values == 0.0
+    holes = int(np.count_nonzero(zero))
+    if holes and not (all_rows_kept and holes * _FEW_ZEROS <= n):
+        return None
+    presence = np.repeat((kept > 0)[:, None], d, axis=1)
+    if holes:
+        at = np.flatnonzero(zero.reshape(-1))  # 1-D: a 2-D nonzero is 4x
+        pairs, zeros = np.unique(
+            codes[at // d] * d + at % d, return_counts=True)
+        presence.reshape(-1)[pairs] = kept[pairs // d] > zeros
+    return presence
+
+
+def _dense_presence_by_gather(
+    ell_val: np.ndarray, rows_p: np.ndarray, pair_codes: np.ndarray,
+    num_entities: int,
+) -> np.ndarray:
+    """[E, d] per-entity feature presence of a dense shard: every row
+    touches every column, so the per-entity active-feature union is one
+    segment-OR over the kept rows ``rows_p`` in entity order
+    (``pair_codes``: their entities, ascending) — no 17M-key sort (the
+    reference amortizes the equivalent union across the cluster's
+    foldByKey, RandomEffectDataset.scala:390-426)."""
+    # Compare/gather in whichever order moves fewer bytes: when most
+    # rows are kept, compare first (the bool matrix is 4x narrower
+    # than the floats, so the fancy-index moves 4x fewer bytes); when
+    # the reservoir cap discards most rows, gather the kept rows
+    # first and compare only those.
+    if rows_p.size * 2 > ell_val.shape[0]:
+        present = (ell_val != 0.0)[rows_p]  # [m, d]
+    else:
+        present = ell_val[rows_p] != 0.0
+    presence = np.zeros((num_entities, ell_val.shape[1]), dtype=bool)
+    if present.all():
+        # Fully dense kept rows (their zeros lie in rows not kept): every
+        # entity's subspace is the whole feature set — skip the
+        # segment-OR entirely.
+        presence[np.unique(pair_codes)] = True
+        return presence
+    m = rows_p.shape[0]
+    seg_starts = np.searchsorted(pair_codes, np.arange(num_entities))
+    seg_ends = np.append(seg_starts[1:], m)
+    nonempty = seg_starts < seg_ends
+    # reduceat over the NONEMPTY starts only: consecutive empty
+    # segments share their successor's start, so a naive clamp of
+    # trailing starts to m-1 would shave the last row off the
+    # preceding entity's union. Nonempty starts partition [0, m)
+    # exactly (each spans to the next nonempty start).
+    if nonempty.any():
+        presence[nonempty] = np.logical_or.reduceat(
+            present, seg_starts[nonempty], axis=0
+        )
+    return presence
+
+
 @dataclasses.dataclass
 class _Plan:
     """Host-side build plan: everything downstream layout needs, no loops."""
@@ -785,6 +858,12 @@ class _Plan:
     intercept_slots_all: np.ndarray  # [E] int32; -1 none
     bucket_members: dict  # cap -> np.ndarray of entity codes
     num_features: int
+    # How the pass engaged (the ``plan`` stage's attributes): the form of
+    # ``group_rows`` (``sort`` where the cap binds), and whether one scan
+    # of a dense shard answered the presence test (``scan``) or the kept
+    # rows' presence was gathered in entity order (``gather``).
+    grouping: str
+    presence: str
 
 
 def _plan_random_effect(
@@ -803,11 +882,6 @@ def _plan_random_effect(
         config.feature_shard_id
     )
     labels_np = game_data.host_column("labels")
-    uids = (
-        game_data.uids.astype(np.int64)
-        if game_data.uids is not None
-        else np.arange(n, dtype=np.int64)
-    )
 
     # --- 1. deterministic reservoir cap: per entity keep the
     # active_data_upper_bound rows with smallest hash keys -----------------
@@ -822,6 +896,11 @@ def _plan_random_effect(
         counts_full.max(initial=0) > upper
     )
     if cap_binds:
+        uids = (
+            game_data.uids.astype(np.int64)
+            if game_data.uids is not None
+            else np.arange(n, dtype=np.int64)
+        )
         seed = _stable_type_seed(config.random_effect_type)
         order_keys = map_chunked(
             lambda u: _byteswap64_mix(u, seed),
@@ -829,10 +908,10 @@ def _plan_random_effect(
             uids,
         )
         # Group-by-entity, ordered by hash within the group. A two-key
-        # lexsort costs two comparison sorts (~1.5s at 4M rows — the
-        # single hottest planning op); packing (code, high hash bits) into
-        # one int64 lets numpy's stable integer argsort run as an O(n)
-        # radix sort instead. Within-entity ties on the truncated hash
+        # lexsort costs two comparison sorts; packing (code, high hash
+        # bits) into one int64 makes it one. (Still a comparison sort:
+        # numpy's stable argsort is a radix sort for 8- and 16-bit keys
+        # only.) Within-entity ties on the truncated hash
         # fall back to stable row order — still a deterministic uniform
         # reservoir (the hash bits kept exceed 2x log2(n) for any E below
         # 2^20, so ties are vanishing).
@@ -849,17 +928,17 @@ def _plan_random_effect(
             perm = np.argsort(key, kind="stable")
         else:  # pathological entity counts: keep the exact two-key sort
             perm = np.lexsort((order_keys, codes))
+        grouping = "sort"
     else:
         # No entity exceeds the cap (or no cap): the reservoir keeps every
-        # row, so within-entity order is irrelevant — group by entity
-        # alone with a narrow radix sort and skip the hashing pass.
-        sort_codes = (
-            codes.astype(np.int32) if num_entities <= (1 << 31) - 1
-            else codes
-        )
-        perm = np.argsort(sort_codes, kind="stable")
-    sorted_codes = codes[perm]
-    starts = np.searchsorted(sorted_codes, np.arange(num_entities))
+        # row, so within-entity order is row order — group by entity
+        # alone, by counting, and skip the hashing pass.
+        perm, grouping = group_rows(codes, num_entities)
+    # perm groups the rows by ascending entity on either branch, so what
+    # follows the grouping streams over the counts.
+    starts = np.cumsum(counts_full) - counts_full
+    sorted_codes = np.repeat(
+        np.arange(num_entities, dtype=np.int64), counts_full)
     counts = (
         counts_full if upper is None else np.minimum(counts_full, upper)
     )
@@ -868,7 +947,7 @@ def _plan_random_effect(
         starts, counts_full
     ) if n else np.empty(0, dtype=np.int64)
     keep_sorted = (
-        np.ones(n, dtype=bool) if upper is None else rank_sorted < upper
+        rank_sorted < upper if cap_binds else np.ones(n, dtype=bool)
     )
     # Lower-bound filter: too-small entities train no model (their rows
     # still score via the zero row of the coefficient matrix).
@@ -883,54 +962,27 @@ def _plan_random_effect(
                 stride = max(stride, int(a.max()) + 1)
     tail = game_data.host_shard_tail(config.feature_shard_id)
     proj_mask = keep_sorted & active[sorted_codes]
-    rows_p = perm[proj_mask]
-    pair_codes = sorted_codes[proj_mask]
-    dense_view = isinstance(
+    has_rows = bool(proj_mask.any())
+    dense_only = tail is None and isinstance(
         game_data.feature_shards[config.feature_shard_id], DenseFeatures
     )
-    if rows_p.size and dense_view and tail is None:
-        # Dense shards: every row touches every column, so the per-entity
-        # active-feature union is a [E, d] presence matrix computed by one
-        # segment-OR over the entity-grouped rows — no 17M-key sort. This
-        # is the hot ingest path for dense GLMix shards (the reference
-        # amortizes the equivalent union across the cluster's foldByKey,
-        # RandomEffectDataset.scala:390-426).
-        # Compare/gather in whichever order moves fewer bytes: when most
-        # rows are kept, compare first (the bool matrix is 4x narrower
-        # than the floats, so the fancy-index moves 4x fewer bytes); when
-        # the reservoir cap discards most rows, gather the kept rows
-        # first and compare only those.
-        if rows_p.size * 2 > ell_val.shape[0]:
-            present = (ell_val != 0.0)[rows_p]  # [m, d]
-        else:
-            present = ell_val[rows_p] != 0.0
-        if present.all():
-            # Fully dense kept rows (no exact zeros anywhere): every
-            # active entity's subspace is the whole feature set — skip
-            # the segment-OR entirely.
-            presence = np.zeros((num_entities, ell_val.shape[1]), bool)
-            presence[np.unique(pair_codes)] = True
-        else:
-            m = rows_p.shape[0]
-            seg_starts = np.searchsorted(
-                pair_codes, np.arange(num_entities))
-            seg_ends = np.append(seg_starts[1:], m)
-            nonempty = seg_starts < seg_ends
-            # reduceat over the NONEMPTY starts only: consecutive empty
-            # segments share their successor's start, so a naive clamp of
-            # trailing starts to m-1 would shave the last row off the
-            # preceding entity's union. Nonempty starts partition [0, m)
-            # exactly (each spans to the next nonempty start).
-            presence = np.zeros(
-                (num_entities, ell_val.shape[1]), dtype=bool)
-            if nonempty.any():
-                presence[nonempty] = np.logical_or.reduceat(
-                    present, seg_starts[nonempty], axis=0
-                )
+    scan = False
+    if has_rows and dense_only:
+        # Ask a dense shard before gathering from it: where one streaming
+        # pass answers, nothing needs the rows in entity order.
+        presence = _dense_presence_by_scan(
+            ell_val, codes, np.where(active, counts, 0), not cap_binds)
+        scan = presence is not None
+        if not scan:
+            presence = _dense_presence_by_gather(
+                ell_val, perm[proj_mask], sorted_codes[proj_mask],
+                num_entities)
         rows_e, cols_f = np.nonzero(presence)
         # Row-major nonzero order == ascending key order (stride >= d).
         uniq = rows_e.astype(np.int64) * np.int64(stride) + cols_f
-    elif rows_p.size:
+    elif has_rows:
+        rows_p = perm[proj_mask]
+        pair_codes = sorted_codes[proj_mask]
         iv = ell_idx[rows_p]
         present = ell_val[rows_p] != 0.0
         pair_keys = (
@@ -1042,6 +1094,8 @@ def _plan_random_effect(
         intercept_slots_all=intercept_slots_all,
         bucket_members=bucket_members,
         num_features=num_features,
+        grouping=grouping,
+        presence="scan" if scan else "gather",
     )
 
 
@@ -1732,8 +1786,9 @@ def build_random_effect_dataset(
             game_data, config,
             intercept_index=intercept_index, extra_features=extra_features,
         )
-        # How the bucket ladder engaged, for a trace's reader: slab_rows
-        # over real_rows is this coordinate's padding ratio.
+        # How the bucket ladder and the grouping engaged, for a trace's
+        # reader: slab_rows over real_rows is this coordinate's padding
+        # ratio.
         buckets = [
             [cap, int(plan.bucket_members[cap].size)]
             for cap in sorted(plan.bucket_members)
@@ -1742,6 +1797,8 @@ def build_random_effect_dataset(
             buckets=buckets,
             slab_rows=sum(cap * b for cap, b in buckets),
             real_rows=int(plan.counts[plan.active].sum()),
+            grouping=plan.grouping,
+            presence=plan.presence,
         )
     if lazy is None:
         # An explicit score-table width cap is a signal that max_sub_dim is

@@ -1,5 +1,7 @@
 """Data layer: libsvm ingest, index maps, ELL packing, synthetic generators."""
 
+import time
+
 import numpy as np
 import pytest
 
@@ -93,3 +95,78 @@ def test_game_data_generator():
     # power-law skew: most common entity should dominate
     counts = np.bincount(data.entity_ids["user"], minlength=20)
     assert counts[0] == counts.max()
+
+
+# ---------------------------------------------------------------------------
+# IdTag.from_raw: small integer ids are coded by counting, the rest by sort
+# ---------------------------------------------------------------------------
+
+
+def _tag_by_sort(raw):
+    """The ``np.unique`` form ``from_raw`` had for every column."""
+    uniq, codes = np.unique(np.asarray(raw), return_inverse=True)
+    keys = tuple(str(k.item()) for k in uniq)
+    return codes.astype(np.int32), keys, {k: i for i, k in enumerate(keys)}
+
+
+def _assert_tag_is(tag, raw):
+    codes, keys, vocab = _tag_by_sort(raw)
+    np.testing.assert_array_equal(tag.host_codes(), codes)
+    np.testing.assert_array_equal(np.asarray(tag.codes), codes)
+    assert tag.host_codes().dtype == np.int32
+    assert tag.codes.dtype == np.int32
+    assert tag.inverse == keys
+    assert tag.vocab == vocab
+    assert list(tag.vocab) == list(vocab)  # the same insertion order too
+
+
+_COUNTED_IDS = {
+    "dense": lambda rng: rng.integers(0, 50, size=400),
+    "holes": lambda rng: rng.integers(0, 40, size=400) * 3 + 7,
+    "one": lambda rng: np.full(9, 113),
+}
+
+
+@pytest.mark.parametrize("dtype", [np.uint8, np.int32, np.int64, np.uint64])
+@pytest.mark.parametrize("ids", sorted(_COUNTED_IDS))
+def test_from_raw_codes_small_integer_ids_by_counting(ids, dtype):
+    from photon_tpu.data.game_data import IdTag
+
+    raw = _COUNTED_IDS[ids](np.random.default_rng(5)).astype(dtype)
+    tag = IdTag.from_raw(raw)
+    assert tag.grouping == "count"
+    _assert_tag_is(tag, raw)
+
+
+_SORTED_IDS = {
+    "strings": np.array(["u7", "u3", "u7", "u10"]),
+    "negative": np.array([4, -1, 4, 2], np.int64),
+    # At or over max(4 n, 2**20): a table of counts would outgrow the ids.
+    "over_the_span": np.array([3, 1 << 20, 3, 0], np.int64),
+    "uint64_over_2_63": np.array([5, (1 << 63) + 9, 5], np.uint64),
+    "empty": np.empty(0, np.int64),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SORTED_IDS))
+def test_from_raw_takes_the_sort_for_every_other_column(case):
+    """Asserted through the ``dataset`` stage's ``id_grouping``."""
+    from photon_tpu import obs
+    from photon_tpu.data.dataset import DenseFeatures
+    from photon_tpu.data.game_data import make_game_dataset
+
+    raw = _SORTED_IDS[case]
+    n = raw.shape[0]
+    small = np.arange(n, dtype=np.int32) % 3
+    start = time.perf_counter()
+    data = make_game_dataset(
+        np.zeros(n, np.float32),
+        {"s": DenseFeatures(np.ones((n, 2), np.float32))},
+        id_tags={"g": raw, "small": small},
+    )
+    (rec,) = [r for r in obs.TRACER.completed()
+              if r.name == "dataset" and r.t0 >= start]
+    assert rec.attrs == {"id_grouping": {
+        "g": "sort", "small": "count" if n else "sort"}}
+    _assert_tag_is(data.id_tags["g"], raw)
+    _assert_tag_is(data.id_tags["small"], small)

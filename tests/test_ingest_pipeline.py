@@ -156,6 +156,161 @@ def test_parallel_planner_bit_identical_to_serial(kind):
 
 
 # ---------------------------------------------------------------------------
+# grouping by counting: group_rows, and the whole plan against the sort
+# ---------------------------------------------------------------------------
+
+
+def _codes_for(e: int, law: str, n: int = 20_000) -> np.ndarray:
+    rng = np.random.default_rng([e, len(law)])
+    if law == "uniform":
+        return rng.integers(0, e, size=n)
+    if law == "skewed":  # a few entities own most rows, many own none
+        return np.minimum(rng.zipf(1.3, size=n) - 1, e - 1)
+    # "holes": only every third entity has rows, the last one among them
+    codes = rng.integers(0, -(-e // 3), size=n) * 3
+    codes[0] = e - 1
+    return np.minimum(codes, e - 1)
+
+
+_GROUPS = (1, 2, 65_535, 65_536, 65_537, 200_000, (1 << 20) + 1)
+
+
+@pytest.mark.parametrize("serial", [True, False], ids=["serial", "threaded"])
+@pytest.mark.parametrize("law", ["uniform", "skewed", "holes"])
+@pytest.mark.parametrize("e", _GROUPS)
+def test_group_rows_is_the_stable_argsort(e, law, serial):
+    codes = _codes_for(e, law).astype(np.int64)
+    with ingest_mode(serial=serial):
+        perm, how = pipeline.group_rows(codes, e)
+    want = np.argsort(codes, kind="stable")
+    np.testing.assert_array_equal(perm, want)
+    assert perm.dtype == want.dtype
+    assert how == ("radix16" if e <= 65_536 else "radix16x2")
+
+
+@pytest.mark.parametrize(
+    "e, how",
+    [(1, "radix16"), (65_537, "radix16x2"), ((1 << 20) + 1, "radix16x2"),
+     ((1 << 32) + 1, "sort")],
+)
+def test_group_rows_of_no_rows_and_of_too_many_groups(e, how):
+    perm, got = pipeline.group_rows(np.empty(0, np.int64), e)
+    assert perm.shape == (0,) and got == how
+    few = np.array([3, 0, 3, 1, 0], np.int64)
+    perm, got = pipeline.group_rows(few, e)
+    np.testing.assert_array_equal(perm, np.argsort(few, kind="stable"))
+    assert got == how
+
+
+def _golden_data(law: str, shard: str, cap_binds: bool):
+    """Some 12 000 rows of 200 users and 40 movies, the size of the
+    benchmark configurations' ``tiny`` blocks, made here."""
+    n, users, movies, d = 12_000, 200, 40, 6
+    rng = np.random.default_rng([len(law), len(shard), cap_binds])
+    if law == "uniform":
+        uid = rng.integers(0, users, size=n)
+        mid = rng.integers(0, movies, size=n)
+    else:  # power law: entity k's share falls as 1 / (k + 1)
+        uid = rng.choice(users, size=n, p=_shares(users))
+        mid = rng.choice(movies, size=n, p=_shares(movies))
+    y = rng.normal(size=n).astype(np.float32)
+    if shard == "sparse":
+        idx = rng.integers(0, d, size=(n, 3)).astype(np.int32)
+        val = rng.normal(size=(n, 3)).astype(np.float32)
+        val[val < -1.0] = 0.0
+        feats = SparseFeatures(idx, val, d)
+    else:
+        x = rng.normal(size=(n, d)).astype(np.float32)
+        x[:, -1] = 1.0
+        if shard == "dense_few_zeros":
+            # Under n / 16 exact zeros, and two entities that lack a
+            # feature outright: every row of theirs holds a zero there.
+            x[rng.integers(0, n, 40), rng.integers(0, d - 1, 40)] = 0.0
+            x[uid == _smallest_active(uid), 2] = 0.0
+            x[mid == _smallest_active(mid), 0] = 0.0
+        elif shard == "dense_many_zeros":
+            x[x < 0.3] = 0.0
+        feats = DenseFeatures(x)
+    data = make_game_dataset(
+        y, {"s": feats}, id_tags={"userId": uid, "movieId": mid})
+    # The smallest entity counts set the caps: one that binds for some
+    # entities, or one that no entity reaches.
+    top = int(max(np.bincount(uid).max(), np.bincount(mid).max()))
+    upper = 24 if cap_binds else top
+    return data, [
+        RandomEffectDataConfiguration(
+            tag, "s", active_data_upper_bound=upper,
+            active_data_lower_bound=2)
+        for tag in ("userId", "movieId")
+    ]
+
+
+def _smallest_active(ids: np.ndarray) -> int:
+    """The entity with the fewest rows that still trains (two or more)."""
+    counts = np.bincount(ids)
+    return int(np.where(counts >= 2, counts, ids.size).argmin())
+
+
+def _shares(e: int) -> np.ndarray:
+    w = 1.0 / np.arange(1, e + 1)
+    return w / w.sum()
+
+
+def _plan_and_flat(data, cfg):
+    plan = _plan_random_effect(
+        data, cfg, intercept_index=5, extra_features=None)
+    pending = build_random_effect_dataset(
+        data, cfg, intercept_index=5, lazy=True, defer_transfer=True)
+    return plan, pending.flat
+
+
+@pytest.mark.parametrize("cap_binds", [False, True], ids=["nocap", "cap"])
+@pytest.mark.parametrize(
+    "shard",
+    ["dense", "dense_few_zeros", "dense_many_zeros", "sparse"])
+@pytest.mark.parametrize("law", ["uniform", "power"])
+def test_plan_by_counting_is_the_plan_by_sorting(
+    law, shard, cap_binds, monkeypatch
+):
+    """The whole plan is the parent's: with ``group_rows`` and the dense
+    scan in place, and with them patched to the stable argsort and the
+    gather in entity order that stood there before."""
+    from photon_tpu.data import random_effect as re_mod
+
+    data, cfgs = _golden_data(law, shard, cap_binds)
+    with ingest_mode(serial=True):
+        new = [_plan_and_flat(data, cfg) for cfg in cfgs]
+        monkeypatch.setattr(
+            re_mod, "group_rows",
+            lambda codes, e: (np.argsort(codes, kind="stable"), "sort"))
+        monkeypatch.setattr(
+            re_mod, "_dense_presence_by_scan", lambda *a: None)
+        old = [_plan_and_flat(data, cfg) for cfg in cfgs]
+    for (plan, flat), (plan0, flat0) in zip(new, old):
+        assert plan.grouping == ("sort" if cap_binds else "radix16")
+        scans = shard == "dense" or (
+            shard == "dense_few_zeros" and not cap_binds)
+        assert plan.presence == ("scan" if scans else "gather")
+        assert (plan0.grouping, plan0.presence) == ("sort", "gather")
+        for f in ("perm", "starts", "sorted_codes", "rank_sorted",
+                  "keep_sorted", "counts", "active", "proj_all",
+                  "sub_dims", "intercept_slots_all"):
+            got, want = getattr(plan, f), getattr(plan0, f)
+            np.testing.assert_array_equal(got, want, f)
+            assert got.dtype == want.dtype, f
+        np.testing.assert_array_equal(plan.table.keys, plan0.table.keys)
+        assert sorted(plan.bucket_members) == sorted(plan0.bucket_members)
+        assert len(flat) == len(flat0)
+        for a, b in zip(flat, flat0):
+            np.testing.assert_array_equal(a, b)
+            assert a.dtype == b.dtype
+    if shard == "dense_few_zeros" and not cap_binds:
+        # The two entities that lack a feature: the scan found them.
+        assert (new[0][0].sub_dims < 6).sum() == 1
+        assert (new[1][0].sub_dims < 6).sum() == 1
+
+
+# ---------------------------------------------------------------------------
 # the round-5 regression pin: _bucket_rows
 # ---------------------------------------------------------------------------
 

@@ -202,13 +202,32 @@ def test_a_plan_stage_says_how_the_bucket_ladder_engaged(ring):
     plans = _by_name(ring)["plan"]
     assert len(plans) == len(built) >= 1
     for rec in plans:
-        assert set(rec.attrs) == {"buckets", "slab_rows", "real_rows"}
+        assert set(rec.attrs) == {
+            "buckets", "slab_rows", "real_rows", "grouping", "presence"}
         assert rec.attrs["buckets"] in built.values()
         assert rec.attrs["slab_rows"] == sum(
             cap * b for cap, b in rec.attrs["buckets"])
         assert 0 < rec.attrs["real_rows"] <= rec.attrs["slab_rows"]
         assert rec.attrs["real_rows"] <= data.num_samples
         json.dumps(rec.to_json())  # attributes an exporter can write
+
+
+def test_dataset_and_plan_stages_say_how_the_rows_were_grouped(ring):
+    """A tiny job's integer ids are coded by counting, its 7 entities
+    grouped in one radix pass, and its dense shard (normal draws and an
+    intercept: no exact zero) answers the presence test by one scan."""
+    from photon_tpu.analysis import program
+
+    with jax.enable_x64(False):
+        est, data = program._tiny_glmix()
+        est.prepare(data)
+    got = _by_name(ring)
+    (dataset,) = got["dataset"]
+    assert dataset.attrs == {"id_grouping": {"userId": "count"}}
+    (plan,) = got["plan"]
+    assert plan.attrs["grouping"] == "radix16"
+    assert plan.attrs["presence"] == "scan"
+    json.dumps(dataset.to_json())
 
 
 # ---------------------------------------------------------------------------
