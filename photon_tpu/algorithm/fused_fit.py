@@ -1256,7 +1256,7 @@ class FusedFit:
         # entry to the return of the dispatch, NOT to the device's end,
         # and its parts `fit.operands`, `compile_wait` (_consume_aot),
         # `fit.materialize`, `fit.dispatch`. None of them syncs.
-        with obs.span("fused_fit") as sp, obs.stage("fit"):
+        with obs.span("fused_fit") as sp, obs.stage("fit") as fit_stage:
             with obs.stage("fit.operands"):
                 ops = self._operands(coords, initial_models)
                 statics = self._statics(coords, initial_models)
@@ -1347,6 +1347,7 @@ class FusedFit:
             states, scores, total, packed_flat, conv = out
             if sp is not None:
                 sp.sync = out
+            fit_stage.attrs = self._fit_attrs(coords, ebs_all)
         if sp is not None:
             obs.convergence.record(
                 tuple(
@@ -1501,3 +1502,37 @@ class FusedFit:
             best_evaluation=None,
             history=tuple(history),
         )
+
+    _fit_attrs_cache: dict | None = None  # an instance's own once made
+
+    def _fit_attrs(self, coords, ebs_all) -> dict:
+        """The ``fit`` stage's attributes: per random-effect coordinate
+        what the planner counted (``RandomEffectDataset.plan_counts``:
+        ``active_rows``, ``passive_rows``, ``capped_entities``), its
+        ``slab_rows`` and its ``rungs`` as ``[entities, row cap, route]``
+        with the ``solve.<route>`` scope ``_solve_block`` gives that slab.
+        Host ints and strings from shapes alone, made on the first fit of
+        this prepared data set and handed to every later one: a warm fit
+        pays one attribute read."""
+        attrs = self._fit_attrs_cache
+        if attrs is None:
+            from photon_tpu.algorithm.random_effect import solve_route
+
+            per_coord = {}
+            for cid in self.seq:
+                if self.kinds[cid] != "random":
+                    continue
+                inner = getattr(coords[cid], "inner", coords[cid])
+                statics = _re_statics(inner)
+                rungs = [
+                    [int(eb.x_values.shape[0]), int(eb.x_values.shape[1]),
+                     solve_route(statics, eb, precision=self.precision)]
+                    for eb in ebs_all[cid]["ebs"]
+                ]
+                per_coord[cid] = dict(
+                    inner.dataset.plan_counts or {},
+                    slab_rows=sum(b * r for b, r, _ in rungs),
+                    rungs=rungs,
+                )
+            attrs = self._fit_attrs_cache = {"coordinates": per_coord}
+        return attrs

@@ -1636,3 +1636,44 @@ class RandomEffectCoordinate:
     def score(self, model: RandomEffectModel) -> Array:
         """Model contribution per canonical row (active + passive)."""
         return model.score_dataset(self.dataset)
+
+
+def solve_route(
+    statics: dict, slab, *, precision: str = "float32", spmd: bool = False
+) -> str:
+    """The ``solve.<route>`` scope ``_solve_block`` gives a bucket whose
+    materialized slab is ``slab`` (an ``EntityBlocks``; only shapes and
+    dtypes are read, never a value): the same tests in the same order,
+    kept beside it so that a stage attribute can name the route without
+    a frame on the traced path. ``statics``: ``fused_fit._re_statics``."""
+    if statics["direct"]:
+        return "direct"
+    if statics["newton"]:
+        from photon_tpu.ops import newton_kernel as nk
+
+        _, r, s = slab.x_values.shape
+        dense = slab.x_indices is None or (
+            s <= DENSE_SUB_DIM_MAX
+            and int(np.prod(slab.x_indices.shape)) * s
+            <= ONE_HOT_ELEMENT_BUDGET
+        )
+        if not dense:
+            # A wide ELL slab is dense where the segment-reduce kernel
+            # serves it: asked of the function itself, on shapes alone.
+            dense = jax.eval_shape(
+                lambda xi, xv: segment_reduce.densify_ell_blocks(
+                    xi, xv, s, spmd=spmd),
+                slab.x_indices, slab.x_values,
+            ) is not None
+        dtype = (
+            jnp.bfloat16 if precision_mod.is_mixed(precision)
+            else slab.x_values.dtype
+        )
+        if dense and nk.kernel_supported(
+            statics["task"], dtype, r, s, spmd=spmd
+        ):
+            return "newton_kernel"
+        return "newton_xla"
+    if statics["use_owlqn"]:
+        return "owlqn"
+    return statics["opt_config"].optimizer_type.value.lower()

@@ -214,13 +214,22 @@ class BlockPlan:
         Returns an ``EntityBlocks`` whose ``offsets`` already include the
         coordinate-descent residuals. For sub_dims up to
         ``DENSE_SUB_DIM_MAX`` (within the one-hot element budget) the
-        feature slab comes out subspace-DENSE, built by one-hot einsums
-        (comparisons feeding a matmul) — row gathers are plain
-        ``jnp.take``; no batched gather/scatter, because those lower to
-        pathologically slow-compiling programs on TPU while the one-hot
-        contraction compiles in under a second and runs on the MXU. Wider
-        subspaces (or over-budget one-hot operands) fall back to ELL form
-        via gather lowerings: slower compiles, bounded memory.
+        feature slab comes out subspace-DENSE, built by one-hot
+        selections (comparisons feeding a contraction) — row gathers are
+        plain ``jnp.take``; no batched gather/scatter, because those lower
+        to pathologically slow-compiling programs on TPU while the one-hot
+        contraction compiles in under a second. Wider subspaces (or
+        over-budget one-hot operands) fall back to ELL form via gather
+        lowerings: slower compiles, bounded memory.
+
+        A dense shard's selection is a sum of elementwise products, NOT
+        a ``dot_general``: a slab must hold the raw features exactly,
+        whatever JAX's matmul precision is. As a matmul it held them
+        rounded to bf16 at the TPU's default precision, and at
+        ``highest`` the 12-slab program of a heavy-tailed GLMix came back
+        from XLA with one slab wrong (every column of 3 119 of a
+        [4 868, 256, 17] slab's entities a copy of the first; PERF.md
+        section 6, PR 32).
         """
         b, r = self.row_ids.shape
         s = self.proj.shape[-1]
@@ -255,7 +264,8 @@ class BlockPlan:
                     proj[:, None, :]
                     == jnp.arange(d, dtype=proj.dtype)[None, :, None]
                 ).astype(dtype)  # [B, d, S]
-                x_values = jnp.einsum("brf,bfs->brs", xr, onehot)
+                x_values = jnp.sum(
+                    xr[:, :, :, None] * onehot[:, None, :, :], axis=2)
                 x_values = jnp.where(row_mask[:, :, None], x_values, 0)
                 x_indices = None
             else:
@@ -398,6 +408,10 @@ class RandomEffectDataset:
     # through ``device_plans()``. ``blocks`` carries host-numpy plan leaves
     # when this is set.
     packed_view: object | None = None
+    # Host integers of the plan this dataset was built from
+    # (``_plan_counts``): the `fit` stage's attributes read them, so a
+    # fit never counts anything. None on an AOT skeleton.
+    plan_counts: dict | None = None
 
     @property
     def num_rows(self) -> int:
@@ -2016,6 +2030,20 @@ def build_random_effect_dataset(
         block_intercepts_np=tuple(bh["intercepts"] for bh in bucket_host),
         block_gram_mults=tuple(gram_mults_list),
         covered_np=covered_np,
+        plan_counts=_plan_counts(plan),
+    )
+
+
+def _plan_counts(plan: _Plan) -> dict:
+    """What the reservoir cap did, as host integers: rows that train
+    (the kept rows of entities that train a model), rows that are only
+    scored (a capped entity's other rows, and every row of an entity
+    under the lower bound), and entities with more rows than they keep."""
+    active_rows = int(plan.counts[plan.active].sum())
+    return dict(
+        active_rows=active_rows,
+        passive_rows=int(plan.codes.shape[0]) - active_rows,
+        capped_entities=int(np.count_nonzero(plan.counts_full > plan.counts)),
     )
 
 
@@ -2061,4 +2089,5 @@ def _finalize_lazy(
         ),
         covered_np=covered_np,
         packed_view=devs,
+        plan_counts=_plan_counts(plan),
     )
