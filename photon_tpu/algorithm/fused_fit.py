@@ -18,6 +18,15 @@ table operands, and regularization weights stay traced so a config-grid
 sweep (GameEstimator.scala:452-468 warm-start ladder) re-enters the SAME
 executable with new lambdas.
 
+Row order (``FusedFit._choose_home``): nothing outside the program sees
+the order of its rows (``run`` keeps the coefficient tables and drops the
+per-row scores), so the rows stand in the entity order of ONE
+random-effect coordinate, its "home": that coordinate's rows <-> slab
+moves are then contiguous copies (ops/ragged.py) and not element gathers,
+which cost 7 ns an index on a TPU v5e whatever they point at. The
+permutation is made once per prepared data set, in the materialize
+program; DATA.md "The fused fit's row order".
+
 Eligibility (``fuse_eligible``): single device (collectives stay on the
 serialized unfused path), no validation-driven best-model tracking, lazy
 random-effect datasets, no down-sampling (its per-iteration reseeding is
@@ -59,11 +68,13 @@ from photon_tpu.models.game import (
     _bucket_score_add,
     _passive_score_set_dense,
     _passive_score_set_sparse,
+    _score_raw_dense,
     bucket_score_parts,
     passive_raw_scores,
     score_raw_features,
 )
 from photon_tpu.models.glm import Coefficients, GeneralizedLinearModel
+from photon_tpu.ops import ragged
 
 Array = jax.Array
 logger = logging.getLogger(__name__)
@@ -156,6 +167,12 @@ NUMERICS_AUDIT = dict(
             "are unique and sorted (bucket-slab construction), "
             "diagnostic slots are distinct iteration indices"
         ),
+        # the home order's inverse: iota set-scattered through a
+        # permutation (a sort's index output), every destination once
+        "materialize_*:scatter": (
+            "set-scatter through a permutation: _home_order inverts "
+            "the sorted row order, each row written exactly once"
+        ),
     },
     suppress={
         "numerics-scan-recast": (
@@ -173,6 +190,23 @@ NUMERICS_AUDIT = dict(
     },
     tolerance=1.5,
 )
+
+
+# A first cut that spares a compile (``FusedFit._choose_home``): home's order
+# is not tried where the fixed effect's batches, of which it keeps a second
+# copy, are more than a ninth of the device. On a TPU v5e (16.9 GB) the fit
+# program of a 2.14 GB batch (8 M rows of 64 features) reserves 9.87 GB of
+# scratch and was refused beside home's copy by 0.95 GB; that of a 1.61 GB
+# batch (6 M rows) reserves 6.56 GB and runs (PERF.md section 6, PR 33). The
+# cut is a guess between those two readings and decides nothing that can
+# fail: what passes it is compiled, and the compiler's own account of the
+# two programs has the last word (``FusedFit._home_fits``).
+_HOME_MEMORY_FACTOR = 9
+
+
+def _device_bytes_limit() -> int | None:
+    """The device's memory, where the backend says (the CPU does not)."""
+    return (jax.local_devices()[0].memory_stats() or {}).get("bytes_limit")
 
 
 class _PackedDiags:
@@ -432,6 +466,7 @@ class FusedFit:
                     "buf": buf,
                     "n_blocks": len(ds.blocks),
                 }
+        self._home = self._choose_home(coords)
         # FE normalization contexts ride as trace-time constants: the
         # factor/shift arrays are tiny [d] vectors fixed per estimator
         # generation, and embedding them keeps _run_impl's static
@@ -482,6 +517,71 @@ class FusedFit:
     # operand assembly (per run; cheap)
     # ------------------------------------------------------------------
 
+    def _choose_home(self, coords) -> str | None:
+        """The random-effect coordinate whose entity order the fit's rows
+        take, or None (today's canonical order).
+
+        Home is the coordinate whose two row <-> slab maps hold the most
+        indices (slab slots + rows; the rows are every coordinate's
+        alike, so: the most slab slots, the first of equals). Only one
+        order can be home; the other coordinates keep their gathers,
+        renumbered. Shapes and layout alone decide, so the AOT skeleton
+        and the built data set agree: every random-effect coordinate must
+        carry the packed score map and build dense slabs, home's shard
+        must be dense, and every fixed-effect batch must be a set of
+        ``[n, ...]`` arrays (a dual-ELL tail is not row-gatherable).
+
+        And the device must have the room: the batches in home's order
+        are a second copy of the fixed effect's data for as long as the
+        prepared data set lives. Here only the first cut, by the batches'
+        size (``_HOME_MEMORY_FACTOR``); ``compile_programs`` asks the
+        compiled programs and takes home away again where they say no."""
+        from photon_tpu.data.dataset import DenseFeatures
+        from photon_tpu.data.random_effect import packed_len_with_score_inv
+
+        slots: dict[str, int] = {}
+        copies = 0
+        for cid in self.seq:
+            if self.kinds[cid] == "locked":
+                continue
+            inner = getattr(coords[cid], "inner", coords[cid])
+            if self.kinds[cid] == "fixed":
+                n = inner.batch.num_samples
+                leaves = jax.tree.leaves(inner.batch)
+                if any(leaf.shape[:1] != (n,) for leaf in leaves):
+                    return None
+                copies += sum(leaf.nbytes for leaf in leaves)
+                continue
+            meta = self._re_meta[cid]
+            blocks = inner.dataset.blocks
+            if (
+                not blocks
+                or meta["slices"] is None
+                or len(meta["slices"])
+                != packed_len_with_score_inv(len(blocks))
+                or not all(b.dense_slab for b in blocks)
+            ):
+                return None
+            slots[cid] = sum(int(np.prod(b.row_ids.shape)) for b in blocks)
+        if not slots:
+            return None
+        home = max(slots, key=slots.get)
+        ds = getattr(coords[home], "inner", coords[home]).dataset
+        if not isinstance(ds.raw, DenseFeatures):
+            return None
+        limit = _device_bytes_limit()
+        if limit is not None and copies * _HOME_MEMORY_FACTOR > limit:
+            return None
+        return home
+
+    @staticmethod
+    def _home_of(ebs_all: dict) -> str | None:
+        """The coordinate in whose order these materialized slabs, and so
+        every fit over them, stand; None: the canonical order."""
+        return next(
+            (cid for cid, m in ebs_all.items()
+             if m.get("home") is not None), None)
+
     def _mat_fn(self, mat_ops: dict):
         """Unpack plan arrays + materialize every bucket slab, traced.
 
@@ -490,14 +590,37 @@ class FusedFit:
         gather the [B, R, S] slabs, and emit (EntityBlocks, scoring plan
         arrays, projector table) — everything later fits consume. Each
         coordinate's operations carry the scope ``coord.<cid>/materialize``
-        (metadata only)."""
+        (metadata only).
+
+        With a home coordinate the fit's row order is made here too, once
+        per prepared data set (``_home_order``): home's slabs are built by
+        contiguous moves from its shard in that order, the other
+        coordinates' maps are renumbered into it, and the fixed-effect
+        batches are gathered into it (``out[home]["home"]``)."""
+        unpacked = {
+            cid: self._unpack(cid, op) for cid, op in mat_ops.items()
+            if self.kinds[cid] == "random"
+        }
+        home = self._home
+        order = None
+        if home is not None:
+            with jax.named_scope(f"coord.{home}/materialize"):
+                order = self._home_order(unpacked[home], mat_ops[home])
         out = {}
-        for cid, op in mat_ops.items():
+        for cid, parts in unpacked.items():
             with jax.named_scope(f"coord.{cid}/materialize"):
-                out[cid] = self._mat_one(cid, op)
+                out[cid] = self._mat_one(cid, parts, mat_ops[cid], order)
+        if order is not None:
+            for cid, op in mat_ops.items():
+                if self.kinds[cid] != "fixed":
+                    continue
+                with jax.named_scope(f"coord.{cid}/materialize"):
+                    out[home]["home"]["batches"][cid] = jax.tree.map(
+                        lambda a: jnp.take(a, order["perm"], axis=0),
+                        op["batch"])
         return out
 
-    def _mat_one(self, cid: str, op: dict) -> dict:
+    def _unpack(self, cid: str, op: dict) -> dict:
         from photon_tpu.data.random_effect import (
             PLAN_ARRAYS_PER_BUCKET as _PPB,
             BlockPlan,
@@ -507,37 +630,186 @@ class FusedFit:
         )
 
         meta = self._re_meta[cid]
-        if "buf" in op:
-            arrays = []
-            for off, shape in meta["slices"]:
-                n = int(np.prod(shape)) if shape else 1
-                arrays.append(
-                    jax.lax.slice_in_dim(
-                        op["buf"], off, off + n).reshape(shape)
-                )
-            plans = [
-                BlockPlan(
-                    entity_codes=arrays[_PPB * i],
-                    row_ids=arrays[_PPB * i + 1],
-                    row_counts=arrays[_PPB * i + 2],
-                    proj=arrays[_PPB * i + 3],
-                    intercept_slots=arrays[_PPB * i + 4],
-                    raw=op["raw"],
-                    raw_labels=op["labels"],
-                    raw_offsets=op["offsets"],
-                    raw_weights=op["weights"],
-                )
-                for i in range(meta["n_blocks"])
-            ]
+        if "buf" not in op:
+            return {"plans": list(op["plans"]), "proj_dev": op["proj_dev"],
+                    "score_inv": None}
+        arrays = []
+        for off, shape in meta["slices"]:
+            n = int(np.prod(shape)) if shape else 1
+            arrays.append(
+                jax.lax.slice_in_dim(
+                    op["buf"], off, off + n).reshape(shape)
+            )
+        plans = [
+            BlockPlan(
+                entity_codes=arrays[_PPB * i],
+                row_ids=arrays[_PPB * i + 1],
+                row_counts=arrays[_PPB * i + 2],
+                proj=arrays[_PPB * i + 3],
+                intercept_slots=arrays[_PPB * i + 4],
+                raw=op["raw"],
+                raw_labels=op["labels"],
+                raw_offsets=op["offsets"],
+                raw_weights=op["weights"],
+            )
+            for i in range(meta["n_blocks"])
+        ]
+        return {
+            "plans": plans,
             # Layout contract (build_random_effect_dataset): the
             # projector sits at 5*n_blocks; trailing arrays (the
             # score map) come AFTER it — arrays[-1] would pick those.
-            proj_dev = arrays[packed_proj_index(meta["n_blocks"])]
-        else:
-            plans = list(op["plans"])
-            proj_dev = op["proj_dev"]
+            "proj_dev": arrays[packed_proj_index(meta["n_blocks"])],
+            # Inverse score map (row -> flat bucket/passive score
+            # position): present on packed layouts with the extra
+            # trailing array; enables the gather-based scorer.
+            "score_inv": (
+                arrays[packed_score_inv_index(meta["n_blocks"])]
+                if len(meta["slices"])
+                == packed_len_with_score_inv(meta["n_blocks"])
+                else None
+            ),
+        }
+
+    @staticmethod
+    def _home_order(parts: dict, op: dict) -> dict:
+        """The fit's row order from home's plan: ``perm`` (position ->
+        canonical row), its inverse, and per bucket ``moves``: where its
+        rows start in that order and the receive bits of its two moves
+        (ops/ragged.py); and ``stacked``, home's shard beside its label /
+        offset / weight vectors, in that order.
+
+        The order: home's buckets in ladder order, inside a bucket its
+        entities in slab order, inside an entity its ACTIVE rows as
+        ``row_ids`` lists them (that is the order of the score map's flat
+        positions); then its passive rows, grouped by entity."""
+        score_inv = parts["score_inv"]
+        n = score_inv.shape[0]
+        slots = sum(int(np.prod(p.row_ids.shape)) for p in parts["plans"])
+        iota = jnp.arange(n, dtype=jnp.int32)
+        passive = score_inv >= slots
+        _, _, perm = lax.sort(
+            (
+                jnp.where(passive, slots, score_inv),
+                jnp.where(passive, op["score_codes"], 0),
+                iota,
+            ),
+            num_keys=2, is_stable=True,
+        )
+        inv = jnp.zeros(n, jnp.int32).at[perm].set(
+            iota, unique_indices=True)
+        moves = []
+        base = jnp.zeros((), jnp.int32)
+        for p in parts["plans"]:
+            moves.append(
+                (base, *ragged.shift_bits(p.row_counts, p.row_ids.shape[1])))
+            base = base + jnp.sum(p.row_counts, dtype=jnp.int32)
+        # Home's shard and its three row vectors, side by side: ONE row
+        # gather puts them in the order, and one move a bucket builds its
+        # slab from them.
+        # (In the wider of their dtypes, which holds both exactly; each
+        # column goes back to its own.)
+        vectors = ("labels", "offsets", "weights")
+        x = op["raw"].x
+        wide = jnp.result_type(x.dtype, *(op[name].dtype for name in vectors))
+        stacked = jnp.take(
+            jnp.concatenate(
+                [x.astype(wide)]
+                + [op[name][:, None].astype(wide) for name in vectors],
+                axis=1),
+            perm, axis=0)
+        return {"perm": perm, "inv": inv, "moves": tuple(moves),
+                "stacked": stacked}
+
+    @staticmethod
+    def _pad_rows(arr: Array, slab_shapes) -> Array:
+        """``arr`` with room behind it for the longest bucket's move."""
+        room = max(b * cap for b, cap in slab_shapes)
+        return jnp.pad(arr, [(0, room)] + [(0, 0)] * (arr.ndim - 1))
+
+    @staticmethod
+    def _slab_rows(padded: Array, move, b: int, cap: int) -> Array:
+        """Rows ``[base, ...)`` of a home-ordered (and ``_pad_rows``
+        padded) array in ``[B, cap, ...]`` slab layout: a contiguous
+        slice, then the ragged -> padded move. Slots past an entity's
+        count hold stale values; the caller masks them."""
+        base, into_slab, _ = move
+        seg = lax.dynamic_slice_in_dim(padded, base, b * cap)
+        slab = ragged.ragged_to_padded(
+            seg, into_slab, ragged.shift_steps(b, cap))
+        return slab.reshape((b, cap) + padded.shape[1:])
+
+    @staticmethod
+    def _rows_from_slabs(parts, moves, slab_shapes, n: int) -> Array:
+        """The way back: each bucket's flat ``[B * cap]`` slab vector
+        leaves by the padded -> ragged move and lands where the bucket's
+        rows start in the home order. In ladder order, so a bucket's
+        stale tail is overwritten by the next one's rows; the last tail
+        falls on the passive rows (the caller writes those) or past
+        ``n``."""
+        room = max(b * cap for b, cap in slab_shapes)
+        z = jnp.zeros(n + room, parts[0].dtype)
+        for part, (base, _, out_of_slab), (b, cap) in zip(
+            parts, moves, slab_shapes
+        ):
+            z = lax.dynamic_update_slice_in_dim(
+                z,
+                ragged.padded_to_ragged(
+                    part, out_of_slab, ragged.shift_steps(b, cap)),
+                base, axis=0,
+            )
+        return z[:n]
+
+    def _mat_one(self, cid: str, parts: dict, op: dict, order) -> dict:
         from photon_tpu.ops import precision as precision_mod
 
+        plans = parts["plans"]
+        score_inv = parts["score_inv"]
+        home = None
+        if order is None:
+            blocks = [p.materialize(None) for p in plans]
+        elif cid != self._home:
+            # Slabs from the raw shard by today's ids; the maps a fit
+            # reads are renumbered into the fit's order.
+            blocks = [
+                dataclasses.replace(
+                    eb, row_ids=jnp.take(order["inv"], eb.row_ids))
+                for eb in (p.materialize(None) for p in plans)
+            ]
+            score_inv = jnp.take(score_inv, order["perm"])
+        else:
+            perm = order["perm"]
+            p0 = plans[0]
+            d = p0.raw.x.shape[1]
+            padded = self._pad_rows(
+                order["stacked"], [p.row_ids.shape for p in plans])
+            blocks = []
+            for p, move in zip(plans, order["moves"]):
+                slab = self._slab_rows(padded, move, *p.row_ids.shape)
+                blocks.append(p.materialize(None, gathered={
+                    "x": slab[..., :d].astype(p0.raw.x.dtype),
+                    "labels": slab[..., d].astype(p0.raw_labels.dtype),
+                    "offsets": slab[..., d + 1].astype(
+                        p0.raw_offsets.dtype),
+                    "weights": slab[..., d + 2].astype(
+                        p0.raw_weights.dtype),
+                }))
+            # The passive rows are the order's tail: their features and
+            # owners are static slices, read (not gathered) by every fit.
+            tail = self._re_meta[cid]["passive"]
+            n_act = perm.shape[0] - (0 if tail is None else tail.size)
+            home = {
+                "perm": perm,
+                "moves": order["moves"],
+                "passive_x": (
+                    None if tail is None
+                    else order["stacked"][n_act:, :d].astype(
+                        p0.raw.x.dtype)),
+                "passive_codes": None if tail is None else jnp.take(
+                    op["score_codes"], perm[n_act:]),
+                "batches": {},
+            }
+            score_inv = None  # home's scores leave by the moves
         # bf16 slab storage (mixed precision): the gather happens
         # once per dataset generation, so the cast is amortized —
         # every later sweep reads the slab at half HBM width.
@@ -547,7 +819,7 @@ class FusedFit:
                 x_values=precision_mod.in_storage(
                     eb.x_values, self.precision),
             )
-            for eb in (p.materialize(None) for p in plans)
+            for eb in blocks
         )
         return {
             "ebs": ebs,
@@ -555,17 +827,9 @@ class FusedFit:
                 (p.row_ids, p.row_counts, p.entity_codes)
                 for p in plans
             ),
-            "proj_dev": proj_dev,
-            # Inverse score map (row -> flat bucket/passive score
-            # position): present on packed layouts with the extra
-            # trailing array; enables the gather-based scorer.
-            "score_inv": (
-                arrays[packed_score_inv_index(meta["n_blocks"])]
-                if "buf" in op
-                and len(meta["slices"])
-                == packed_len_with_score_inv(meta["n_blocks"])
-                else None
-            ),
+            "proj_dev": parts["proj_dev"],
+            "score_inv": score_inv,
+            "home": home,
         }
 
     def _zeros(self, shape, dtype) -> Array:
@@ -657,9 +921,12 @@ class FusedFit:
     def _mat_operands(self, coords) -> dict:
         mat_ops = {}
         for cid in self.seq:
+            inner = getattr(coords[cid], "inner", coords[cid])
+            if self.kinds[cid] == "fixed" and self._home is not None:
+                # The batch to be put in home's order.
+                mat_ops[cid] = {"batch": inner.batch}
             if self.kinds[cid] != "random":
                 continue
-            inner = getattr(coords[cid], "inner", coords[cid])
             ds = inner.dataset
             meta = self._re_meta[cid]
             if meta["slices"] is not None and ds.blocks:
@@ -671,6 +938,8 @@ class FusedFit:
                     "offsets": b0.raw_offsets,
                     "weights": b0.raw_weights,
                 }
+                if cid == self._home:
+                    mat_ops[cid]["score_codes"] = ds.score_codes
             else:
                 mat_ops[cid] = {
                     "plans": ds.device_plans(),
@@ -711,13 +980,15 @@ class FusedFit:
     # ------------------------------------------------------------------
 
     def _re_score(self, w, op, mat):
-        """Model contribution per canonical row (active+passive), traced.
+        """Model contribution per row (active+passive) in the fit's row
+        order, traced.
 
         With a packed score map this is scatter-FREE: per-bucket score
         blocks and the passive-row scores concatenate into one flat
-        vector that a single gather distributes to canonical rows (a
-        TPU scatter-add of the same pass measured ~4x slower). Otherwise
-        mirrors models/game.py _score_via_buckets."""
+        vector that a single gather distributes to the rows (a TPU
+        scatter-add of the same pass measured ~4x slower); the home
+        coordinate's need no gather either. Otherwise mirrors
+        models/game.py _score_via_buckets."""
         from photon_tpu.data.dataset import DenseFeatures
 
         n = op["score_codes"].shape[0]
@@ -726,12 +997,28 @@ class FusedFit:
             # ELL fallback bucket present: score straight off the raw shard.
             return score_raw_features(
                 w, op["score_codes"], op["raw"], proj_dev)
-        if mat.get("score_inv") is not None:
+        home = mat.get("home")
+        if home is not None or mat.get("score_inv") is not None:
             parts = bucket_score_parts(
                 w,
                 tuple(eb.x_values for eb in mat["ebs"]),
                 tuple(eb.entity_codes for eb in mat["ebs"]),
             )
+            if home is not None:
+                # Home: the buckets' scores leave their slabs by
+                # contiguous moves; the passive rows are the order's tail
+                # and score from their slice of the ordered shard.
+                z = self._rows_from_slabs(
+                    parts, home["moves"],
+                    [eb.weights.shape for eb in mat["ebs"]], n,
+                ).astype(w.dtype)
+                if home["passive_x"] is not None:
+                    zp = _score_raw_dense(
+                        w, home["passive_codes"], home["passive_x"],
+                        proj_dev)
+                    z = lax.dynamic_update_slice_in_dim(
+                        z, zp.astype(w.dtype), n - zp.shape[0], axis=0)
+                return z
             if op["passive"] is not None:
                 parts.append(passive_raw_scores(
                     w, op["passive"], op["score_codes"], op["raw"],
@@ -809,6 +1096,14 @@ class FusedFit:
         # The running TOTAL stays in f32 (it is the accumulator every
         # residual derives from); the per-coordinate score CARRIES are
         # stored through _store_score — bf16 under mixed precision.
+        # The rows stand in home's order (``_mat_fn``), or canonically.
+        home = ebs_all.get(self._home_of(ebs_all), {}).get("home")
+        if home is not None:
+            ops = tuple(
+                dict(op, batch=home["batches"][cid]) if "batch" in op
+                else op
+                for cid, op in zip(self.seq, ops)
+            )
         states: list = []
         scores: list = []
         diags: list = []
@@ -818,6 +1113,8 @@ class FusedFit:
             if kind == "locked":
                 states.append(())
                 z = op["z"]
+                if home is not None:
+                    z = jnp.take(z, home["perm"])
                 diags.append(())
             elif kind == "fixed":
                 means = op["w0"]
@@ -934,12 +1231,25 @@ class FusedFit:
                         its_e = jnp.zeros(e, jnp.int32)
                         rs_e = jnp.zeros(e, jnp.int32)
                         mat = ebs_all[self.seq[i]]
-                        for (_, _, codes), eb in zip(
-                            mat["score_plans"], mat["ebs"]
+                        residuals = [residual] * len(mat["ebs"])
+                        if mat.get("home") is not None:
+                            # Home's residuals enter each slab by a
+                            # contiguous move, not through row_ids.
+                            with jax.named_scope("residual"):
+                                shapes = [
+                                    eb.weights.shape for eb in mat["ebs"]]
+                                padded = self._pad_rows(residual, shapes)
+                                residuals = [
+                                    self._slab_rows(padded, move, *shape)
+                                    for move, shape in zip(
+                                        mat["home"]["moves"], shapes)
+                                ]
+                        for (_, _, codes), eb, slab_residual in zip(
+                            mat["score_plans"], mat["ebs"], residuals
                         ):
                             w_all, v_all, its, rs = _solve_block(
                                 eb,
-                                residual,
+                                slab_residual,
                                 op["factors"],
                                 op["shifts"],
                                 w_prev,
@@ -1022,7 +1332,7 @@ class FusedFit:
         directly; this distributes ``total_seconds`` — the fit program's
         REAL dispatch->completion window, measured by the run span's
         root sync — proportionally to each block's analytic work estimate
-        (the same counting family as bench.estimate_model_flops), using
+        (counts from shapes, as ``benchmark/costs.py`` takes its own), using
         the MEASURED per-iteration solver counts from the packed
         diagnostics: fixed effects at iters x 4nd value/grad passes +
         scoring, random effects at mean-Newton-iters x (margins + Hessian
@@ -1169,24 +1479,79 @@ class FusedFit:
         """Lower (never execute) the slab materialization program."""
         return self._mat_jit.lower(self._mat_operands(coords))
 
-    def aot_lower(self, coords) -> dict:
-        """Trace the materialize + cold-fit programs for AOT warm compile.
+    def compile_programs(self, coords, initial_models=None) -> dict:
+        """The materialize and fit programs, compiled ahead of their first
+        run: the AOT warm compile's artifact (``key`` is the caller's).
 
         The SAME operand assembly as ``trace``/``run`` (the audited
         ingest-pipeline contract pins that these jaxprs match the
         production generation's signatures exactly), packaged with the
-        statics so the caller can key the compiled executables."""
-        mat_ops = self._mat_operands(coords)
-        mat_traced = self._mat_jit.trace(mat_ops)
-        ebs_avals = jax.eval_shape(self._mat_fn, mat_ops)
-        ops = self._operands(coords, None)
-        statics = self._statics(coords, None)
-        fit_traced = self._jit.trace(ops, ebs_avals, statics=statics)
-        return {
-            "mat_traced": mat_traced,
-            "fit_traced": fit_traced,
-            "statics": statics,
+        statics so the caller can key the compiled executables.
+
+        A home order stands only if the device has room for it
+        (``_home_fits``): where the compiled programs say it has not, home
+        is given up and both are compiled once more in the canonical
+        order. ``home`` says which order the executables are for."""
+        from photon_tpu.utils.compile_cache import aot_compile
+
+        while True:
+            mat_ops = self._mat_operands(coords)
+            mat_traced = self._mat_jit.trace(mat_ops)
+            ebs_avals = jax.eval_shape(self._mat_fn, mat_ops)
+            ops = self._operands(coords, initial_models)
+            statics = self._statics(coords, initial_models)
+            fit_traced = self._jit.trace(ops, ebs_avals, statics=statics)
+            art = {
+                "statics": statics,
+                "layout": self.packed_layout(),
+                "home": self._home,
+                "mat": aot_compile(
+                    mat_traced.lower(), ledger_key="fused_fit/materialize"),
+                "fit": aot_compile(
+                    fit_traced.lower(), ledger_key="fused_fit/fit"),
+                "mat_text": str(mat_traced.jaxpr),
+                "fit_text": str(fit_traced.jaxpr),
+            }
+            if self._home is None or self._home_fits(art, mat_ops, ops):
+                return art
+            self._home = None
+
+    @staticmethod
+    def _home_fits(art: dict, mat_ops, ops) -> bool:
+        """Whether the device holds both compiled programs beside what
+        they keep on it, by the compiler's own account
+        (``memory_analysis``, which counts every array in the layout the
+        device stores it in): the data set's arrays (the materialize
+        program's arguments, and what a fit is handed besides) and the
+        materialize program's outputs for as long as the prepared data
+        set lives, a fit's outputs, and the larger of the two programs'
+        scratch, which the device reserves when a program is loaded and
+        keeps. No limit or no account (the CPU states no limit): it fits.
+        ``art["memory"]`` keeps the sums."""
+        limit = _device_bytes_limit()
+        mat = art["mat"].memory_analysis()
+        fit = art["fit"].memory_analysis()
+        if limit is None or mat is None or fit is None:
+            return True
+        counted = {id(a) for a in jax.tree.leaves(mat_ops)}
+        besides = {
+            id(a): int(np.prod(a.shape)) * np.dtype(a.dtype).itemsize
+            for a in jax.tree.leaves(ops)
+            if hasattr(a, "shape") and id(a) not in counted
         }
+        art["memory"] = memory = {
+            "resident": mat.argument_size_in_bytes
+            + mat.output_size_in_bytes + fit.output_size_in_bytes
+            + sum(besides.values()),
+            "scratch": max(mat.temp_size_in_bytes, fit.temp_size_in_bytes),
+            "limit": limit,
+        }
+        fits = memory["resident"] + memory["scratch"] <= limit
+        if not fits:
+            logger.info(
+                "fused fit: no room for home order %r on the device "
+                "(%s); the canonical order is kept", art["home"], memory)
+        return fits
 
     def _consume_aot(self) -> dict | None:
         """Resolve the pending warm-compile future (blocking if the
@@ -1211,6 +1576,20 @@ class FusedFit:
         for another layout would be accepted by the aval check and read
         the wrong plan arrays, so ``_run_mat`` compares layouts itself."""
         return {cid: m["slices"] for cid, m in self._re_meta.items()}
+
+    def _settle_home(self, coords, initial_models, aot):
+        """Before the slabs are built: the prepared data set takes the
+        order its compiled programs are for, since ``compile_programs``
+        has asked the device. Where no warm compile came (a warm start, a
+        validation set) and the device states a limit, the programs are
+        compiled here and kept. Returns the artifact to run."""
+        if self._home is None:
+            return aot
+        if aot is None and _device_bytes_limit() is not None:
+            aot = self._aot = self.compile_programs(coords, initial_models)
+        if aot is not None and aot.get("home") != self._home:
+            self._home = None
+        return aot
 
     def _run_mat(self, coords, aot):
         """Materialize slabs via the AOT executable when compatible."""
@@ -1277,6 +1656,7 @@ class FusedFit:
             )
             if ebs_all is None:
                 with obs.stage("fit.materialize") as mat_stage:
+                    aot = self._settle_home(coords, initial_models, aot)
                     ebs_all = self._run_mat(coords, aot)
                 mat_window = (mat_stage.t0, mat_stage.t1)
                 if share is not None:
@@ -1518,11 +1898,12 @@ class FusedFit:
         if attrs is None:
             from photon_tpu.algorithm.random_effect import solve_route
 
-            per_coord = {}
+            per_coord, rows = {}, {}
             for cid in self.seq:
                 if self.kinds[cid] != "random":
                     continue
                 inner = getattr(coords[cid], "inner", coords[cid])
+                rows[cid] = inner.dataset.num_rows
                 statics = _re_statics(inner)
                 rungs = [
                     [int(eb.x_values.shape[0]), int(eb.x_values.shape[1]),
@@ -1534,5 +1915,17 @@ class FusedFit:
                     slab_rows=sum(b * r for b, r, _ in rungs),
                     rungs=rungs,
                 )
-            attrs = self._fit_attrs_cache = {"coordinates": per_coord}
+            # Indices a CD iteration still gathers element by element:
+            # every coordinate but home reads its residuals through
+            # row_ids (slab slots), its scores through the score map
+            # (rows) and its passive rows' features by row.
+            home = self._home_of(ebs_all)
+            attrs = self._fit_attrs_cache = {
+                "coordinates": per_coord,
+                "home": home,
+                "gather_indices": sum(
+                    c["slab_rows"] + rows[cid] + c.get("passive_rows", 0)
+                    for cid, c in per_coord.items() if cid != home
+                ),
+            }
         return attrs

@@ -1092,7 +1092,7 @@ def _solve_one_entity(
 )
 def _solve_block(
     block,  # EntityBlocks | BlockPlan (pytree structure selects the path)
-    residuals: Array | None,  # [n] canonical residual scores, or None
+    residuals: Array | None,  # [n] row residuals, [B, R] slab ones, or None
     factors_full: Array | None,  # [d] global normalization factors
     shifts_full: Array | None,  # [d] global normalization shifts
     w0_full: Array | None,  # [E, Smax] original-space warm starts
@@ -1137,12 +1137,16 @@ def _solve_block(
         offsets = block.offsets
         if residuals is not None:
             with jax.named_scope("residual"):
-                # Padding rows alias canonical row 0; mask their gather.
+                # One rule on what is handed over: [n] row residuals are
+                # gathered through row_ids; [B, R] ones stand in slab
+                # layout already (the fused fit's home coordinate moves
+                # them there contiguously). Padding slots alias canonical
+                # row 0, or hold stale values: masked either way.
+                if residuals.ndim == 1:
+                    residuals = jnp.take(
+                        residuals, block.row_ids, mode="clip")
                 offsets = offsets + jnp.where(
-                    block.weights > 0,
-                    jnp.take(residuals, block.row_ids, mode="clip"),
-                    0.0,
-                )
+                    block.weights > 0, residuals, 0.0)
     if precision_mod.is_mixed(precision):
         # bf16 SLAB STORAGE (the mixed-precision policy): the design
         # slab — the dominant per-iteration HBM read — is held and read

@@ -77,10 +77,12 @@ Array = jax.Array
 # ``_assign_buckets`` applies above the largest cap too. Padding rows carry
 # weight 0 but are not free: every fit gathers the row residuals into the
 # padded slabs, and the slab build the features, and on the chip (TPU v5e)
-# those gathers run at 29-38 M slab rows/s whatever a row holds, so their
-# time goes with the SLAB rows (PERF.md section 6, PR 29: a ratio-4 ladder
-# held 1.75 x the slab rows on the benchmark's GLMix and a fit took 1.45-1.6 x
-# as long).
+# an element gather costs about 7 ns an index (116-151 M slab rows/s)
+# whatever a row holds, so its time goes with the SLAB rows (PERF.md
+# section 6, PR 29: a ratio-4 ladder held 1.75 x the slab rows on the
+# benchmark's GLMix and a fit took 1.45-1.6 x as long). The fused fit's home
+# coordinate moves its rows without a gather (PR 33, ops/ragged.py: a pass
+# over the slab's slots); every other coordinate still pays the gather.
 DEFAULT_BUCKET_CAPS = (16, 32, 64, 128, 256, 512, 1024, 2048, 4096)
 # The price of a fine ladder: each occupied rung is a solver instance of the
 # fused program, 2-3.5 s of trace on the chip's host in EVERY process (a
@@ -208,8 +210,32 @@ class BlockPlan:
     def sub_dim(self) -> int:
         return self.proj.shape[-1]
 
-    def materialize(self, residuals: Array | None = None) -> EntityBlocks:
+    @property
+    def dense_slab(self) -> bool:
+        """Whether ``materialize`` builds this bucket's feature slab
+        subspace-DENSE (``x_indices is None``): shapes alone decide."""
+        b, r = self.row_ids.shape
+        s = self.proj.shape[-1]
+        if isinstance(self.raw, DenseFeatures):
+            width = self.raw.x.shape[1]
+        else:
+            width = r * self.raw.indices.shape[1]
+        return (
+            s <= DENSE_SUB_DIM_MAX
+            and b * width * s <= ONE_HOT_ELEMENT_BUDGET
+        )
+
+    def materialize(
+        self, residuals: Array | None = None, *, gathered: dict | None = None,
+    ) -> EntityBlocks:
         """Gather the bucket's training slabs (traceable; runs in jit).
+
+        ``gathered``: the rows of a DENSE shard already in slab layout,
+        ``labels`` / ``offsets`` / ``weights`` as ``[B, R]`` and ``x`` as
+        ``[B, R, d]``; left out, they are gathered through ``row_ids``.
+        The fused fit hands its home coordinate's over, moved there
+        contiguously (``algorithm/fused_fit.py``). Padding slots may hold
+        anything: every slab is masked by ``row_counts`` below.
 
         Returns an ``EntityBlocks`` whose ``offsets`` already include the
         coordinate-descent residuals. For sub_dims up to
@@ -234,15 +260,21 @@ class BlockPlan:
         b, r = self.row_ids.shape
         s = self.proj.shape[-1]
         rows = self.row_ids
+        if gathered is None:
+            gathered = {
+                "labels": jnp.take(self.raw_labels, rows),
+                "weights": jnp.take(self.raw_weights, rows),
+                "offsets": jnp.take(self.raw_offsets, rows),
+            }
+            if isinstance(self.raw, DenseFeatures):
+                gathered["x"] = jnp.take(self.raw.x, rows, axis=0)
         dtype = self.raw_weights.dtype
         row_mask = jnp.arange(r, dtype=jnp.int32)[None, :] < (
             self.row_counts[:, None]
         )
-        labels = jnp.take(self.raw_labels, rows)
-        weights = jnp.where(
-            row_mask, jnp.take(self.raw_weights, rows), 0
-        )
-        offs = jnp.take(self.raw_offsets, rows)
+        labels = gathered["labels"]
+        weights = jnp.where(row_mask, gathered["weights"], 0)
+        offs = gathered["offsets"]
         if residuals is not None:
             offs = offs + jnp.take(residuals, rows)
         offs = jnp.where(row_mask, offs, 0)
@@ -256,8 +288,8 @@ class BlockPlan:
 
         if isinstance(self.raw, DenseFeatures):
             d = self.raw.x.shape[1]
-            xr = jnp.take(self.raw.x, rows, axis=0)  # [B, R, d]
-            if s <= DENSE_SUB_DIM_MAX and b * d * s <= ONE_HOT_ELEMENT_BUDGET:
+            xr = gathered["x"]  # [B, R, d]
+            if self.dense_slab:
                 # Feature->slot one-hot per entity:
                 # M[b, f, s] = proj[b,s] == f; -1 pads never match.
                 onehot = (
@@ -288,10 +320,7 @@ class BlockPlan:
             val = jnp.take(self.raw.values, rows, axis=0)
             val = jnp.where(row_mask[:, :, None], val, 0)
             k = idx.shape[-1]
-            if (
-                s <= DENSE_SUB_DIM_MAX
-                and b * r * k * s <= ONE_HOT_ELEMENT_BUDGET
-            ):
+            if self.dense_slab:
                 # Slot one-hot: idx[b,r,k] == proj[b,s]; the contraction
                 # densifies without any gather/scatter.
                 onehot = (

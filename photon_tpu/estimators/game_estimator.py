@@ -749,8 +749,6 @@ class GameEstimator:
             share_skeleton_packing,
             skeleton_random_effect_dataset,
         )
-        from photon_tpu.utils.compile_cache import aot_compile
-
         # Eligibility + skeleton construction OUTSIDE the "compile"
         # stage: a declined prediction must leave compile_seconds at
         # 0 (a truthy near-zero value would both fake an overlap
@@ -789,22 +787,7 @@ class GameEstimator:
             self.locked_coordinates, self.precision,
         )
         with PIPELINE_STATS.stage("compile"):
-            art = fused.aot_lower(coords)
-            return {
-                "key": key,
-                "statics": art["statics"],
-                "layout": fused.packed_layout(),
-                "mat": aot_compile(
-                    art["mat_traced"].lower(),
-                    ledger_key="fused_fit/materialize",
-                ),
-                "fit": aot_compile(
-                    art["fit_traced"].lower(),
-                    ledger_key="fused_fit/fit",
-                ),
-                "mat_text": str(art["mat_traced"].jaxpr),
-                "fit_text": str(art["fit_traced"].jaxpr),
-            }
+            return {"key": key, **fused.compile_programs(coords)}
 
     def _build_validation(
         self,

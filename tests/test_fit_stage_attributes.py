@@ -138,6 +138,28 @@ def test_the_counts_are_those_of_the_raw_ids_and_the_caps(heavy_tail):
                             "slab_rows", "rungs"}
 
 
+def test_home_and_the_indices_still_gathered_read_from_stage_records(
+        heavy_tail):
+    """As the benchmark would read them: ``sut.stage_records()``. Home is
+    the coordinate with the most slab slots; a CD iteration still gathers,
+    for every other one, its slab slots, the rows and its passive rows."""
+    from benchmark import sut
+
+    with jax.enable_x64(False):
+        heavy_tail["est"].fit(heavy_tail["game"])
+    record = [r for r in sut.stage_records() if r.name == "fit"][-1]
+    assert set(record.attrs) == {"coordinates", "home", "gather_indices"}
+    slots = {cid: sum(int(np.prod(b.row_ids.shape)) for b in ds.blocks)
+             for cid, ds in heavy_tail["datasets"].items() if cid in CAPS}
+    home = max(slots, key=slots.get)
+    assert record.attrs["home"] == home == "per-user"
+    (other,) = set(CAPS) - {home}
+    counts = np.bincount(heavy_tail["ids"][TAGS[other]])
+    passive = N - np.minimum(counts, CAPS[other]).sum()
+    assert record.attrs["gather_indices"] == slots[other] + N + passive
+    assert record.attrs["coordinates"][other]["slab_rows"] == slots[other]
+
+
 def test_the_rungs_hold_every_entity_under_a_row_cap_that_fits_it(heavy_tail):
     attrs = heavy_tail["fits"][-1].attrs["coordinates"]
     for cid, cap in CAPS.items():
