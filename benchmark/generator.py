@@ -3,15 +3,18 @@
 How many rows each entity has comes from the configuration alone: each
 random coordinate's ``rows_per_entity`` law, drawn from ``shape_seed``. So
 every seed plans the same bucket shapes and every run after a cell's first
-finds its programs in the compile cache. ``--seed`` draws which entity id
-owns which of those row sets (a relabelling of the users and of the
-movies). The features, true coefficients and labels come from the
-configuration's ``data_seed`` where it states one: every seed then gets
-the SAME per-entity problems under other names, and the solvers'
-data-dependent iteration counts, which moved ``train_rows_per_s`` by 3 %
-from seed to seed when the values came from ``--seed`` (PERF.md, PR 24),
-are the same in every run. A configuration whose ``data_seed`` is null
-draws the values from ``--seed``.
+finds its programs in the compile cache. The features, true coefficients,
+labels AND which entity id owns which row set come from the
+configuration's ``data_seed`` where it states one: the data set is then the
+configuration's, every ``--seed`` trains on the same arrays, and every run
+of a cell does the same work. Both halves were measured (PERF.md): values
+drawn from ``--seed`` moved ``train_rows_per_s`` by 3 % from seed to seed
+(the solvers' data-dependent iteration counts; PR 24), and a renaming of
+the entities by ``--seed``, which stood until PR 35, moved it by 4 % and
+one naming in five by a fifth (the fused fit sums the fixed effect's loss
+in one random coordinate's entity order, in float32, and its L-BFGS stops
+an iteration apart). A configuration whose ``data_seed`` is null draws
+values and names from ``--seed``.
 
 Marginals follow ``bench.py`` ``_synth_arrays``: standard-normal features
 with the last column the intercept, true coefficients N(0, scale^2),
@@ -98,7 +101,7 @@ def generate(config: dict, seed: int) -> GlmixData:
     data_seed = int(
         seed if config.get("data_seed") is None else config["data_seed"])
     rng = np.random.default_rng([data_seed, _DATA_STREAM])
-    names = np.random.default_rng([int(seed), _NAME_STREAM])
+    names = np.random.default_rng([data_seed, _NAME_STREAM])
     features, ids = {}, {}
     z = np.zeros(rows, np.float32)
     with concurrent.futures.ThreadPoolExecutor(_THREADS) as pool:

@@ -12,7 +12,7 @@ class Kind:
     def __init__(self, config: dict, traffic: dict, data, spans):
         self.config, self.traffic, self.data = config, traffic, data
         self.spans = spans
-        self.first = self.last = None
+        self.first = self.last = self.last_result = None
         self.plan_shapes: dict = {}
 
     def setup(self) -> None:
@@ -28,7 +28,7 @@ class Kind:
             result = sut.fit_blocking(self.est, self.dataset)
         if k == 0:
             self.first = result.model
-        self.last = result.model
+        self.last, self.last_result = result.model, result
 
     def end_to_end(self, units: int, window_s: float) -> dict:
         swept = (float(self.config["rows"])
@@ -36,7 +36,9 @@ class Kind:
         return {"train_rows_per_s": swept / window_s}
 
     def report(self) -> dict:
-        return {"plan_shapes": dict(self.plan_shapes)}
+        return {"plan_shapes": dict(self.plan_shapes),
+                "fixed_iterations": sut.fixed_effect_iterations(
+                    self.last_result)}
 
     def answer(self) -> dict:
         """The last fit of the window, and the first beside it: every fit
@@ -48,4 +50,5 @@ class Kind:
 
     def release(self) -> None:
         self.dataset = self.est = self.first = self.last = None
+        self.last_result = None
         gc.collect()

@@ -1,9 +1,22 @@
 """``BENCHMARK.json`` and the files it names, found by name.
 
 A cell, a configuration, a traffic mix, a traffic kind, a per-layer
-metric, a cell's limits, and a configuration's plain reference and data
-generator each sit in a file of their own, so a later PR adds a file and
-an entry and edits nothing here.
+metric, a cell's limits, and a configuration's plain reference, data
+generator and builder each sit in a file of their own, so a later PR adds
+files and entries and edits nothing here.
+
+Adding a configuration: (1) its file ``benchmark/configs/<name>.json``
+(sizes, ``tiny`` block, ``assumed``) and its entry under ``configs``;
+(2) a cell under ``workloads`` (traffic ``refit`` or ``retrain_job_uniform``,
+or a new ``traffic/<name>.json``, with ``kinds/<kind>.py`` if the kind is
+new), appended to the ``workloads`` of every metric of its kind (and a
+reader ``metrics/<name>.py`` for a metric of its own); (3) the cell's
+``limits/<cell>.json`` with the chip readings they were set between; and
+only where the plain ones do not do, named in the configuration's file:
+(4) ``reference``: ``references/<name>.py``, (5) ``generator``:
+``generators/<name>.py``, (6) ``builder``: ``builders/<name>.py``. The
+three directories may hold committed files; the tests write theirs as
+``mine.py``, a name no committed file takes.
 """
 
 from __future__ import annotations
@@ -57,8 +70,9 @@ class Manifest:
         raise KeyError(f"no configuration {name!r} in BENCHMARK.json")
 
     def _named_module(self, config_name: str, key: str):
-        """The module a configuration names under ``key`` (``reference``
-        or ``generator``): ``benchmark/<key>s/<name>.py``; without the key,
+        """The module a configuration names under ``key`` (``reference``,
+        ``generator`` or ``builder``): ``benchmark/<key>s/<name>.py``, and
+        ``FileNotFoundError`` for a name that is nowhere. Without the key,
         ``benchmark/<key>.py``, the one every configuration had so far.
         Either way a file of THIS checkout, loaded by its path."""
         name = self.config(config_name).get(key)
@@ -68,12 +82,33 @@ class Manifest:
 
     def reference(self, config_name: str):
         """The configuration's plain reference: ``fit(config, data)`` and
-        ``predict(config, data, tables)``."""
+        ``predict(config, data, tables)``, coordinate name -> host float32
+        table in the data's order (``sut.model_tables``). It imports
+        nothing of the program. ``run_cell`` calls it once the window has
+        closed, the peak has been read and the kind has released the
+        program's state, so a named reference may rely on: the generated
+        host arrays, the configuration, all ``cell["chips"]`` devices free
+        of the program's state (it may hold the data in blocks or over
+        every one of them), and float32 at ``highest`` whatever the
+        configuration's own precision. It may take its time; PERF.md says
+        where it takes longer than the window."""
         return self._named_module(config_name, "reference")
 
     def generator(self, config_name: str):
-        """The configuration's data generator: ``generate(config, seed)``."""
+        """The configuration's data generator: ``generate(config, seed)``
+        (host arrays: ``labels``, ``features``, ``ids``) and
+        ``rows_per_entity(config, coordinate)``, a function of the
+        configuration alone."""
         return self._named_module(config_name, "generator")
+
+    def builder(self, config_name: str):
+        """The module the configuration names under ``builder``, which may
+        give ``build_estimator(config, precision=None)`` and
+        ``build_dataset(data)`` (benchmark/sut.py says what each is), or
+        None: ``sut.py``'s plain pair builds it."""
+        if "builder" not in self.config(config_name):
+            return None
+        return self._named_module(config_name, "builder")
 
     def traffic_path(self, name: str) -> str:
         return os.path.join(self.bench_dir, "traffic", name + ".json")

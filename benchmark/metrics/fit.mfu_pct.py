@@ -6,4 +6,5 @@ peak. Host clock; the fits end in block_until_ready."""
 
 def read(ctx):
     flops = ctx.costs.fit_flops(ctx.config) * ctx.units
-    return 100.0 * flops / (ctx.window_s * ctx.peaks["flops_per_s"])
+    return 100.0 * flops / (
+        ctx.window_s * (ctx.chips * ctx.peaks["flops_per_s"]))

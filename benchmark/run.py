@@ -4,6 +4,14 @@
 Prints one JSON object as the last line of standard output. Fails with no
 result where JAX finds no accelerator the peaks table knows, fewer chips
 than the cell asks for, or no program to measure.
+
+The order of a run, which a configuration's own files may rely on: the
+generator makes host arrays from the seed; the builder the configuration
+names (or ``sut.py``'s plain pair) builds estimator and data set inside the
+kind's set-up and units; after the window the peak is read and the kind
+releases the program's state; only then ``reference.fit(config, data)``
+runs, with all of the cell's chips free, in float32 at ``highest``, and is
+not counted in ``setup_s`` (``window.check_s``).
 """
 
 from __future__ import annotations
@@ -29,9 +37,12 @@ class Reading:
     """What a per-layer metric's reader may look at: the run's own fields
     (config, traffic, cell, units, window_s, window_start, spans, trace,
     compile_events, peaks, costs, xplane) and whatever the traffic kind's
-    ``report()`` names, with the three reductions several readers share."""
+    ``report()`` names, with the three reductions several readers share.
+    ``chips`` is the cell's: a share of the whole fit divides by ``chips``
+    x ``peaks``, one device's trace by one chip's."""
 
-    jobs = plan_shapes = None
+    jobs = plan_shapes = fixed_iterations = None
+    chips = 1
 
     def __init__(self, **kw):
         self.__dict__.update(kw)
@@ -99,12 +110,21 @@ def run_cell(man, cell: dict, seed: int, seconds: float, trace: bool,
              device: dict, process_start: float | None = None) -> dict:
     """Everything of a run after the look for a chip; returns the result
     object. ``device`` is what ``look_for_chip`` returned."""
+    from benchmark import sut
+
+    process_start = (time.perf_counter() if process_start is None
+                     else process_start)
+    with sut.using_builder(man.builder(cell["config"])):
+        return _run_cell(man, cell, seed, seconds, trace, device,
+                         process_start)
+
+
+def _run_cell(man, cell: dict, seed: int, seconds: float, trace: bool,
+              device: dict, process_start: float) -> dict:
     import jax
 
     from benchmark import check, costs, sut, windows, xplane
 
-    process_start = (time.perf_counter() if process_start is None
-                     else process_start)
     config = man.config(cell["config"])
     traffic = man.traffic(cell["traffic"])
     limits = man.limits(cell["name"])
@@ -190,7 +210,8 @@ def run_cell(man, cell: dict, seed: int, seconds: float, trace: bool,
         device_out["window_s"] = reduced.window_s
         ctx = Reading(
             **report,
-            config=config, traffic=traffic, cell=cell, units=units,
+            config=config, traffic=traffic, cell=cell,
+            chips=int(cell["chips"]), units=units,
             window_s=window_s, spans=spans, trace=reduced,
             compile_events=(
                 counters_after["hits"] + counters_after["misses"]

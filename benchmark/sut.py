@@ -1,13 +1,46 @@
-"""The system under test: the one module that imports ``photon_tpu``.
+"""The system under test: with the files under ``benchmark/builders/``,
+the one place that imports ``photon_tpu``.
 
 Everything the harness asks of the program goes through here: the
 estimator a configuration file describes, the data set, one blocking fit,
 save and load of a model, and the program's own counters.
+
+``build_estimator`` and ``build_dataset`` state what the four first cells
+need: L2, dense features, one optimizer, no weights, offsets or validation
+data. A configuration that needs more names a builder (key ``builder``:
+``benchmark/builders/<name>.py``, found by ``Manifest.builder``), which may
+give ``build_estimator(config, precision=None)``, ``build_dataset(data)``
+or both; what it does not give, and everything of a configuration without
+the key, comes from the plain pair here (``plain_estimator``,
+``plain_dataset``, which a builder may call). A builder builds a
+``GameEstimator`` and a ``GameDataset`` for the program's normal entry
+points (``prepare``, ``fit``), which the traffic kinds drive: it is no
+place for a fit loop of its own. The kinds and the tests call
+``sut.build_estimator`` / ``sut.build_dataset`` whatever the configuration
+names; ``run_cell`` says which builder is in use (``using_builder``).
 """
 
 from __future__ import annotations
 
+import contextlib
+
 import numpy as np
+
+_builder = None  # the module the running cell's configuration names
+
+
+@contextlib.contextmanager
+def using_builder(module):
+    """Inside: ``build_estimator`` / ``build_dataset`` are ``module``'s
+    (``Manifest.builder``) where it gives them; None, and outside, the
+    plain pair's. On the way out the builder that was in use before is in
+    use again, so one use may stand inside another."""
+    global _builder
+    before, _builder = _builder, module
+    try:
+        yield
+    finally:
+        _builder = before
 
 
 def program_missing() -> str | None:
@@ -38,8 +71,21 @@ def configure(config: dict) -> str:
 
 
 def build_estimator(config: dict, precision: str | None = None):
-    """The GameEstimator ``config`` states. ``precision`` overrides the
-    configuration's only for the low-precision control of the check."""
+    """The GameEstimator ``config`` states, by the builder in use where it
+    gives one. ``precision`` overrides the configuration's only for the
+    low-precision control of the check."""
+    build = getattr(_builder, "build_estimator", None) or plain_estimator
+    return build(config, precision=precision)
+
+
+def build_dataset(data):
+    """Host arrays -> GameDataset, by the builder in use where it gives
+    one."""
+    return (getattr(_builder, "build_dataset", None) or plain_dataset)(data)
+
+
+def plain_estimator(config: dict, precision: str | None = None):
+    """L2 on every coordinate, each solver route's default optimizer."""
     from photon_tpu import optim
     from photon_tpu.algorithm.problems import GLMOptimizationConfiguration
     from photon_tpu.data.random_effect import RandomEffectDataConfiguration
@@ -82,8 +128,8 @@ def build_estimator(config: dict, precision: str | None = None):
     )
 
 
-def build_dataset(data):
-    """Host arrays -> GameDataset, raw shards resident on the device."""
+def plain_dataset(data):
+    """Dense shards and id tags, raw shards resident on the device."""
     import jax
 
     from photon_tpu.data.dataset import DenseFeatures
@@ -115,6 +161,18 @@ def fit_blocking(est, dataset):
     result = est.fit(dataset)[0]
     jax.block_until_ready(list(coefficient_arrays(result.model).values()))
     return result
+
+
+def fixed_effect_iterations(result):
+    """Optimizer iterations a fit spent on its fixed effects, summed over
+    its coordinate-descent iterations, from the diagnostics the program
+    hands back with the fit (one small pull from the device); None where
+    it hands back none. A random effect's diagnostics carry no such
+    count."""
+    history = getattr(getattr(result, "descent", None), "history", ())
+    found = [record.diagnostics.iterations for record in history
+             if getattr(record.diagnostics, "iterations", None) is not None]
+    return int(sum(found)) if found else None
 
 
 def model_tables(model, config: dict) -> dict:

@@ -266,17 +266,61 @@ MANIFEST_CHECKS = [
 ]
 
 
+# The keys by which a configuration's file names a file of its own:
+# benchmark/<key>s/<name>.py.
+NAMED_KEYS = ("reference", "generator", "builder")
+
+
 def check_a_configuration_has_a_reference_and_a_generator(man, config_name):
+    """And, where it names one, a builder that gives something."""
     reference = man.reference(config_name)
     assert callable(reference.fit) and callable(reference.predict)
-    assert callable(man.generator(config_name).generate)
+    generator = man.generator(config_name)
+    assert callable(generator.generate)
+    assert callable(generator.rows_per_entity)
+    builder = man.builder(config_name)
+    assert (builder is None) == ("builder" not in man.config(config_name))
+    if builder is not None:
+        gives = [getattr(builder, name, None)
+                 for name in ("build_estimator", "build_dataset")]
+        assert any(gives) and all(g is None or callable(g) for g in gives)
+
+
+# The metrics PR 32 made for a configuration whose reservoir caps bind.
+CAP_METRICS = ("plan.passive_row_share", "plan.solver_shapes",
+               "solve.xla_newton_slab_share")
+
+
+def a_cap_binds(man, config_name: str) -> bool:
+    """Some random coordinate has an entity with more rows than its
+    ``active_data_upper_bound``: by the generator's law, no data made."""
+    config = man.config(config_name)
+    rows_per_entity = man.generator(config_name).rows_per_entity
+    return any(
+        c["active_data_upper_bound"] is not None
+        and rows_per_entity(config, c).max() > c["active_data_upper_bound"]
+        for c in config["coordinates"] if c["kind"] == "random")
+
+
+def check_the_cap_metrics_list_cells_whose_cap_binds(man, name):
+    (metric,) = [m for m in man.doc["per_layer"] if m["name"] == name]
+    assert "heavytail.refit" in metric["workloads"]
+    for cell in metric["workloads"]:
+        assert a_cap_binds(man, man.cell(cell)["config"]), (name, cell)
+    assert metric["moves"] == "train_rows_per_s"
+    assert metric["source"] == "program_counter"
+    assert metric["better"] == "lower"
 
 
 # ---- a cell's rehearsal on the CPU, on a tiny copy
 
 def rehearse(man, cell: str, trace, seed: int, seconds: float = 0.5) -> dict:
-    return run.run_cell(man, man.cell(cell), seed=seed, seconds=seconds,
-                        trace=bool(trace), device=dict(FAKE_DEVICE))
+    """The device handed over has the chips the cell asks for (the
+    suite's CPU has 8 host devices; tests/conftest.py)."""
+    entry = man.cell(cell)
+    return run.run_cell(man, entry, seed=seed, seconds=seconds,
+                        trace=bool(trace),
+                        device=dict(FAKE_DEVICE, count=entry["chips"]))
 
 
 def check_rehearsal_of_a_cell(man, cell, trace, out):

@@ -1,7 +1,10 @@
 """A later ``model_config`` PR's GLMix arrives as files and entries: a
 configuration with coordinates of other names, power-law rows per entity
 and a cap that binds, a refit cell and a retrain cell on it and each
-cell's limits, laid over a copy of the benchmark with no edit to any file
+cell's limits; and a second configuration that needs more of the
+yardstick, with a reference, a generator and a builder of its own, each
+named in its file, and a cell that asks for four chips: all laid over a
+copy of the benchmark with no edit to any file
 that was there. The grown manifest then passes every check the suite
 makes of the repository's (conftest.py holds them as plain functions):
 the manifest's, each configuration's, each cell's limits and each cell's
@@ -18,12 +21,16 @@ import shutil
 
 import pytest
 
+from benchmark import costs, run
 from benchmark.manifest import Manifest
 
 from conftest import (
     CAME_WITH,
+    CAP_METRICS,
+    FAKE_DEVICE,
     LIMITS_CHECKS,
     MANIFEST_CHECKS,
+    NAMED_KEYS,
     REPO_ROOT,
     cell_kind,
     cell_names,
@@ -32,6 +39,7 @@ from conftest import (
     check_a_traced_rehearsal_prints_every_metric_that_lists_the_cell,
     check_every_cell_is_listed_by_every_metric_of_its_kind,
     check_rehearsal_of_a_cell,
+    check_the_cap_metrics_list_cells_whose_cap_binds,
     copy_benchmark,
     rehearse,
     tiny_copy,
@@ -126,8 +134,9 @@ def test_adding_a_configuration_needs_files_and_entries_only(
     full, before, tiny = grown_root
     added = set(_files(full)) - set(before)
     assert added == {
-        "benchmark/configs/glmix_fixture_powerlaw.json"} | {
-        f"benchmark/limits/{cell}.json" for cell in ADDED_CELLS}
+        f"benchmark/configs/{name}.json" for name in ADDED_CONFIGS} | {
+        f"benchmark/limits/{cell}.json" for cell in ADDED_CELLS} | {
+        f"benchmark/{key}s/fixture_named.py" for key in NAMED_KEYS}
     for name in before:
         if name != "BENCHMARK.json":
             assert filecmp.cmp(os.path.join(REPO_ROOT, name),
@@ -205,6 +214,90 @@ def test_a_traced_rehearsal_prints_every_metric_that_lists_each_grown_cell(
         grown_root, traced, cell):
     check_a_traced_rehearsal_prints_every_metric_that_lists_the_cell(
         Manifest(grown_root.tiny), cell, traced(cell))
+
+
+NAMED_CONFIG, NAMED_CELL = "glmix_fixture_named", "named.refit4"
+
+
+def test_the_named_files_are_the_ones_a_run_of_the_four_chip_cell_uses(
+        grown_root, traced):
+    """Each of the fixture's three named files leaves a mark when it is
+    called; the cells of the other configurations leave none."""
+    man = Manifest(grown_root.tiny)
+    config = man.config(NAMED_CONFIG)
+    assert man.cell(NAMED_CELL)["chips"] == 4 and config["mesh"] == "off"
+    for key in NAMED_KEYS:
+        assert config[key] == "fixture_named"
+        assert getattr(man, key)(NAMED_CONFIG).__file__ == os.path.join(
+            grown_root.tiny, "benchmark", key + "s", "fixture_named.py")
+    out = traced(NAMED_CELL)
+    assert out["correct"] is True, out["compared"]
+    assert out["device"]["count"] == 4
+    seed = out["window"]["seed"]
+    assert (NAMED_CONFIG, seed) in man.generator(NAMED_CONFIG).CALLS
+    assert NAMED_CONFIG in man.reference(NAMED_CONFIG).CALLS
+    built = man.builder(NAMED_CONFIG).CALLS
+    assert ("estimator", NAMED_CONFIG) in built
+    assert ("dataset", config["rows"]) in built
+    traced("powerlaw.refit")
+    assert {name for name, _ in man.generator(NAMED_CONFIG).CALLS} == {
+        NAMED_CONFIG}
+    assert set(man.reference(NAMED_CONFIG).CALLS) == {NAMED_CONFIG}
+    assert {what for kind, what in built if kind == "estimator"} == {
+        NAMED_CONFIG}
+
+
+@pytest.mark.parametrize("name, count, peak", [
+    ("fit.mfu_pct", costs.fit_flops, "flops_per_s"),
+    ("fit.hbm_share_pct", costs.fit_hbm_bytes, "hbm_bytes_per_s"),
+])
+def test_a_whole_fit_share_divides_by_the_cells_chips(
+        grown_root, traced, name, count, peak):
+    """At one chip the float every accepted cell read before ``chips``
+    was there; at four, for the same window, a quarter of it; and the
+    four-chip cell's line carries the quarter."""
+    man = Manifest(grown_root.tiny)
+    out = traced(NAMED_CELL)
+    config = man.config(NAMED_CONFIG)
+    peaks = costs.chip_peaks(FAKE_DEVICE["kind"])
+    window = dict(config=config, units=out["attempted"], costs=costs,
+                  window_s=out["window"]["window_s"], peaks=peaks)
+    read = man.metric_reader(name)
+    one = read(run.Reading(**window, chips=1))
+    assert one == read(run.Reading(**window))
+    assert one == 100.0 * count(config) * out["attempted"] / (
+        out["window"]["window_s"] * peaks[peak])
+    four = read(run.Reading(**window, chips=4))
+    assert four == one / 4.0
+    assert out["metrics"][name]["value"] == four
+    # The one-chip fixture cell on the same configuration's sizes reads
+    # against one chip's peak.
+    other = traced("powerlaw.refit")
+    assert other["metrics"][name]["value"] == (
+        100.0 * count(man.config("glmix_fixture_powerlaw"))
+        * other["attempted"]
+        / (other["window"]["window_s"] * peaks[peak]))
+
+
+@pytest.mark.parametrize("name", CAP_METRICS)
+def test_the_cap_metrics_may_list_a_second_cell_whose_cap_binds(
+        grown_root, tmp_path, name):
+    man = Manifest(grown_root.full)
+    (metric,) = [m for m in man.doc["per_layer"] if m["name"] == name]
+    assert metric["workloads"] == ["heavytail.refit", NAMED_CELL]
+    check_the_cap_metrics_list_cells_whose_cap_binds(man, name)
+
+    # A cell in which no cap binds is not theirs to list.
+    def relist(doc):
+        for m in doc["per_layer"]:
+            if m["name"] == name:
+                m["workloads"].append("linear.refit")
+
+    full = str(tmp_path / "full")
+    _grow(full, relist)
+    with pytest.raises(AssertionError, match="linear.refit"):
+        check_the_cap_metrics_list_cells_whose_cap_binds(
+            Manifest(full), name)
 
 
 def test_the_fixture_brings_a_cell_of_each_kind_that_has_metrics(grown_root):

@@ -44,8 +44,12 @@ def test_the_control_is_not_correct_and_the_program_is(
                       reference.predict), limits)
     assert not ok, compared
 
-    est = sut.build_estimator(config)
-    fitted = sut.fit_blocking(est, sut.build_dataset(data))
+    # Built as run_cell builds it: by the configuration's builder where it
+    # names one.
+    with sut.using_builder(man.builder(config_name)):
+        est = sut.build_estimator(config)
+        dataset = sut.build_dataset(data)
+    fitted = sut.fit_blocking(est, dataset)
     ok, compared = check.verdict(
         check.compare(config, data,
                       {"tables": sut.model_tables(fitted.model, config)},
