@@ -123,6 +123,18 @@ class FixedEffectCoordinate:
             s = s[: self.logical_rows]
         return s
 
+    def programs_per_update(self) -> dict:
+        """The programs an update dispatches (``fit`` stage, unfused
+        loop): the solve; the matvec and, over padded rows, the cut back
+        to the canonical ones; with residuals their sum into the offsets
+        and, over padded rows, their padding. JAX's one-primitive helpers
+        (a cast of a scalar operand, a zeros vector, a slice's index) are
+        not counted."""
+        padded = (
+            self.logical_rows is not None
+            and self.batch.num_samples != self.logical_rows)
+        return {"train": 1, "score": 1 + padded, "residuals": 1 + padded}
+
 
 @dataclasses.dataclass(frozen=True)
 class ModelCoordinate:
@@ -142,3 +154,6 @@ class ModelCoordinate:
 
     def score(self, model=None) -> Array:
         return self.inner.score(self.model if model is None else model)
+
+    def programs_per_update(self) -> dict:
+        return self.inner.programs_per_update()

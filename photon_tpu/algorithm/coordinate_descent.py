@@ -115,6 +115,53 @@ def _serialize_on_cpu_mesh(x) -> None:
         jax.block_until_ready(x)
 
 
+def _programs_per_update(coord) -> dict:
+    """``train`` / ``score`` / ``residuals``: the programs one update of
+    ``coord`` dispatches to train, to score, and on top of those to take
+    residuals in, as the coordinate itself says
+    (``programs_per_update``); one each for a coordinate that says
+    nothing."""
+    said = getattr(coord, "programs_per_update", None)
+    return said() if said is not None else {
+        "train": 1, "score": 1, "residuals": 0}
+
+
+def fit_stage_attrs(coordinates: dict[str, Coordinate]) -> dict:
+    """What the unfused loop's ``fit`` stage carries beside ``programs``:
+    ``coordinates`` (per random-effect coordinate what
+    ``fit_stage_coordinate`` gives: the fused fit's ``fit`` stage carries
+    the same), ``devices`` (how many devices hold a leaf of the prepared
+    data sets) and ``placed_bytes`` (one entry a device, in the order of
+    the devices' ids: the bytes of those leaves it holds, from shapes and
+    shardings). Host integers and strings; nothing is read from a device.
+    ``GameEstimator`` makes it once per prepared data set."""
+    from photon_tpu.algorithm.random_effect import (
+        RandomEffectCoordinate,
+        fit_stage_coordinate,
+    )
+    from photon_tpu.parallel.mesh import placed_bytes
+
+    per_coord, leaves = {}, []
+    for cid, coord in coordinates.items():
+        inner = getattr(coord, "inner", coord)
+        if isinstance(inner, RandomEffectCoordinate):
+            ds = inner.dataset
+            per_coord[cid] = fit_stage_coordinate(
+                inner, ds.device_blocks(), precision=inner.precision)
+            leaves.append(ds.device_leaves())
+        else:
+            leaves.append(getattr(inner, "batch", None))
+    devices = sorted(
+        {d for leaf in jax.tree.leaves(leaves) if isinstance(leaf, jax.Array)
+         for d in leaf.sharding.device_set},
+        key=lambda d: d.id)
+    return {
+        "coordinates": per_coord,
+        "devices": len(devices),
+        "placed_bytes": placed_bytes(leaves, devices),
+    }
+
+
 @dataclasses.dataclass(frozen=True)
 class ValidationContext:
     """Validation data + per-coordinate scorers.
@@ -237,8 +284,18 @@ class CoordinateDescent:
         start_iteration: int = 0,
         on_iteration=None,
         initial_best=None,
+        fit_attrs: dict | None = None,
     ) -> CoordinateDescentResult:
         """Train all coordinates by block coordinate descent.
+
+        The whole descent is one always-recorded ``fit`` stage, from
+        entry to the return of the last dispatch (no sync), as the fused
+        fit's is. Its attributes are ``fit_attrs`` (``fit_stage_attrs`` of
+        these coordinates, which ``GameEstimator`` hands over ready-made;
+        made here when left out) and ``programs``: the programs this
+        descent dispatched, the loop's own vector programs and what each
+        coordinate says an update of it dispatches
+        (``programs_per_update``).
 
         Mirrors CoordinateDescent.descend/descendWithValidation: coordinate k
         trains against offsets + (sum of all other coordinates' scores); its
@@ -258,6 +315,21 @@ class CoordinateDescent:
         best-so-far (None until a full model has been evaluated) — the
         training checkpointer's hook.
         """
+        from photon_tpu import obs
+
+        with obs.stage("fit") as fit_stage:
+            result, programs = self._descend(
+                coordinates, initial_models, validation, seed,
+                start_iteration, on_iteration, initial_best)
+            if fit_attrs is None:
+                fit_attrs = fit_stage_attrs(coordinates)
+            fit_stage.attrs = dict(fit_attrs, programs=programs)
+        return result
+
+    def _descend(
+        self, coordinates, initial_models, validation, seed,
+        start_iteration, on_iteration, initial_best,
+    ) -> tuple[CoordinateDescentResult, int]:
         if not 0 <= start_iteration <= self.num_iterations:
             raise ValueError(
                 f"start_iteration {start_iteration} outside "
@@ -276,6 +348,14 @@ class CoordinateDescent:
         models: dict[str, Any] = {}
         scores: dict[str, Array] = {}
         total: Array | None = None
+        # Programs dispatched, for the ``fit`` stage: host integers, what
+        # each coordinate says of itself once a descent and the loop's own
+        # three vector programs where they run.
+        per_update = {
+            cid: _programs_per_update(coordinates[cid])
+            for cid in self.update_sequence
+        }
+        programs = 0
 
         def add(total_, s):
             return s if total_ is None else total_ + s
@@ -288,6 +368,7 @@ class CoordinateDescent:
                 s = coordinates[cid].score(models[cid])
                 _serialize_on_cpu_mesh(s)
                 scores[cid] = s
+                programs += per_update[cid]["score"] + (total is not None)
                 total = add(total, s)
 
         history: list[CoordinateUpdateRecord] = []
@@ -314,10 +395,14 @@ class CoordinateDescent:
                 # per-update syncs are exactly what this loop avoids).
                 with obs.span(f"coord:{cid}", attrs={"iteration": it}):
                     residuals = None
+                    counts = per_update[cid]
+                    programs += counts["train"] + counts["score"]
                     if total is not None:
                         residuals = total
+                        programs += counts["residuals"]
                         if cid in scores:
                             residuals = residuals - scores[cid]
+                            programs += 1
                     model, diag = coord.train(
                         residuals=residuals,
                         initial_model=models.get(cid),
@@ -330,6 +415,10 @@ class CoordinateDescent:
                     # rollback keeps the previous iterate for this
                     # coordinate; total/scores stay untouched, so every
                     # later update trains against the last good state.
+                    if self.non_finite_guard:
+                        # The guard's reduce of the scores and of each
+                        # weight array.
+                        programs += 1 + len(_model_weight_arrays(model))
                     if self.non_finite_guard and not _update_is_finite(
                         model, new_scores
                     ):
@@ -352,7 +441,9 @@ class CoordinateDescent:
                         total = new_scores
                     elif cid in scores:
                         total = _sub_add(total, scores[cid], new_scores)
+                        programs += 1
                     else:
+                        programs += 1
                         total = total + new_scores  # photon: ignore[use-after-donate] -- line 354 re-binds `total` to the donating call's result in the same statement, so this branch (a later coordinate's first appearance) reads the NEW buffer; the carry-aliased case routes through the plain twin via _sub_add's identity guard
                 if rolled_back:
                     logger.warning(
@@ -462,4 +553,4 @@ class CoordinateDescent:
             best_model=best_model,
             best_evaluation=best_eval,
             history=tuple(history),
-        )
+        ), programs

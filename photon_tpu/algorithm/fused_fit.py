@@ -60,6 +60,8 @@ from photon_tpu.algorithm.random_effect import (
     RandomEffectCoordinate,
     RandomEffectTrainingStats,
     _solve_block,
+    fit_stage_coordinate,
+    solver_statics as _re_statics,
 )
 from photon_tpu.models.game import (
     FixedEffectModel,
@@ -324,32 +326,6 @@ def fuse_ineligibility_reasons(
 def fuse_eligible(coords: dict[str, object]) -> bool:
     """True when every coordinate can ride the single-program fit."""
     return not fuse_ineligibility_reasons(coords)
-
-
-def _re_statics(coord: RandomEffectCoordinate) -> dict:
-    """Static solver routing for one RE coordinate (mirrors
-    RandomEffectCoordinate._dispatch_block's well-posedness analysis)."""
-    from photon_tpu.types import TaskType
-
-    cfg = coord.config
-    well_posed = (
-        cfg.l1_weight == 0.0
-        and cfg.l2_weight > 0.0
-        and cfg.optimizer.box_constraints is None
-        and (coord.prior is None or cfg.incremental_weight > 0.0)
-    )
-    direct = well_posed and coord.task == TaskType.LINEAR_REGRESSION
-    newton = well_posed and coord.task in (
-        TaskType.LOGISTIC_REGRESSION, TaskType.POISSON_REGRESSION
-    )
-    return dict(
-        task=coord.task,
-        opt_config=cfg.optimizer,
-        use_owlqn=cfg.l1_weight != 0.0,
-        variance_computation=cfg.variance_computation,
-        direct=direct,
-        newton=newton,
-    )
 
 
 def fused_static_key(coords: dict, seq: list[str], num_iterations: int,
@@ -1887,34 +1863,21 @@ class FusedFit:
 
     def _fit_attrs(self, coords, ebs_all) -> dict:
         """The ``fit`` stage's attributes: per random-effect coordinate
-        what the planner counted (``RandomEffectDataset.plan_counts``:
-        ``active_rows``, ``passive_rows``, ``capped_entities``), its
-        ``slab_rows`` and its ``rungs`` as ``[entities, row cap, route]``
-        with the ``solve.<route>`` scope ``_solve_block`` gives that slab.
+        what ``fit_stage_coordinate`` gives (the unfused loop's ``fit``
+        stage carries the same), then ``home`` and ``gather_indices``.
         Host ints and strings from shapes alone, made on the first fit of
         this prepared data set and handed to every later one: a warm fit
         pays one attribute read."""
         attrs = self._fit_attrs_cache
         if attrs is None:
-            from photon_tpu.algorithm.random_effect import solve_route
-
             per_coord, rows = {}, {}
             for cid in self.seq:
                 if self.kinds[cid] != "random":
                     continue
                 inner = getattr(coords[cid], "inner", coords[cid])
                 rows[cid] = inner.dataset.num_rows
-                statics = _re_statics(inner)
-                rungs = [
-                    [int(eb.x_values.shape[0]), int(eb.x_values.shape[1]),
-                     solve_route(statics, eb, precision=self.precision)]
-                    for eb in ebs_all[cid]["ebs"]
-                ]
-                per_coord[cid] = dict(
-                    inner.dataset.plan_counts or {},
-                    slab_rows=sum(b * r for b, r, _ in rungs),
-                    rungs=rungs,
-                )
+                per_coord[cid] = fit_stage_coordinate(
+                    inner, ebs_all[cid]["ebs"], precision=self.precision)
             # Indices a CD iteration still gathers element by element:
             # every coordinate but home reads its residuals through
             # row_ids (slab slots), its scores through the score map

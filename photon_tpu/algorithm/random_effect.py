@@ -1641,6 +1641,73 @@ class RandomEffectCoordinate:
         """Model contribution per canonical row (active + passive)."""
         return model.score_dataset(self.dataset)
 
+    def programs_per_update(self) -> dict:
+        """The programs an update dispatches (``fit`` stage, unfused
+        loop): one solve a bucket; the scorer's
+        (``models.game.score_programs``). Residuals enter inside the
+        solves; the zeros tables the solves fill are JAX's one-primitive
+        helpers and not counted."""
+        from photon_tpu.models.game import score_programs
+
+        return {
+            "train": len(self.dataset.device_blocks()),
+            "score": score_programs(self.dataset),
+            "residuals": 0,
+        }
+
+
+def solver_statics(coord: RandomEffectCoordinate) -> dict:
+    """Static solver routing for one RE coordinate (mirrors
+    RandomEffectCoordinate._dispatch_block's well-posedness analysis)."""
+    cfg = coord.config
+    well_posed = (
+        cfg.l1_weight == 0.0
+        and cfg.l2_weight > 0.0
+        and cfg.optimizer.box_constraints is None
+        and (coord.prior is None or cfg.incremental_weight > 0.0)
+    )
+    direct = well_posed and coord.task == TaskType.LINEAR_REGRESSION
+    newton = well_posed and coord.task in (
+        TaskType.LOGISTIC_REGRESSION, TaskType.POISSON_REGRESSION
+    )
+    return dict(
+        task=coord.task,
+        opt_config=cfg.optimizer,
+        use_owlqn=cfg.l1_weight != 0.0,
+        variance_computation=cfg.variance_computation,
+        direct=direct,
+        newton=newton,
+    )
+
+
+def fit_stage_coordinate(
+    coord: RandomEffectCoordinate, slabs, *, precision: str = "float32"
+) -> dict:
+    """One random-effect coordinate's entry of a ``fit`` stage's
+    ``coordinates`` attribute, the same for the fused fit and for the
+    unfused loop: what the planner counted
+    (``RandomEffectDataset.plan_counts``: ``active_rows``,
+    ``passive_rows``, ``capped_entities``), its ``slab_rows`` and its
+    ``rungs`` as ``[entities, row cap, route]`` with the ``solve.<route>``
+    scope ``_solve_block`` gives that slab. ``slabs``: the buckets as the
+    fit solves them, ``EntityBlocks`` or, for a bucket left lazy, its
+    ``BlockPlan``; only shapes, dtypes and placements are read. Host ints
+    and strings: a caller makes it once per prepared data set."""
+    statics = solver_statics(coord)
+    rungs = []
+    for slab in slabs:
+        spmd = placement.spans_devices(slab)
+        if isinstance(slab, BlockPlan):
+            slab = jax.eval_shape(lambda b: b.materialize(None), slab)
+        rungs.append([
+            int(slab.x_values.shape[0]), int(slab.x_values.shape[1]),
+            solve_route(statics, slab, precision=precision, spmd=spmd)])
+    return dict(
+        coord.dataset.plan_counts or {},
+        slab_rows=sum(b * r for b, r, _ in rungs),
+        rungs=rungs,
+    )
+
 
 def solve_route(
     statics: dict, slab, *, precision: str = "float32", spmd: bool = False
@@ -1649,7 +1716,7 @@ def solve_route(
     materialized slab is ``slab`` (an ``EntityBlocks``; only shapes and
     dtypes are read, never a value): the same tests in the same order,
     kept beside it so that a stage attribute can name the route without
-    a frame on the traced path. ``statics``: ``fused_fit._re_statics``."""
+    a frame on the traced path. ``statics``: ``solver_statics``."""
     if statics["direct"]:
         return "direct"
     if statics["newton"]:

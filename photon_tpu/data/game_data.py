@@ -57,7 +57,7 @@ class IdTag:
         return np.asarray(self.codes)
 
     @staticmethod
-    def from_raw(raw_ids) -> "IdTag":
+    def from_raw(raw_ids, *, place: bool = True) -> "IdTag":
         # Entity keys are normalized to str at ingest: the Avro model format
         # stores modelId as a string (BayesianLinearModelAvro), so keeping
         # numeric keys here would make every vocab lookup after a model
@@ -74,7 +74,7 @@ class IdTag:
                 "id tag keys collide after str normalization"
             )
         return IdTag(
-            codes=jnp.asarray(codes),
+            codes=jnp.asarray(codes) if place else codes,
             vocab={k: i for i, k in enumerate(keys)},
             inverse=keys,
             codes_np=codes,
@@ -206,6 +206,32 @@ class GameDataset:
         t = self.id_tags[tag]
         return t.codes, t.num_groups
 
+    @property
+    def on_host(self) -> bool:
+        """Whether the columns and raw shards are still host arrays
+        (``make_host_game_dataset``): an estimator's ``prepare`` places
+        them."""
+        return isinstance(self.labels, np.ndarray)
+
+    def on_device(self) -> "GameDataset":
+        """This table with its columns, raw shards and id codes on the
+        default device: itself unless it was left on the host."""
+        if not self.on_host:
+            return self
+        placed = jax.device_put
+        return dataclasses.replace(
+            self,
+            labels=placed(self.labels),
+            offsets=placed(self.offsets),
+            weights=placed(self.weights),
+            feature_shards={
+                k: jax.tree.map(placed, f)
+                for k, f in self.feature_shards.items()},
+            id_tags={
+                k: dataclasses.replace(t, codes=placed(t.codes))
+                for k, t in self.id_tags.items()},
+        )
+
 
 def make_game_dataset(
     labels,
@@ -221,18 +247,47 @@ def make_game_dataset(
     An always-recorded stage, ``dataset`` (the dtype conversions, the id
     vocabularies, and inside it ``raw_transfer``, the enqueue of the one
     ``device_put``; the copy itself is asynchronous and outlasts it)."""
+    return _dataset_stage(
+        labels, feature_shards, offsets, weights, id_tags, uids, dtype,
+        place=True)
+
+
+def make_host_game_dataset(
+    labels,
+    feature_shards: dict[str, Features],
+    *,
+    offsets=None,
+    weights=None,
+    id_tags: dict[str, np.ndarray] | None = None,
+    uids=None,
+    dtype=jnp.float32,
+) -> GameDataset:
+    """``make_game_dataset`` with every column, raw shard and id code LEFT
+    ON THE HOST (host shards only): for a table that no single device
+    should hold. ``GameEstimator.prepare`` places each leaf from the host
+    where its mesh's partition rules put it (parallel/mesh.py: a quarter
+    of a fixed effect's rows a device, a random effect's raw shard on
+    every device), so no device ever holds a whole copy; without a mesh
+    it places the table on the default device (``on_device``). The same
+    ``dataset`` stage, with no ``raw_transfer`` inside it."""
+    return _dataset_stage(
+        labels, feature_shards, offsets, weights, id_tags, uids, dtype,
+        place=False)
+
+
+def _dataset_stage(*args, place: bool) -> GameDataset:
     from photon_tpu import obs
 
     with obs.stage("dataset") as stage:
-        data = _make_game_dataset(
-            labels, feature_shards, offsets, weights, id_tags, uids, dtype)
+        data = _make_game_dataset(*args, place=place)
         stage.attrs = dict(
             id_grouping={k: t.grouping for k, t in data.id_tags.items()})
         return data
 
 
 def _make_game_dataset(
-    labels, feature_shards, offsets, weights, id_tags, uids, dtype
+    labels, feature_shards, offsets, weights, id_tags, uids, dtype,
+    place: bool = True,
 ) -> GameDataset:
     np_dtype = np.dtype(dtype)
     labels_np = np.asarray(labels, dtype=np_dtype)
@@ -287,12 +342,20 @@ def _make_game_dataset(
             val = np.asarray(feats.values, dtype=np_dtype)
             host[("shard", name)] = (idx, val, feats.d)
             specs[name] = ("sparse", stage_arr(idx), stage_arr(val), feats.d)
+        elif not place:
+            raise TypeError(
+                f"feature shard {name!r}: a data set left on the host "
+                "takes host Dense or Sparse shards, got "
+                f"{type(feats).__name__}")
         shards[name] = feats
     i_lab = stage_arr(labels_np)
     i_off = stage_arr(offsets_np)
     i_wt = stage_arr(weights_np)
-    with PIPELINE_STATS.stage("raw_transfer"):
-        devs = jax.device_put(staged)
+    if place:
+        with PIPELINE_STATS.stage("raw_transfer"):
+            devs = jax.device_put(staged)
+    else:
+        devs = staged
     for name, spec in specs.items():
         if spec[0] == "dense":
             shards[name] = DenseFeatures(devs[spec[1]])
@@ -305,7 +368,8 @@ def _make_game_dataset(
         offsets=devs[i_off],
         weights=devs[i_wt],
         feature_shards=shards,
-        id_tags={k: IdTag.from_raw(v) for k, v in (id_tags or {}).items()},
+        id_tags={k: IdTag.from_raw(v, place=place)
+                 for k, v in (id_tags or {}).items()},
         uids=None if uids is None else np.asarray(uids),
         host=host,
     )

@@ -536,9 +536,12 @@ class RandomEffectDataset:
         residual-independent, so materializing it once per dataset cuts the
         per-solve gather traffic to the [B, R] residual rows (~S x less).
         The one-time cost is HBM for the slabs — gated by
-        ``_DEVICE_SLAB_BUDGET_BYTES``, beyond which the lazy form is kept
-        (gather per solve, bounded memory). Materialization runs as one
-        jitted program per bucket, so slabs never touch the host.
+        ``_DEVICE_SLAB_BUDGET_BYTES`` A DEVICE, beyond which the lazy form
+        is kept (gather per solve, bounded memory): a bucket whose entity
+        axis is sharded over a mesh costs each device its shard of the
+        slab, read from the placement of the bucket's own ``row_ids``.
+        Materialization runs as one jitted program per bucket, so slabs
+        never touch the host.
         """
         cached = getattr(self, "_device_blocks", None)
         if cached is not None:
@@ -548,7 +551,12 @@ class RandomEffectDataset:
         itemsize = np.dtype(self.dtype).itemsize
         for b in self.device_plans():
             if isinstance(b, BlockPlan):
-                bb, r = b.row_ids.shape
+                # The entities one device holds: the shard of a placed
+                # plan array, the whole bucket anywhere else.
+                sharding = getattr(b.row_ids, "sharding", None)
+                bb, r = (
+                    b.row_ids.shape if sharding is None
+                    else sharding.shard_shape(b.row_ids.shape))
                 s = b.proj.shape[-1]
                 # Conservative estimate of the materialized layout: the
                 # subspace-dense [B, R, S] slab, or the ELL fallback's
@@ -606,6 +614,34 @@ class RandomEffectDataset:
         result = (covered, passive)
         object.__setattr__(self, "_covered", result)
         return result
+
+    def passive_rows_device(self) -> Array | None:
+        """The passive rows' numbers on the device (cached), on every
+        device where ``score_codes`` spans several; None without any."""
+        cached = getattr(self, "_passive_dev", None)
+        if cached is None:
+            _, passive = self.covered_row_partition()
+            if not passive.size:
+                return None
+            sharding = getattr(self.score_codes, "sharding", None)
+            if sharding is not None and len(sharding.device_set) > 1:
+                cached = jax.device_put(passive, jax.sharding.NamedSharding(
+                    sharding.mesh, jax.sharding.PartitionSpec()))
+            else:
+                cached = jnp.asarray(passive)
+            object.__setattr__(self, "_passive_dev", cached)
+        return cached
+
+    def device_leaves(self) -> tuple:
+        """Everything of this data set that lives on a device, as the
+        unfused loop holds it: plans, cached slabs, scoring state."""
+        return (
+            self.device_plans(), self.device_blocks(), self.score_codes,
+            self.raw, self.proj_dev,
+            self.passive_rows_device() if self.is_lazy else None,
+            self.score_indices, self.score_values, self.score_tail_rows,
+            self.score_tail_indices, self.score_tail_values,
+        )
 
     @property
     def is_lazy(self) -> bool:

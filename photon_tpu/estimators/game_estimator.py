@@ -215,6 +215,9 @@ class _FixedEffectModelAdapter:
     def score(self, model: FixedEffectModel):
         return self.inner.score(model.model)
 
+    def programs_per_update(self) -> dict:
+        return self.inner.programs_per_update()
+
     def warmup_thunks(self):
         def thunk():
             model, _ = self.train()
@@ -356,6 +359,8 @@ class GameEstimator:
         from photon_tpu.data.dataset import DualEllFeatures
 
         mesh = self.resolve_mesh()
+        if mesh is None:
+            data = data.on_device()
 
         def build_one(cid: str, cfg):
             from photon_tpu.resilience import faults
@@ -453,6 +458,15 @@ class GameEstimator:
         }
         if not pending:
             return out
+        if mesh is not None:
+            # The plan arrays go from the host to the devices that share
+            # them (shard_random_effect_dataset pads them there): the
+            # packed buffer would put every one of them on one device
+            # first.
+            for cid, p in pending.items():
+                out[cid] = shard_random_effect_dataset(
+                    p.finalize(None), mesh)
+            return out
         all_flat: list = []
         spans: dict[str, tuple[int, int]] = {}
         for cid, p in pending.items():
@@ -461,10 +475,7 @@ class GameEstimator:
         devs = _plan_arrays_to_device(all_flat)
         for cid, p in pending.items():
             lo, hi = spans[cid]
-            ds = p.finalize(devs.view(lo, hi))
-            if mesh is not None:
-                ds = shard_random_effect_dataset(ds, mesh)
-            out[cid] = ds
+            out[cid] = p.finalize(devs.view(lo, hi))
         return out
 
     def _wants_column_sharding(
@@ -684,6 +695,23 @@ class GameEstimator:
         while len(cache) > _FUSED_CACHE_SIZE:
             cache.popitem(last=False)
         return self._attach_aot(fused)
+
+    def _unfused_fit_attrs(self, coords, datasets, opt_configs) -> dict:
+        """The unfused loop's ``fit`` stage attributes
+        (``coordinate_descent.fit_stage_attrs``), made on the first fit of
+        a prepared data set under an optimization configuration and
+        handed to every later one: a warm fit pays one comparison."""
+        key = tuple(self._full_config(opt_configs).items())
+        cached = getattr(self, "_unfused_attrs", None)
+        if (cached is None or cached[0] is not datasets
+                or cached[1] != key):
+            from photon_tpu.algorithm.coordinate_descent import (
+                fit_stage_attrs,
+            )
+
+            cached = self._unfused_attrs = (
+                datasets, key, fit_stage_attrs(coords))
+        return cached[2]
 
     def _attach_aot(self, fused):
         """Hand prepare()'s pending AOT warm-compile future to the fused
@@ -1049,6 +1077,7 @@ class GameEstimator:
         self._primed_datasets = None
         self._fused_cache = None
         self._fused_mat_share = None
+        self._unfused_attrs = None
         self._fit_cache = None
         # Ingest pipeline: fresh stage accounting per dataset generation
         # (raw_transfer survives — it was recorded at make_game_dataset
@@ -1379,6 +1408,8 @@ class GameEstimator:
                         ),
                         on_iteration=on_iteration,
                         initial_best=initial_best,
+                        fit_attrs=self._unfused_fit_attrs(
+                            coords, datasets, opt_configs),
                     )
             full_config = self._full_config(opt_configs)
             result = GameFitResult(

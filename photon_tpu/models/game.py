@@ -169,7 +169,7 @@ def _score_via_buckets(w: Array, ds: RandomEffectDataset) -> Array | None:
         # no passive rows) fall through to the zeros below.
         slabs = tuple(eb.x_values for eb in blocks)
         codes = tuple(p.entity_codes for p in plans)
-        pr = jnp.asarray(passive) if passive.size else None
+        pr = ds.passive_rows_device()
         return _gather_score(
             w, slabs, codes, inv, pr, ds.score_codes, ds.raw,
             ds.proj_device())
@@ -181,7 +181,7 @@ def _score_via_buckets(w: Array, ds: RandomEffectDataset) -> Array | None:
             spmd=placement.spans_devices((eb.x_values, plan.row_ids, w)),
         )
     if passive.size:
-        pr = jnp.asarray(passive)
+        pr = ds.passive_rows_device()
         feats = ds.raw
         if isinstance(feats, DenseFeatures):
             z = _passive_score_set_dense(
@@ -193,6 +193,23 @@ def _score_via_buckets(w: Array, ds: RandomEffectDataset) -> Array | None:
                 w, ds.proj_device(),
             )
     return z
+
+
+def score_programs(ds: RandomEffectDataset) -> int:
+    """How many programs ``score_dataset`` dispatches on ``ds``: the
+    branches of ``_score_via_buckets`` counted, not run (a zeros vector
+    to add into is one of JAX's one-primitive helpers and not counted)."""
+    if not ds.is_lazy:
+        return 1
+    plans, blocks = ds.device_plans(), ds.device_blocks()
+    if any(eb is plan or getattr(eb, "x_indices", True) is not None
+           for plan, eb in zip(plans, blocks)):
+        return 1  # score_raw_features
+    _, passive = ds.covered_row_partition()
+    if ds.score_inv_device() is not None and (blocks or passive.size):
+        return 1  # _gather_score
+    # One add a bucket and the passive rows' set.
+    return len(blocks) + bool(passive.size)
 
 
 def bucket_score_parts(w, slabs, codes):

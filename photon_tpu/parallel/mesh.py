@@ -188,7 +188,10 @@ def shard_random_effect_dataset(
         if leaf is None:  # dense-layout EntityBlocks carry x_indices=None
             return None
         widths = [(0, pad)] + [(0, 0)] * (np.ndim(leaf) - 1)
-        return jnp.pad(leaf, widths, constant_values=fills.get(name, 0))
+        # A plan leaf still on the host is padded there and goes from
+        # the host to its devices, never through one device.
+        xp = np if isinstance(leaf, np.ndarray) else jnp
+        return xp.pad(leaf, widths, constant_values=fills.get(name, 0))
 
     codes_np, ints_np = [], []
 
@@ -225,8 +228,12 @@ def shard_random_effect_dataset(
         return jax.tree.map(place, b)
 
     deferred: list[tuple] = []
+    first = ds.blocks[0] if ds.blocks else None
+    on_host = isinstance(first, BlockPlan) and isinstance(
+        first.row_ids, np.ndarray)
     out_blocks = [
-        pad_block(i, b) for i, b in enumerate(ds.device_plans())
+        pad_block(i, b)
+        for i, b in enumerate(ds.blocks if on_host else ds.device_plans())
     ]
     if deferred:
         from photon_tpu.data.pipeline import PIPELINE_STATS
@@ -504,6 +511,11 @@ def shard_batch(
     unsharded objectives agree bit-for-bit up to reduction order.
     """
     n_dev = mesh.shape[axis_name]
+    if all(isinstance(leaf, np.ndarray) for leaf in jax.tree.leaves(batch)):
+        # A batch still on the host (make_host_game_dataset): each device
+        # is sent its own rows and no device sees the whole table.
+        return jax.tree.map(
+            lambda leaf: _rows_from_host(leaf, mesh, axis_name), batch)
     batch = pad_batch(batch, n_dev)
     return jax.tree.map(
         lambda leaf: jax.device_put(
@@ -511,3 +523,43 @@ def shard_batch(
         ),
         batch,
     )
+
+
+def _rows_from_host(leaf: np.ndarray, mesh: Mesh, axis_name: str):
+    """A host ``[n, ...]`` array as a row-sharded device array of
+    ``pad_batch``'s length (zero rows after the last), each device's rows
+    sent from the host to that device alone."""
+    n = leaf.shape[0]
+    n_dev = mesh.shape[axis_name]
+    padded = (n + (-n) % n_dev,) + leaf.shape[1:]
+
+    def rows(index):
+        lo, hi, _ = index[0].indices(padded[0])
+        part = leaf[lo:min(hi, n)]
+        if hi > n:
+            part = np.concatenate(
+                [part, np.zeros((hi - max(lo, n),) + leaf.shape[1:],
+                                leaf.dtype)])
+        return part
+
+    return jax.make_array_from_callback(
+        padded, row_sharding(mesh, leaf.ndim, axis_name=axis_name), rows)
+
+
+def placed_bytes(tree, devices) -> list[int]:
+    """Bytes of ``tree``'s device arrays that each of ``devices`` holds,
+    from shapes and shardings alone (a replicated array counts whole on
+    every device it is on, a sharded one by its shard; an array that
+    appears twice counts once). Nothing is read from a device."""
+    held = {d: 0 for d in devices}
+    seen = set()
+    for leaf in jax.tree.leaves(tree):
+        if not isinstance(leaf, jax.Array) or id(leaf) in seen:
+            continue
+        seen.add(id(leaf))
+        shard = leaf.sharding.shard_shape(leaf.shape)
+        size = int(np.prod(shard, dtype=np.int64)) * leaf.dtype.itemsize
+        for d in leaf.sharding.device_set:
+            if d in held:
+                held[d] += size
+    return [held[d] for d in devices]

@@ -115,7 +115,8 @@ class TestTrainCLI:
         # The driver's section spans and the estimator's fit tree (this
         # config has validation -> the unfused per-coordinate path).
         assert "prepare training datasets" in span_paths
-        assert any("fit/config:0/coord:" in p for p in span_paths)
+        # Since PR 36 the loop's updates nest under its own `fit` stage.
+        assert any("fit/config:0/fit/coord:" in p for p in span_paths)
         summary = json.loads(
             (tmp_path / "out" / "training-summary.json").read_text())
         assert summary["telemetry"]["spans"]
