@@ -49,6 +49,17 @@ class Coordinate(Protocol):
         """Model contribution per row of the canonical table."""
 
 
+def fit_rows(v: Array, rows: int | None) -> Array:
+    """``v`` cut or zero-padded to ``rows`` entries (None: as it is): a
+    per-row vector moved between a coordinate's own row count and the
+    coordinate-descent loop's (``FixedEffectCoordinate.logical_rows``)."""
+    if rows is None or v.shape[0] == rows:
+        return v
+    if v.shape[0] > rows:
+        return v[:rows]
+    return jnp.pad(v, (0, rows - v.shape[0]))
+
+
 @dataclasses.dataclass(frozen=True)
 class FixedEffectCoordinate:
     """Global GLM coordinate over one feature shard.
@@ -62,10 +73,12 @@ class FixedEffectCoordinate:
 
     batch: GLMBatch
     problem: GLMOptimizationProblem
-    # Canonical row count when ``batch`` carries weight-0 padding rows for
-    # even device sharding (parallel/mesh.py shard_batch): residual vectors
-    # arrive at the canonical length and scores must return at it, so the
-    # coordinate-descent bookkeeping never sees the padding.
+    # The length of the vectors the coordinate-descent loop exchanges with
+    # this coordinate (residuals in, scores out): the canonical row count,
+    # or on a mesh that count padded to the device count
+    # (parallel/mesh.py loop_rows), which a row-sharded ``batch`` already
+    # has (shard_batch's weight-0 padding rows). A batch of another length
+    # has its scores and residuals fitted to it (``fit_rows``).
     logical_rows: int | None = None
 
     @property
@@ -81,9 +94,7 @@ class FixedEffectCoordinate:
     ):
         batch = self.batch
         if residuals is not None:
-            pad = batch.num_samples - residuals.shape[0]
-            if pad:
-                residuals = jnp.pad(residuals, (0, pad))
+            residuals = fit_rows(residuals, batch.num_samples)
             batch = batch.with_offsets(batch.offsets + residuals)
         rate = self.config.down_sampling_rate
         if 0.0 < rate < 1.0:
@@ -118,18 +129,17 @@ class FixedEffectCoordinate:
         return model, solution.result
 
     def score(self, model: GeneralizedLinearModel) -> Array:
-        s = model.coefficients.compute_score(self.batch.features)
-        if self.logical_rows is not None and s.shape[0] != self.logical_rows:
-            s = s[: self.logical_rows]
-        return s
+        return fit_rows(
+            model.coefficients.compute_score(self.batch.features),
+            self.logical_rows)
 
     def programs_per_update(self) -> dict:
         """The programs an update dispatches (``fit`` stage, unfused
-        loop): the solve; the matvec and, over padded rows, the cut back
-        to the canonical ones; with residuals their sum into the offsets
-        and, over padded rows, their padding. JAX's one-primitive helpers
-        (a cast of a scalar operand, a zeros vector, a slice's index) are
-        not counted."""
+        loop): the solve; the matvec and, where the batch has another
+        length than the loop's vectors, the fit to it; with residuals
+        their sum into the offsets and that fit the other way. JAX's
+        one-primitive helpers (a cast of a scalar operand, a zeros vector,
+        a slice's index) are not counted."""
         padded = (
             self.logical_rows is not None
             and self.batch.num_samples != self.logical_rows)

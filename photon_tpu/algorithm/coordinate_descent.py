@@ -19,6 +19,7 @@ primary evaluator is tracked across all updates.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import logging
 import time
 from typing import Any, Callable
@@ -55,6 +56,15 @@ def _sub_add(total, old, new):
     if total is old or total is new:
         return _sub_add_plain(total, old, new)
     return _sub_add_donating(total, old, new)
+
+
+@functools.partial(jax.jit, static_argnames=("sharding",))
+def _placed_residuals(total, old, *, sharding):
+    """``total - old`` (``old`` None: ``total``) placed as ``sharding``
+    says, in one program: on a mesh the one replication of an update
+    whose coordinate gathers its residuals at arbitrary rows."""
+    r = total if old is None else total - old
+    return jax.lax.with_sharding_constraint(r, sharding)
 
 
 @jax.jit
@@ -113,6 +123,17 @@ def _serialize_on_cpu_mesh(x) -> None:
     ds = x.devices()
     if len(ds) > 1 and next(iter(ds)).platform == "cpu":
         jax.block_until_ready(x)
+
+
+def _residual_sharding(coord, total):
+    """The placement ``coord`` asks of its residuals
+    (``residual_sharding``), where ``total`` lies elsewhere; None where
+    it asks none or ``total`` is already there."""
+    said = getattr(coord, "residual_sharding", None)
+    want = said() if said is not None else None
+    if want is None or total.sharding.is_equivalent_to(want, total.ndim):
+        return None
+    return want
 
 
 def _programs_per_update(coord) -> dict:
@@ -400,7 +421,12 @@ class CoordinateDescent:
                     if total is not None:
                         residuals = total
                         programs += counts["residuals"]
-                        if cid in scores:
+                        want = _residual_sharding(coord, total)
+                        if want is not None:
+                            residuals = _placed_residuals(
+                                total, scores.get(cid), sharding=want)
+                            programs += 1
+                        elif cid in scores:
                             residuals = residuals - scores[cid]
                             programs += 1
                     model, diag = coord.train(

@@ -32,6 +32,7 @@ import numpy as np
 from jax import lax
 
 from photon_tpu import optim
+from photon_tpu.algorithm.coordinate import fit_rows
 from photon_tpu.algorithm.problems import (
     GLMOptimizationConfiguration,
     VarianceComputationType,
@@ -49,7 +50,12 @@ from photon_tpu.data.random_effect import (
     EntityBlocks,
     RandomEffectDataset,
 )
-from photon_tpu.models.game import RandomEffectModel
+from photon_tpu.models.game import (
+    RandomEffectModel,
+    score_programs,
+    score_route,
+    score_rows,
+)
 from photon_tpu.ops import glm as glm_ops
 from photon_tpu.ops import losses as losses_mod
 from photon_tpu.ops import placement
@@ -1430,6 +1436,9 @@ class RandomEffectCoordinate:
     # design slabs bf16 with f32 accumulators/state; "float32" (default)
     # is the historical path. A declared recompile key (PERFORMANCE.md).
     precision: str = "float32"
+    # The length of the coordinate-descent loop's per-row vectors, as
+    # FixedEffectCoordinate.logical_rows: scores are fitted to it.
+    logical_rows: int | None = None
 
     def _dispatch_block(self, block, residuals, w0_full, w_all, v_all,
                         block_index=None):
@@ -1638,20 +1647,36 @@ class RandomEffectCoordinate:
         return model, stats
 
     def score(self, model: RandomEffectModel) -> Array:
-        """Model contribution per canonical row (active + passive)."""
-        return model.score_dataset(self.dataset)
+        """Model contribution per row (active + passive), one a row of the
+        loop's vectors (``logical_rows``)."""
+        return fit_rows(model.score_dataset(self.dataset), self.logical_rows)
+
+    def residual_sharding(self):
+        """Where an update wants its residuals: replicated over the mesh
+        its buckets are sharded on, since every bucket's solve gathers
+        them at arbitrary rows (the loop replicates them once an update,
+        and no solve's program reshards them again); None on one device."""
+        for plan in self.dataset.device_plans():
+            sharding = getattr(plan.row_ids, "sharding", None)
+            if (isinstance(sharding, jax.sharding.NamedSharding)
+                    and len(sharding.device_set) > 1):
+                return jax.sharding.NamedSharding(
+                    sharding.mesh, jax.sharding.PartitionSpec())
+        return None
 
     def programs_per_update(self) -> dict:
         """The programs an update dispatches (``fit`` stage, unfused
         loop): one solve a bucket; the scorer's
-        (``models.game.score_programs``). Residuals enter inside the
+        (``models.game.score_programs``) and, where its vector has another
+        length than the loop's, the fit to it. Residuals enter inside the
         solves; the zeros tables the solves fill are JAX's one-primitive
         helpers and not counted."""
-        from photon_tpu.models.game import score_programs
-
+        ds = self.dataset
+        refit = (self.logical_rows is not None
+                 and score_rows(ds) != self.logical_rows)
         return {
-            "train": len(self.dataset.device_blocks()),
-            "score": score_programs(self.dataset),
+            "train": len(ds.device_blocks()),
+            "score": score_programs(ds) + refit,
             "residuals": 0,
         }
 
@@ -1687,9 +1712,10 @@ def fit_stage_coordinate(
     ``coordinates`` attribute, the same for the fused fit and for the
     unfused loop: what the planner counted
     (``RandomEffectDataset.plan_counts``: ``active_rows``,
-    ``passive_rows``, ``capped_entities``), its ``slab_rows`` and its
+    ``passive_rows``, ``capped_entities``), its ``slab_rows``, its
     ``rungs`` as ``[entities, row cap, route]`` with the ``solve.<route>``
-    scope ``_solve_block`` gives that slab. ``slabs``: the buckets as the
+    scope ``_solve_block`` gives that slab, and its ``score_route``
+    (``models.game.score_route``). ``slabs``: the buckets as the
     fit solves them, ``EntityBlocks`` or, for a bucket left lazy, its
     ``BlockPlan``; only shapes, dtypes and placements are read. Host ints
     and strings: a caller makes it once per prepared data set."""
@@ -1706,6 +1732,7 @@ def fit_stage_coordinate(
         coord.dataset.plan_counts or {},
         slab_rows=sum(b * r for b, r, _ in rungs),
         rungs=rungs,
+        score_route=score_route(coord.dataset, slabs),
     )
 
 

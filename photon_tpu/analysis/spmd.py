@@ -335,6 +335,9 @@ def _named_mesh_leaves(batch, re_ds, w) -> dict[str, Any]:
     codes = getattr(re_ds, "score_codes", None)
     if codes is not None:
         leaves["re/score_codes"] = codes
+    if re_ds.is_lazy:
+        leaves["re/score_inv"] = re_ds.score_inv_device()
+        leaves["re/passive_rows"] = re_ds.passive_rows_device()
     return leaves
 
 
@@ -404,9 +407,11 @@ def build_mesh_spmd(hosts: int) -> SpmdTrace:
 
     # Random-effect placement + the named-leaf coverage table.
     est, data = _tiny_glmix(n=16 * n_dev, e=2 * n_dev)
+    # A cap under the rows per entity leaves passive rows to place.
     re_ds = build_random_effect_dataset(
         data,
-        RandomEffectDataConfiguration("userId", "userShard"),
+        RandomEffectDataConfiguration(
+            "userId", "userShard", active_data_upper_bound=4),
         intercept_index=3,
     )
     re_ds = mesh_mod.shard_random_effect_dataset(re_ds, mesh)

@@ -441,6 +441,11 @@ class RandomEffectDataset:
     # (``_plan_counts``): the `fit` stage's attributes read them, so a
     # fit never counts anything. None on an AOT skeleton.
     plan_counts: dict | None = None
+    # The inverse score map where no packed buffer carries it: the host
+    # array of a build whose plan stayed on the host, then, once a mesh
+    # has placed it (parallel/mesh.py shard_random_effect_dataset), its
+    # row-sharded device array in the padded buckets' layout.
+    score_inv: object | None = None
 
     @property
     def num_rows(self) -> int:
@@ -495,18 +500,26 @@ class RandomEffectDataset:
         object.__setattr__(self, "_device_plans", out)
         return out
 
+    @property
+    def has_score_inv(self) -> bool:
+        """Whether an inverse score map exists, read without a device."""
+        return self.score_inv is not None or (
+            self.packed_view is not None
+            and len(self.packed_view) == packed_len_with_score_inv(
+                len(self.blocks)))
+
     def score_inv_device(self) -> Array | None:
         """[n] int32 inverse score map (device), or None when absent.
 
         Maps each canonical row to its flat position in the concatenation
         of all buckets' [B, cap] score blocks followed by the passive-row
         score vector — the scatter-free scoring contract (trailing array
-        of the packed plan layout)."""
-        if self.packed_view is None:
-            return None
+        of the packed plan layout, or the mesh's placed map)."""
+        if isinstance(self.score_inv, jax.Array):
+            return self.score_inv
+        if self.packed_view is None or not self.has_score_inv:
+            return None  # none, or a pre-score-map packed layout
         n_blocks = len(self.blocks)
-        if len(self.packed_view) != packed_len_with_score_inv(n_blocks):
-            return None  # pre-score-map packed layout
         cached = getattr(self, "_score_inv_cache", None)
         if cached is None:
             cached = self.packed_view.device_arrays()[
@@ -616,8 +629,11 @@ class RandomEffectDataset:
         return result
 
     def passive_rows_device(self) -> Array | None:
-        """The passive rows' numbers on the device (cached), on every
-        device where ``score_codes`` spans several; None without any."""
+        """The passive rows' numbers on the device (cached); None without
+        any. Where ``score_codes`` spans a mesh they are sharded over its
+        axis, padded to the device count with the last passive row again:
+        the gather scorer never reads the padding's scores, and a scatter
+        that sets them writes that row's own score twice."""
         cached = getattr(self, "_passive_dev", None)
         if cached is None:
             _, passive = self.covered_row_partition()
@@ -625,8 +641,14 @@ class RandomEffectDataset:
                 return None
             sharding = getattr(self.score_codes, "sharding", None)
             if sharding is not None and len(sharding.device_set) > 1:
-                cached = jax.device_put(passive, jax.sharding.NamedSharding(
-                    sharding.mesh, jax.sharding.PartitionSpec()))
+                from photon_tpu.parallel.mesh import row_sharding
+
+                mesh = sharding.mesh
+                axis = mesh.axis_names[0]
+                pad = (-passive.size) % mesh.shape[axis]
+                cached = jax.device_put(
+                    np.pad(passive, (0, pad), mode="edge"),
+                    row_sharding(mesh, 1, axis_name=axis))
             else:
                 cached = jnp.asarray(passive)
             object.__setattr__(self, "_passive_dev", cached)
@@ -639,6 +661,7 @@ class RandomEffectDataset:
             self.device_plans(), self.device_blocks(), self.score_codes,
             self.raw, self.proj_dev,
             self.passive_rows_device() if self.is_lazy else None,
+            self.score_inv_device() if self.is_lazy else None,
             self.score_indices, self.score_values, self.score_tail_rows,
             self.score_tail_indices, self.score_tail_values,
         )
@@ -1999,7 +2022,7 @@ def build_random_effect_dataset(
         def finalize(devs):
             return _finalize_lazy(
                 devs, bucket_host, feats, game_data, config, num_entities,
-                tag, plan, dtype, covered_np,
+                tag, plan, dtype, covered_np, score_inv_np,
             )
 
         if defer_transfer:
@@ -2114,14 +2137,16 @@ def _plan_counts(plan: _Plan) -> dict:
 
 def _finalize_lazy(
     devs, bucket_host, feats, game_data, config, num_entities, tag, plan,
-    dtype, covered_np=None,
+    dtype, covered_np=None, score_inv_np=None,
 ):
     """Assemble the lazy RandomEffectDataset around the packed plan view.
 
     ``devs`` is a PackedPlanArrays/_PackedPlanView: the plan arrays stay
     HOST numpy on the BlockPlan leaves (free), and device placement
     resolves lazily — in-trace slices for the fused fit, one split
-    program via ``device_plans()`` for eager consumers."""
+    program via ``device_plans()`` for eager consumers. ``devs`` None
+    (a mesh places the plan from the host): the data set keeps the host
+    inverse score map for the mesh to place."""
     blocks = []
     for bh in bucket_host:
         blocks.append(BlockPlan(
@@ -2155,4 +2180,5 @@ def _finalize_lazy(
         covered_np=covered_np,
         packed_view=devs,
         plan_counts=_plan_counts(plan),
+        score_inv=score_inv_np if devs is None else None,
     )

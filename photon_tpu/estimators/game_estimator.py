@@ -54,6 +54,7 @@ from photon_tpu.models.game import (
 )
 from photon_tpu.ops.normalization import NormalizationContext
 from photon_tpu.parallel.mesh import (
+    loop_rows,
     resolve_mesh,
     shard_batch,
     shard_random_effect_dataset,
@@ -555,8 +556,12 @@ class GameEstimator:
     ) -> dict[str, object]:
         """CoordinateFactory.build equivalent (CoordinateFactory.scala:52);
         ``priors`` carries incremental-training prior models per coordinate
-        (the factory's priorModelOpt, DistributedGLMLossFunction.scala:184)."""
+        (the factory's priorModelOpt, DistributedGLMLossFunction.scala:184).
+        ``logical_rows``: the canonical row count; on a mesh the loop's
+        vectors have it padded to the device count (``loop_rows``)."""
         priors = priors or {}
+        if logical_rows is not None:
+            logical_rows = loop_rows(logical_rows, self.resolve_mesh())
         coords: dict[str, object] = {}
         for cid, cfg in self.coordinate_configs.items():
             opt = opt_configs.get(cid, cfg.optimization)
@@ -568,6 +573,7 @@ class GameEstimator:
                     self._shard_norm(cfg.data.feature_shard_id),
                     prior=priors.get(cid),
                     precision=self.precision,
+                    logical_rows=logical_rows,
                 )
             else:
                 problem = GLMOptimizationProblem(

@@ -22,8 +22,9 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
-from photon_tpu.data.random_effect import RandomEffectDataset
+from photon_tpu.data.random_effect import BlockPlan, RandomEffectDataset
 from photon_tpu.models.glm import GeneralizedLinearModel
 from photon_tpu.ops import placement
 from photon_tpu.ops import precision as precision_mod
@@ -150,29 +151,32 @@ def _score_via_buckets(w: Array, ds: RandomEffectDataset) -> Array | None:
     scores through the raw-gather path on its row SUBSET — the
     active/passive split of RandomEffectDataset.scala:631-640 as device
     index arithmetic. Applicable when every bucket materialized to a
-    subspace-dense slab (the common small-sub_dim case).
+    subspace-dense slab (the common small-sub_dim case). The gather route
+    returns the inverse map's rows: on a mesh the canonical rows padded
+    to the device count (``score_rows``), sharded by rows.
     """
     from photon_tpu.data.dataset import DenseFeatures, SparseFeatures
 
+    route = score_route(ds)
+    if route == "raw":
+        return None
     plans = ds.device_plans()
     blocks = ds.device_blocks()
-    for plan, eb in zip(plans, blocks):
-        if eb is plan or getattr(eb, "x_indices", True) is not None:
-            return None
-    _, passive = ds.covered_row_partition()
-    inv = ds.score_inv_device()
-    if inv is not None and (blocks or passive.size):
+    if route == "gather":
         # Scatter-free path (same contract as the fused fit's scorer):
         # bucket score blocks + passive scores concatenate into one flat
         # vector that a single gather distributes — TPU scatter-adds of
         # the same pass measured ~4x slower. Empty datasets (no buckets,
-        # no passive rows) fall through to the zeros below.
+        # no passive rows) take the zeros below.
+        inv = ds.score_inv_device()
         slabs = tuple(eb.x_values for eb in blocks)
         codes = tuple(p.entity_codes for p in plans)
-        pr = ds.passive_rows_device()
-        return _gather_score(
-            w, slabs, codes, inv, pr, ds.score_codes, ds.raw,
-            ds.proj_device())
+        args = (w, slabs, codes, inv, ds.passive_rows_device(),
+                ds.score_codes, ds.raw, ds.proj_device())
+        if placement.spans_devices(inv):
+            return _gather_score_mesh(*args, mesh=inv.sharding.mesh)
+        return _gather_score(*args)
+    _, passive = ds.covered_row_partition()
     z = jnp.zeros(ds.num_rows, dtype=w.dtype)
     for plan, eb in zip(plans, blocks):
         z = _bucket_score_add(
@@ -195,21 +199,47 @@ def _score_via_buckets(w: Array, ds: RandomEffectDataset) -> Array | None:
     return z
 
 
+def score_route(ds: RandomEffectDataset, slabs=None) -> str:
+    """Which branch of ``RandomEffectModel.score_dataset`` scores ``ds``,
+    decided from what the data set holds, not run: ``"table"`` (the
+    materialized score table), ``"raw"`` (a bucket left lazy or in ELL
+    form: every row from the raw features), ``"gather"`` (one gather
+    through the inverse score map, on a mesh each device its own rows) or
+    ``"scatter"`` (an add a bucket into an ``[n]`` vector, where no map
+    exists). ``slabs``: the buckets as a fit solves them (by default the
+    data set's ``device_blocks``); only their form is read, so a caller
+    that passes them places and materializes nothing."""
+    if not ds.is_lazy:
+        return "table"
+    if slabs is None:
+        slabs = ds.device_blocks()
+    if any(isinstance(b, BlockPlan) or b.x_indices is not None
+           for b in slabs):
+        return "raw"
+    _, passive = ds.covered_row_partition()
+    if ds.has_score_inv and (slabs or passive.size):
+        return "gather"
+    return "scatter"
+
+
 def score_programs(ds: RandomEffectDataset) -> int:
     """How many programs ``score_dataset`` dispatches on ``ds``: the
     branches of ``_score_via_buckets`` counted, not run (a zeros vector
     to add into is one of JAX's one-primitive helpers and not counted)."""
-    if not ds.is_lazy:
+    if score_route(ds) != "scatter":
         return 1
-    plans, blocks = ds.device_plans(), ds.device_blocks()
-    if any(eb is plan or getattr(eb, "x_indices", True) is not None
-           for plan, eb in zip(plans, blocks)):
-        return 1  # score_raw_features
-    _, passive = ds.covered_row_partition()
-    if ds.score_inv_device() is not None and (blocks or passive.size):
-        return 1  # _gather_score
     # One add a bucket and the passive rows' set.
-    return len(blocks) + bool(passive.size)
+    _, passive = ds.covered_row_partition()
+    return len(ds.device_blocks()) + bool(passive.size)
+
+
+def score_rows(ds: RandomEffectDataset) -> int:
+    """The length of the vector ``score_dataset`` returns on ``ds``: the
+    inverse map's on the gather route (on a mesh the rows padded to the
+    device count), the data set's rows on every other."""
+    if score_route(ds) == "gather":
+        return int(ds.score_inv_device().shape[0])
+    return ds.num_rows
 
 
 def bucket_score_parts(w, slabs, codes):
@@ -257,6 +287,33 @@ def _gather_score(w, slabs, codes, inv, pr, score_codes, feats, proj_dev):
                                         proj_dev))
     return jnp.take(
         jnp.concatenate(parts), inv, mode="clip").astype(w.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("mesh",))
+def _gather_score_mesh(w, slabs, codes, inv, pr, score_codes, feats,
+                       proj_dev, *, mesh):
+    """``_gather_score`` on a mesh, its communication written out: each
+    device scores its own entities' slab rows and its share of the
+    passive rows, the flat parts are all-gathered in bucket order (the
+    layout the padded inverse map points into), and each device gathers
+    its own rows of the map. Row-sharded ``[len(inv)]``."""
+    axis = mesh.axis_names[0]
+    rows, whole = P(axis), P()
+
+    def local(w, slabs, codes, inv, pr, score_codes, feats, proj_dev):
+        parts = bucket_score_parts(w, slabs, codes)
+        if pr is not None:
+            parts.append(passive_raw_scores(w, pr, score_codes, feats,
+                                            proj_dev))
+        flat = jnp.concatenate(
+            [jax.lax.all_gather(p, axis, tiled=True) for p in parts])
+        return jnp.take(flat, inv, mode="clip").astype(w.dtype)
+
+    return jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(whole, rows, rows, rows, rows, whole, whole, whole),
+        out_specs=rows,
+    )(w, slabs, codes, inv, pr, score_codes, feats, proj_dev)
 
 
 @jax.jit

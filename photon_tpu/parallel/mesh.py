@@ -98,6 +98,10 @@ PARTITION_RULES = (
     (r"^re/raw(/|$)", P()),
     # Residual-scorer tables: per-row work, rows sharded when divisible.
     (r"^re/score_(codes|indices|values)$", P(DATA_AXIS)),
+    # The lazy scorer's inverse map and passive row numbers: rows sharded,
+    # padded to the device count (each device gathers its own rows'
+    # scores and scores its share of the passive rows, models/game.py).
+    (r"^re/(score_inv|passive_rows)$", P(DATA_AXIS)),
     # Coefficients: replicated in HBM; gradients all-reduce into them.
     (r"^coef(/|$)", P()),
 )
@@ -151,7 +155,10 @@ def shard_random_effect_dataset(
     its own entities' rows locally — the replication rides ICI once, and is
     the memory-for-zero-shuffle tradeoff the reference pays per iteration
     in shuffles instead). The materialized scoring table's row axis is
-    sharded when evenly divisible.
+    sharded when evenly divisible. A lazy data set's inverse score map is
+    rebased to the padded buckets and placed with its rows sharded,
+    padded to the device count (``loop_rows``), from the host where the
+    build left it there.
     """
     import dataclasses
 
@@ -276,6 +283,15 @@ def shard_random_effect_dataset(
             codes = place(codes)
         else:
             codes = replicate(codes)
+        inv = (ds.score_inv if isinstance(ds.score_inv, np.ndarray)
+               else ds.score_inv_device())
+        if inv is not None:
+            inv = _padded_score_inv(
+                inv, [b.row_ids.shape for b in ds.blocks], n_dev)
+            rep["score_inv"] = (
+                _rows_from_host(inv, mesh, axis_name)
+                if isinstance(inv, np.ndarray)
+                else place(jnp.pad(inv, (0, (-inv.shape[0]) % n_dev))))
         rep.update(
             raw=replicate_cached(ds.raw),
             score_codes=codes,
@@ -288,6 +304,33 @@ def shard_random_effect_dataset(
             score_values=place(ds.score_values),
         )
     return dataclasses.replace(ds, **rep)
+
+
+def _padded_score_inv(inv, shapes, n_dev: int):
+    """The inverse score map (``data/random_effect.py``) of buckets padded
+    to the device count: bucket i's ``[B, cap]`` block now starts after
+    the ``(B + pad) * cap`` slots of the buckets before it, where ``pad =
+    (-B) % n_dev`` is the inert tail ``pad_block`` appends, and the
+    passive scores after all of them. ``shapes``: each bucket's ``(B,
+    cap)``; a host map stays on the host."""
+    starts = np.cumsum([0] + [b * r for b, r in shapes])
+    padded = np.cumsum([0] + [(b + (-b) % n_dev) * r for b, r in shapes])
+    xp = np if isinstance(inv, np.ndarray) else jnp
+    passive = int(xp.count_nonzero(inv >= starts[-1]))
+    if padded[-1] + passive >= 2**31:
+        raise OverflowError(
+            f"the padded flat score layout has {padded[-1] + passive} "
+            "elements, which overflows the int32 inverse score map")
+    region = xp.searchsorted(starts[1:], inv, side="right")
+    return inv + xp.asarray((padded - starts).astype(np.int32))[region]
+
+
+def loop_rows(n: int, mesh: Mesh | None) -> int:
+    """The length of the unfused loop's per-row vectors (residuals in,
+    scores out): ``n`` on one device; on a mesh ``n`` padded to the device
+    count, as ``shard_batch`` pads the rows, so that they shard by rows (an
+    array whose length the device count does not divide cannot)."""
+    return n if mesh is None else n + (-n) % mesh.shape[mesh.axis_names[0]]
 
 
 def make_mesh(

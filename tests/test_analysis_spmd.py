@@ -333,6 +333,18 @@ class TestPartitionCoverage:
         leaves["zz/scalar"] = _leaf(0)  # matches nothing; ndim 0
         assert self._check(leaves) == []
 
+    def test_the_repos_mesh_leaves_match_one_rule_each(self):
+        """The mesh fixture's placed leaves, the lazy scorer's inverse
+        map and passive rows among them (PR 37), each match exactly one
+        of ``PARTITION_RULES``, and those two shard as their rule says."""
+        cov = S.build_mesh_spmd(2).coverage["leaves"]
+        for name in ("re/score_inv", "re/passive_rows"):
+            assert cov[name]["placed_sharded"] and cov[name][
+                "intended_sharded"], name
+        assert all(len(row["matches"]) == 1 for row in cov.values()
+                   if row["ndim"]), {
+            n: r["matches"] for n, r in cov.items() if len(r["matches"]) != 1}
+
 
 # --------------------------------------------------------------------------
 # the host-divergence AST lint

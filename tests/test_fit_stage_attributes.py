@@ -135,7 +135,19 @@ def test_the_counts_are_those_of_the_raw_ids_and_the_caps(heavy_tail):
         assert got["passive_rows"] == N - kept.sum() > 0
         assert got["capped_entities"] == np.count_nonzero(counts > cap) > 0
         assert set(got) == {"active_rows", "passive_rows", "capped_entities",
-                            "slab_rows", "rungs"}
+                            "slab_rows", "rungs", "score_route"}
+        assert got["score_route"] == "gather"
+
+
+def test_the_attributes_place_and_materialize_nothing(heavy_tail):
+    """Shapes and host arrays give the ``fit`` stage's attributes
+    (``score_route`` too, PR 37): the fused fit's data sets hold no split
+    plan and no cached slab, the unfused loop's ``device_plans`` /
+    ``device_blocks``, whose device memory a one-chip fit would keep."""
+    for cid in CAPS:
+        ds = heavy_tail["datasets"][cid]
+        assert getattr(ds, "_device_plans", None) is None, cid
+        assert getattr(ds, "_device_blocks", None) is None, cid
 
 
 def test_home_and_the_indices_still_gathered_read_from_stage_records(

@@ -414,8 +414,13 @@ def test_compile_durations_fill_the_stats_and_the_ring_once(ring):
 
 
 def test_a_cache_load_lands_under_its_own_name(ring):
+    from photon_tpu.data import pipeline
     from photon_tpu.utils import cache_stats, compile_cache
 
+    # An earlier test's background AOT compile may still load from the
+    # cache (the ring filters its records, the counter cannot): let it
+    # land before the counter is read.
+    pipeline.compile_executor.shutdown()
     before = cache_stats()["cache_load_seconds"]
     compile_cache._on_duration(
         "/jax/compilation_cache/cache_retrieval_time_sec", 0.5)

@@ -137,6 +137,7 @@ def fits():
             out[name] = {
                 "stage": _last_fit(), "tables": _tables(result),
                 "datasets": datasets, "game": game, "est": est,
+                "model": result.model,
             }
     return out
 
@@ -172,6 +173,37 @@ def test_the_loops_fit_stage_carries_the_fused_fits_attributes(fits):
         assert attrs["slab_rows"] == sum(
             b * r for b, r, _ in attrs["rungs"])
     assert fused["per-user"]["passive_rows"] > 0
+
+
+def test_every_random_effect_scores_by_one_gather(fits):
+    """The inverse score map serves one device and the mesh alike: the
+    ``fit`` stage's ``score_route`` (PR 37; the mesh added a bucket at a
+    time into an ``[n]`` vector before)."""
+    for name in ("fused", "loop", "mesh"):
+        for cid, attrs in fits[name]["stage"].attrs["coordinates"].items():
+            assert attrs["score_route"] == "gather", (name, cid)
+
+
+def test_the_mesh_loop_carries_row_sharded_vectors(fits):
+    """On the mesh every coordinate scores the rows padded to the device
+    count, sharded by rows, and a random effect asks its residuals
+    replicated (one all-gather an update, taken by the loop); one device
+    keeps the canonical rows and asks nothing."""
+    for name, rows in (("mesh", N + (-N) % 4), ("loop", N)):
+        est, model = fits[name]["est"], fits[name]["model"]
+        coords = est._build_coordinates(
+            fits[name]["datasets"], {}, {}, logical_rows=N)
+        for cid, coord in coords.items():
+            scores = coord.score(model[cid])
+            assert scores.shape == (rows,), (name, cid)
+            if name == "mesh":
+                assert scores.sharding.spec == jax.sharding.PartitionSpec(
+                    "data"), cid
+            asks = getattr(coord, "residual_sharding", lambda: None)()
+            if name == "mesh" and cid != "global":
+                assert asks.is_fully_replicated and len(asks.device_set) == 4
+            else:
+                assert asks is None, (name, cid)
 
 
 def _leaves(datasets):
