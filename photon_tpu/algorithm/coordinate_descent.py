@@ -151,18 +151,22 @@ def fit_stage_attrs(coordinates: dict[str, Coordinate]) -> dict:
     """What the unfused loop's ``fit`` stage carries beside ``programs``:
     ``coordinates`` (per random-effect coordinate what
     ``fit_stage_coordinate`` gives: the fused fit's ``fit`` stage carries
-    the same), ``devices`` (how many devices hold a leaf of the prepared
-    data sets) and ``placed_bytes`` (one entry a device, in the order of
-    the devices' ids: the bytes of those leaves it holds, from shapes and
-    shardings). Host integers and strings; nothing is read from a device.
+    the same), ``fe_layout`` (per fixed-effect coordinate, how its solve
+    reads the features: ``data.dataset.feature_layout``), ``devices``
+    (how many devices hold a leaf of the prepared data sets) and
+    ``placed_bytes`` (one entry a device, in the order of the devices'
+    ids: the bytes of those leaves it holds, from shapes and shardings).
+    Host integers and strings; nothing is read from a device.
     ``GameEstimator`` makes it once per prepared data set."""
+    from photon_tpu.algorithm.coordinate import FixedEffectCoordinate
     from photon_tpu.algorithm.random_effect import (
         RandomEffectCoordinate,
         fit_stage_coordinate,
     )
+    from photon_tpu.data.dataset import feature_layout
     from photon_tpu.parallel.mesh import placed_bytes
 
-    per_coord, leaves = {}, []
+    per_coord, fe_layout, leaves = {}, {}, []
     for cid, coord in coordinates.items():
         inner = getattr(coord, "inner", coord)
         if isinstance(inner, RandomEffectCoordinate):
@@ -171,6 +175,8 @@ def fit_stage_attrs(coordinates: dict[str, Coordinate]) -> dict:
                 inner, ds.device_blocks(), precision=inner.precision)
             leaves.append(ds.device_leaves())
         else:
+            if isinstance(inner, FixedEffectCoordinate):
+                fe_layout[cid] = feature_layout(inner.batch)
             leaves.append(getattr(inner, "batch", None))
     devices = sorted(
         {d for leaf in jax.tree.leaves(leaves) if isinstance(leaf, jax.Array)
@@ -178,6 +184,7 @@ def fit_stage_attrs(coordinates: dict[str, Coordinate]) -> dict:
         key=lambda d: d.id)
     return {
         "coordinates": per_coord,
+        "fe_layout": fe_layout,
         "devices": len(devices),
         "placed_bytes": placed_bytes(leaves, devices),
     }

@@ -63,6 +63,7 @@ from photon_tpu.algorithm.random_effect import (
     fit_stage_coordinate,
     solver_statics as _re_statics,
 )
+from photon_tpu.data.dataset import feature_layout, feature_major
 from photon_tpu.models.game import (
     FixedEffectModel,
     GameModel,
@@ -1023,7 +1024,11 @@ class FusedFit:
         return z
 
     def _fe_score(self, means, batch):
-        return Coefficients(means=means).compute_score(batch.features)
+        """The fixed effect's scores, read through the feature-major view
+        as its solve (``_run_impl``) reads them: nothing in the program
+        reads the batch row-major, so XLA makes no relaid-out copy."""
+        return Coefficients(means=means).compute_score(
+            feature_major(batch).features)
 
     def _store_score(self, z):
         """Score-carry storage cast: bf16 under mixed precision (the
@@ -1863,18 +1868,21 @@ class FusedFit:
 
     def _fit_attrs(self, coords, ebs_all) -> dict:
         """The ``fit`` stage's attributes: per random-effect coordinate
-        what ``fit_stage_coordinate`` gives (the unfused loop's ``fit``
-        stage carries the same), then ``home`` and ``gather_indices``.
+        what ``fit_stage_coordinate`` gives and per fixed-effect
+        coordinate its ``fe_layout`` (the unfused loop's ``fit`` stage
+        carries the same), then ``home`` and ``gather_indices``.
         Host ints and strings from shapes alone, made on the first fit of
         this prepared data set and handed to every later one: a warm fit
         pays one attribute read."""
         attrs = self._fit_attrs_cache
         if attrs is None:
-            per_coord, rows = {}, {}
+            per_coord, rows, fe_layout = {}, {}, {}
             for cid in self.seq:
+                inner = getattr(coords[cid], "inner", coords[cid])
+                if self.kinds[cid] == "fixed":
+                    fe_layout[cid] = feature_layout(inner.batch)
                 if self.kinds[cid] != "random":
                     continue
-                inner = getattr(coords[cid], "inner", coords[cid])
                 rows[cid] = inner.dataset.num_rows
                 per_coord[cid] = fit_stage_coordinate(
                     inner, ebs_all[cid]["ebs"], precision=self.precision)
@@ -1885,6 +1893,7 @@ class FusedFit:
             home = self._home_of(ebs_all)
             attrs = self._fit_attrs_cache = {
                 "coordinates": per_coord,
+                "fe_layout": fe_layout,
                 "home": home,
                 "gather_indices": sum(
                     c["slab_rows"] + rows[cid] + c.get("passive_rows", 0)

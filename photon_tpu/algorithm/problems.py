@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from photon_tpu import optim
-from photon_tpu.data.dataset import GLMBatch
+from photon_tpu.data.dataset import GLMBatch, feature_major
 from photon_tpu.models.glm import Coefficients, GeneralizedLinearModel
 from photon_tpu.ops import glm as glm_ops
 from photon_tpu.ops import losses as losses_mod
@@ -268,7 +268,10 @@ def _run_impl(
     DistributedOptimizationProblem.updateRegularizationWeight :64 and the
     tuner's retrains hit the same trace). Solver routing is static: OWL-QN
     whenever the config carries an L1 part (OptimizerFactory semantics).
+    Every read of dense features goes through the feature-major view
+    (``feature_major``): on the TPU no relaid-out copy of them is made.
     """
+    batch = feature_major(batch)
     loss = losses_mod.get_loss(task)
     w0 = norm.coef_to_transformed_space(w0_orig)
     fun = glm_ops.make_value_and_grad(batch, loss, norm)

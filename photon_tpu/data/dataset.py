@@ -65,6 +65,72 @@ class DenseFeatures:
     def rmatvec_sq(self, g: Array) -> Array:
         return (self.x * self.x).T @ g
 
+    def gram(self, c: Array) -> Array:
+        """X^T diag(c) X, [d, d]: the Hessian of a FULL variance."""
+        return self.x.T @ (c[:, None] * self.x)
+
+
+# ``DenseFeatures``' products read through ``xt``, the [d, n] transpose of
+# its ``x``: the operands the other way round, the same dtypes.
+def _fm_matvec(xt: Array, w: Array) -> Array:
+    return w @ xt
+
+
+def _fm_rmatvec(xt: Array, g: Array) -> Array:
+    return xt @ g
+
+
+def _fm_rmatvec_sq(xt: Array, g: Array) -> Array:
+    return (xt * xt) @ g
+
+
+def _fm_gram(xt: Array, c: Array) -> Array:
+    return (xt * c[None, :]) @ xt.T
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class FeatureMajorFeatures:
+    """Dense features read feature-major: ``xt`` is ``[d, n]``, the
+    transpose of the stored ``x``, taken inside a program before its loops
+    (``feature_major``).
+
+    Why: the TPU stores a ``[n, d]`` array of small d with its rows on
+    the lanes, so ``x.T`` is a bitcast of it. A program that reads ``x``
+    row-major has XLA relay it out first, d padded to 128 lanes (twice
+    the bytes at d = 64), and every pass then reads the padded copy. The
+    CPU pads nothing: there each product reads ``x`` as ``DenseFeatures``
+    does (``xt`` is then dead and compiled away), so its arithmetic stays
+    what it was. The platform is chosen when the program is lowered.
+    """
+
+    x: Array  # [n, d], as stored
+    xt: Array  # [d, n]
+
+    @property
+    def num_features(self) -> int:
+        return self.xt.shape[0]
+
+    def _product(self, row_major, feature_major, v: Array) -> Array:
+        """``row_major`` (a ``DenseFeatures`` method) on the CPU,
+        ``feature_major`` of ``xt`` on any other platform."""
+        return jax.lax.platform_dependent(
+            self.x, self.xt, v,
+            cpu=lambda x, xt, v: row_major(DenseFeatures(x), v),
+            default=lambda x, xt, v: feature_major(xt, v))
+
+    def matvec(self, w: Array) -> Array:
+        return self._product(DenseFeatures.matvec, _fm_matvec, w)
+
+    def rmatvec(self, g: Array) -> Array:
+        return self._product(DenseFeatures.rmatvec, _fm_rmatvec, g)
+
+    def rmatvec_sq(self, g: Array) -> Array:
+        return self._product(DenseFeatures.rmatvec_sq, _fm_rmatvec_sq, g)
+
+    def gram(self, c: Array) -> Array:
+        return self._product(DenseFeatures.gram, _fm_gram, c)
+
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
@@ -177,7 +243,8 @@ def ell_to_dual_ell(
     )
 
 
-Features = Union[DenseFeatures, SparseFeatures, DualEllFeatures]
+Features = Union[
+    DenseFeatures, FeatureMajorFeatures, SparseFeatures, DualEllFeatures]
 
 
 @jax.tree_util.register_dataclass
@@ -215,6 +282,32 @@ class GLMBatch:
 
     def weighted_count(self) -> Array:
         return jnp.sum(self.weights)
+
+
+def _has_view(features) -> bool:
+    return isinstance(features, DenseFeatures) and features.x.ndim == 2
+
+
+def feature_layout(batch: GLMBatch) -> str:
+    """How a solve reads the batch's features: ``"feature_major"`` where
+    ``feature_major`` gives the ``[d, n]`` view, ``"row_major"`` where it
+    leaves the batch as it is. From types and ranks alone."""
+    f = batch.features
+    if isinstance(f, FeatureMajorFeatures) or _has_view(f):
+        return "feature_major"
+    return "row_major"
+
+
+def feature_major(batch: GLMBatch) -> GLMBatch:
+    """The batch with its 2-D ``DenseFeatures`` read through the
+    feature-major view (``FeatureMajorFeatures``); any other batch as it
+    is. Meant for inside a program, where the transpose is free on the
+    TPU; outside one it dispatches a transpose."""
+    if _has_view(batch.features):
+        x = batch.features.x
+        return dataclasses.replace(
+            batch, features=FeatureMajorFeatures(x, x.T))
+    return batch
 
 
 def make_dense_batch(

@@ -136,11 +136,10 @@ def hessian_matrix(
     z = margins(batch, coef, norm)
     c = batch.weights * loss.dzz(z, batch.labels)
 
-    from photon_tpu.data.dataset import DenseFeatures
+    from photon_tpu.data.dataset import DenseFeatures, FeatureMajorFeatures
 
-    if isinstance(batch.features, DenseFeatures):
-        x = batch.features.x
-        h_raw = x.T @ (c[:, None] * x)
+    if isinstance(batch.features, (DenseFeatures, FeatureMajorFeatures)):
+        h_raw = batch.features.gram(c)
     else:
         d = batch.num_features
         eye = jnp.eye(d, dtype=c.dtype)

@@ -160,7 +160,8 @@ def test_home_and_the_indices_still_gathered_read_from_stage_records(
     with jax.enable_x64(False):
         heavy_tail["est"].fit(heavy_tail["game"])
     record = [r for r in sut.stage_records() if r.name == "fit"][-1]
-    assert set(record.attrs) == {"coordinates", "home", "gather_indices"}
+    assert set(record.attrs) == {
+        "coordinates", "fe_layout", "home", "gather_indices"}
     slots = {cid: sum(int(np.prod(b.row_ids.shape)) for b in ds.blocks)
              for cid, ds in heavy_tail["datasets"].items() if cid in CAPS}
     home = max(slots, key=slots.get)

@@ -100,6 +100,15 @@ class TestFusedUnfusedParity:
         r_fused = est_fused.fit(game)[0]
         r_unfused = est_unfused.fit(game)[0]
         assert est_fused._fused_cache is not None, "fused path did not run"
+        # Both read the fixed effect's features through the feature-major
+        # view (data.dataset.feature_major), as their fit stages say.
+        from photon_tpu import obs
+
+        (fused,) = est_fused._fused_cache.values()
+        unfused_stage = [
+            r for r in obs.TRACER.completed() if r.name == "fit"][-1]
+        for attrs in (fused._fit_attrs_cache, unfused_stage.attrs):
+            assert attrs["fe_layout"] == {"global": "feature_major"}
         f, u = _coef_maps(r_fused), _coef_maps(r_unfused)
         assert f.keys() == u.keys()
         for cid in f:

@@ -398,14 +398,19 @@ def test_the_fit_in_home_order_is_the_canonical_fit_and_the_unfused_loop(
     game = _game(rng, jnp.float64)
     est = _estimator()
     home = _tables(est.fit(game)[0])
-    assert list(est._fused_cache.values())[0]._home == "per-user"
+    program = list(est._fused_cache.values())[0]
+    assert program._home == "per-user"
     unfused_est = _estimator()
     unfused_est.non_finite_guard = True  # forces the unfused loop
     unfused = _tables(unfused_est.fit(game)[0])
     canonical_order()
     est0 = _estimator()
     canonical = _tables(est0.fit(game)[0])
-    assert list(est0._fused_cache.values())[0]._home is None
+    program0 = list(est0._fused_cache.values())[0]
+    assert program0._home is None
+    # In either order the fixed effect is read feature-major.
+    for attrs in (program._fit_attrs_cache, program0._fit_attrs_cache):
+        assert attrs["fe_layout"] == {"global": "feature_major"}
     for cid in home:
         np.testing.assert_allclose(
             home[cid], canonical[cid], rtol=1e-8, atol=1e-10, err_msg=cid)

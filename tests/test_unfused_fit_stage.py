@@ -184,6 +184,15 @@ def test_every_random_effect_scores_by_one_gather(fits):
             assert attrs["score_route"] == "gather", (name, cid)
 
 
+def test_every_fit_reads_the_fixed_effect_feature_major(fits):
+    """The fused fit, the loop on one device and the loop on the mesh all
+    solve the fixed effect through the feature-major view: the ``fit``
+    stage's ``fe_layout``."""
+    for name in ("fused", "loop", "mesh"):
+        assert fits[name]["stage"].attrs["fe_layout"] == {
+            "global": "feature_major"}, name
+
+
 def test_the_mesh_loop_carries_row_sharded_vectors(fits):
     """On the mesh every coordinate scores the rows padded to the device
     count, sharded by rows, and a random effect asks its residuals
