@@ -55,6 +55,7 @@ from photon_tpu.models.game import (
     score_route,
     score_rows,
 )
+from photon_tpu.ops import cg_kernel
 from photon_tpu.ops import glm as glm_ops
 from photon_tpu.ops import losses as losses_mod
 from photon_tpu.ops import placement
@@ -258,7 +259,7 @@ def _spd_solve_cg(h: Array, b: Array, sub_dim: int,
     return x + run_cg(b - h @ x)
 
 
-def _solve_one_entity_direct(
+def _direct_system(
     x_indices: Array | None,  # [R, k] ELL slots, or None (dense layout)
     x_values: Array,  # [R, k] or [R, S]
     labels: Array,  # [R]
@@ -272,22 +273,21 @@ def _solve_one_entity_direct(
     prior: tuple[Array, Array] | None,
     *,
     sub_dim: int,
-    variance_computation: VarianceComputationType,
     l2_weight: Array,
     incremental_weight: Array,
-    task: TaskType,
 ):
-    """Exact per-entity solve for the squared-loss case: one batched
-    Cholesky instead of ~100 sequential L-BFGS device steps.
-
-    The per-entity GLMix subproblem for squared loss is a small convex
-    quadratic; its minimizer is the normal-equations solution
+    """First half of the exact per-entity solve for the squared-loss case
+    (vmapped over the bucket): the normal equations
       (X'^T diag(wt) X' + diag(pen)) w = X'^T diag(wt) (y - offset) (+ prior)
-    — identical (to machine precision) to what the reference's LBFGS/TRON
-    iterates toward (SingleNodeOptimizationProblem.run), but as a single
-    MXU-friendly [S, S] factorization per entity, vmapped over the bucket.
-    The subspace design matrix is densified per entity (S = sub_dim is small
-    by construction — LinearSubspaceProjector compression).
+    as ``(h [S, S], b [S], l2_diag [S])``, with the unit diagonal on
+    padding slots that keeps ``h`` SPD. Their minimizer is what the
+    reference's LBFGS/TRON iterates toward
+    (SingleNodeOptimizationProblem.run); ``_solve_block`` solves the
+    bucket's systems by fixed-count CG (``_spd_solve_cg`` vmapped, or the
+    lane kernel ``ops/cg_kernel.py``) and ``_direct_finish`` maps the
+    solutions back. The subspace design matrix is densified per entity
+    (S = sub_dim is small by construction — LinearSubspaceProjector
+    compression).
     """
     # Solver STATE (w, H, b, variances) lives in the label dtype (f32);
     # only the design matrix x may be stored bf16 under mixed precision,
@@ -328,8 +328,31 @@ def _solve_one_entity_direct(
     else:
         l2_diag = l2_weight * penalty_mask
     h = h + jnp.diag(l2_diag + (1.0 - valid_mask))
-    w_t = _spd_solve_cg(h, b, sub_dim) * valid_mask
+    return h, b, l2_diag
 
+
+def _direct_finish(
+    w_t: Array,  # [S] solution of _direct_system's h w = b
+    x_indices: Array | None,
+    x_values: Array,
+    labels: Array,
+    offsets: Array,
+    weights: Array,
+    valid_mask: Array,
+    factors: Array | None,
+    shifts: Array | None,
+    intercept_slot: Array,
+    l2_diag: Array,
+    *,
+    sub_dim: int,
+    variance_computation: VarianceComputationType,
+    task: TaskType,
+):
+    """Second half of the direct solve (vmapped over the bucket): mask
+    the padding slots, compute variances, map back to the original
+    space; returns the solvers' ``(w, variances, iterations, reason)``."""
+    dtype = labels.dtype
+    w_t = w_t * valid_mask
     norm = NormalizationContext(
         factors=factors, shifts=shifts,
         intercept_index=None if shifts is None else 0,
@@ -1232,6 +1255,12 @@ def _solve_block(
         block = dataclasses.replace(
             block, x_values=block.x_values.astype(dtype)
         )
+    # A direct bucket's CG runs in the lane kernel where its gate serves
+    # the width (ops/cg_kernel.py; the gram route keeps its own CG).
+    direct_kernel = (
+        direct and not gram_route
+        and cg_kernel.kernel_supported(sub_dim, dtype, spmd=spmd)
+    )
     # `solve.<route>`: the solver the statics select (scope names are
     # metadata on the operations; no operation changes). A dense Newton
     # bucket is named where its route is decided, in
@@ -1240,7 +1269,7 @@ def _solve_block(
     # fit program traced 7 s slower on the chip's host (PERF.md section
     # 6, PR 25; cause not found).
     if direct:
-        route = "direct"
+        route = "direct_kernel" if direct_kernel else "direct"
     elif newton:
         route = "newton_xla"  # sparse buckets: the vmapped XLA step
     elif use_owlqn:
@@ -1291,18 +1320,24 @@ def _solve_block(
                 )
                 return _scatter_results(w_all, v_all, codes, w, v, it, reason)
 
-        def direct_solver(xi, xv, lb, off, wt, pm, vm, f, sh, islot, prior_e):
-            return _solve_one_entity_direct(
+        def system(xi, xv, lb, off, wt, pm, vm, f, sh, islot, prior_e):
+            return _direct_system(
                 xi, xv, lb, off, wt, pm, vm, f, sh, islot, prior_e,
                 sub_dim=sub_dim,
-                variance_computation=variance_computation,
                 l2_weight=l2_weight,
                 incremental_weight=incremental_weight,
+            )
+
+        def finish(w_t, xi, xv, lb, off, wt, vm, f, sh, islot, l2_diag):
+            return _direct_finish(
+                w_t, xi, xv, lb, off, wt, vm, f, sh, islot, l2_diag,
+                sub_dim=sub_dim,
+                variance_computation=variance_computation,
                 task=task,
             )
 
         with solve_scope:
-            w, v, it, reason = jax.vmap(direct_solver)(
+            h, b, l2_diag = jax.vmap(system)(
                 block.x_indices,
                 block.x_values,
                 block.labels,
@@ -1314,6 +1349,25 @@ def _solve_block(
                 shifts_sub,
                 block.intercept_slots,
                 prior,
+            )
+            if direct_kernel:
+                w_t = cg_kernel.spd_solve_lanes(
+                    h, b, interpret=cg_kernel.interpret_required())
+            else:
+                w_t = jax.vmap(
+                    functools.partial(_spd_solve_cg, sub_dim=sub_dim))(h, b)
+            w, v, it, reason = jax.vmap(finish)(
+                w_t,
+                block.x_indices,
+                block.x_values,
+                block.labels,
+                offsets,
+                block.weights,
+                block.valid_mask,
+                factors_sub,
+                shifts_sub,
+                block.intercept_slots,
+                l2_diag,
             )
             return _scatter_results(w_all, v_all, codes, w, v, it, reason)
 
@@ -1748,8 +1802,15 @@ def solve_route(
     materialized slab is ``slab`` (an ``EntityBlocks``; only shapes and
     dtypes are read, never a value): the same tests in the same order,
     kept beside it so that a stage attribute can name the route without
-    a frame on the traced path. ``statics``: ``solver_statics``."""
+    a frame on the traced path. ``statics``: ``solver_statics``. A
+    direct bucket is named by the CG kernel's gate alone: the gram route
+    (``gram_mults`` from the planner; no cell's bucket reaches it,
+    PERF.md 7.24) is not told apart."""
     if statics["direct"]:
+        if cg_kernel.kernel_supported(
+            slab.proj.shape[-1], slab.labels.dtype, spmd=spmd
+        ):
+            return "direct_kernel"
         return "direct"
     if statics["newton"]:
         from photon_tpu.ops import newton_kernel as nk

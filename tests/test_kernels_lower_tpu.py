@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from photon_tpu.ops import cg_kernel as ck
 from photon_tpu.ops import newton_kernel as nk
 from photon_tpu.ops import placement
 from photon_tpu.ops import segment_reduce as sr
@@ -79,6 +80,17 @@ def test_newton_step_lowers(r, s, task):
         sds((1, bp)),
         r=r, s=s, task=task,
     )
+
+
+# [entities, width] of the direct route's CG systems: yahoo.refit's
+# per-user blocks (512 / 256 / 128 rows) and linear.refit's buckets.
+@pytest.mark.parametrize("b, s", [
+    (1213, 197), (4017, 126), (1776, 83), (72380, S_USER), (20000, S_MOVIE),
+])
+def test_cg_solve_lowers(b, s):
+    assert ck._vmem_estimate_bytes(s) <= ck._VMEM_BUDGET_BYTES
+    text = lower_tpu(ck.spd_solve_lanes, sds((b, s, s)), sds((b, s)))
+    assert "cg_solve_lanes" in text
 
 
 def test_newton_gate_is_a_vmem_bound():

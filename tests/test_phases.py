@@ -37,6 +37,13 @@ FIT = "jit(_fit_fn)/while/body/closed_call"
     (f"{FIT}/coord.per-user/residual/gather:", "", "residual"),
     (f"{FIT}/coord.global/solve.lbfgs/while/body/mul:", "", "fe_solve"),
     ("jit(_gather_score_mesh)/shard_map/gather:", "", "score"),
+    # the direct route's CG kernel, as the TPU compiler names it (its
+    # op_name in a v5e-topology compile of _solve_block), in the unfused
+    # loop's program and inside the fused fit's
+    ("jit(_solve_block)/solve.direct_kernel/jit(spd_solve_lanes)/"
+     "cg_solve_lanes/pallas_call:", "jit__solve_block", "re_solve"),
+    (f"{FIT}/coord.per-user/jit(_solve_block)/solve.direct_kernel/"
+     "jit(spd_solve_lanes)/cg_solve_lanes/pallas_call:", "", "re_solve"),
     # a scope beats the program's name
     ("jit(_solve_block)/residual/gather", "jit__solve_block", "residual"),
     ("jit(_solve_block)/solve.lbfgs/while", "jit__solve_block", "re_solve"),
