@@ -36,10 +36,12 @@ EXIT_NO_CHIP = 3
 class Reading:
     """What a per-layer metric's reader may look at: the run's own fields
     (config, traffic, cell, units, window_s, window_start, spans, trace,
-    compile_events, peaks, costs, xplane) and whatever the traffic kind's
-    ``report()`` names, with the three reductions several readers share.
-    ``chips`` is the cell's: a share of the whole fit divides by ``chips``
-    x ``peaks``, one device's trace by one chip's."""
+    compile_events, peaks, costs, counts, xplane) and whatever the traffic
+    kind's ``report()`` names, with the three reductions several readers
+    share. ``counts`` is ``costs.shard_counts`` of the run's data, taken
+    once the window has closed. ``chips`` is the cell's: a share of the
+    whole fit divides by ``chips`` x ``peaks``, one device's trace by one
+    chip's."""
 
     jobs = plan_shapes = fixed_iterations = None
     chips = 1
@@ -217,7 +219,7 @@ def _run_cell(man, cell: dict, seed: int, seconds: float, trace: bool,
                 counters_after["hits"] + counters_after["misses"]
                 - counters_before["hits"] - counters_before["misses"]),
             peaks=costs.chip_peaks(device["kind"]), costs=costs,
-            xplane=xplane,
+            counts=costs.shard_counts(config, data), xplane=xplane,
             window_start=window_start,
         )
         for m in man.per_layer(cell["name"]):

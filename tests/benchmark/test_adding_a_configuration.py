@@ -255,17 +255,22 @@ def test_a_whole_fit_share_divides_by_the_cells_chips(
         grown_root, traced, name, count, peak):
     """At one chip the float every accepted cell read before ``chips``
     was there; at four, for the same window, a quarter of it; and the
-    four-chip cell's line carries the quarter."""
+    four-chip cell's line carries the quarter. The counts are what the
+    run's data holds (``costs.shard_counts``; every shard here is
+    dense)."""
     man = Manifest(grown_root.tiny)
     out = traced(NAMED_CELL)
     config = man.config(NAMED_CONFIG)
+    counts = costs.shard_counts(config, man.generator(NAMED_CONFIG).generate(
+        config, out["window"]["seed"]))
     peaks = costs.chip_peaks(FAKE_DEVICE["kind"])
     window = dict(config=config, units=out["attempted"], costs=costs,
-                  window_s=out["window"]["window_s"], peaks=peaks)
+                  counts=counts, window_s=out["window"]["window_s"],
+                  peaks=peaks)
     read = man.metric_reader(name)
     one = read(run.Reading(**window, chips=1))
     assert one == read(run.Reading(**window))
-    assert one == 100.0 * count(config) * out["attempted"] / (
+    assert one == 100.0 * count(config, counts) * out["attempted"] / (
         out["window"]["window_s"] * peaks[peak])
     four = read(run.Reading(**window, chips=4))
     assert four == one / 4.0
@@ -273,8 +278,12 @@ def test_a_whole_fit_share_divides_by_the_cells_chips(
     # The one-chip fixture cell on the same configuration's sizes reads
     # against one chip's peak.
     other = traced("powerlaw.refit")
+    powerlaw = man.config("glmix_fixture_powerlaw")
+    powerlaw_counts = costs.shard_counts(
+        powerlaw, man.generator("glmix_fixture_powerlaw").generate(
+            powerlaw, other["window"]["seed"]))
     assert other["metrics"][name]["value"] == (
-        100.0 * count(man.config("glmix_fixture_powerlaw"))
+        100.0 * count(powerlaw, powerlaw_counts)
         * other["attempted"]
         / (other["window"]["window_s"] * peaks[peak]))
 

@@ -5,6 +5,7 @@ yardstick's FLOP and byte counts see neither padding nor the seed."""
 import copy
 import json
 import os
+import types
 
 import numpy as np
 import pytest
@@ -157,21 +158,49 @@ def test_costs_unchanged_by_padding(config):
     merged = copy.deepcopy(config)
     for c in merged["coordinates"][1:]:
         c["min_bucket_entities"] = 10**6  # every bucket merges upward
-    _, loose = _plan(merged, 7)
-    _, tight = _plan(config, 7)
+    data_loose, loose = _plan(merged, 7)
+    data_tight, tight = _plan(config, 7)
     padded = {k: sum(b * r for b, r in v) for k, v in loose.items()}
     packed = {k: sum(b * r for b, r in v) for k, v in tight.items()}
     assert any(padded[k] > packed[k] for k in packed)
-    assert costs.fit_flops(merged) == costs.fit_flops(config)
-    assert costs.fit_hbm_bytes(merged) == costs.fit_hbm_bytes(config)
+    counts_loose = costs.shard_counts(merged, data_loose)
+    counts_tight = costs.shard_counts(config, data_tight)
+    assert counts_loose == counts_tight
+    assert costs.fit_flops(merged, counts_loose) == costs.fit_flops(
+        config, counts_tight)
+    assert costs.fit_hbm_bytes(merged, counts_loose) == costs.fit_hbm_bytes(
+        config, counts_tight)
 
 
 def test_costs_by_hand():
-    cfg = {"rows": 10, "num_iterations": 2, "precision": "bfloat16",
-           "coordinates": [{"features": 3}, {"features": 2}]}
-    assert costs.fit_flops(cfg) == 2 * (2 * 10 * 3 * 5 + 2 * 10 * 2 * 4)
-    assert costs.fit_hbm_bytes(cfg) == 2 * (
-        10 * 3 * 2 + 120 + 10 * 2 * 2 + 120)
+    cfg = {"rows": 4, "num_iterations": 2, "precision": "bfloat16",
+           "coordinates": [{"name": "a", "shard": "a", "features": 3},
+                           {"name": "b", "shard": "b", "features": 2},
+                           {"name": "bag", "shard": "bag",
+                            "features": 1000}]}
+    # An ELL shard of 4 rows and 3 slots over 1000 columns: rows hold 1,
+    # 3, 0 and 2 nonzeros; the padding slots hold (0, 0.0), and so does
+    # row 3's first slot, a stored zero that does no work.
+    bag = types.SimpleNamespace(
+        indices=np.array([[7, 0, 0], [1, 2, 999], [0, 0, 0], [5, 5, 9]],
+                         np.int32),
+        values=np.array([[0.5, 0, 0], [1, 2, 3], [0, 0, 0], [0, 4, 1]],
+                        np.float32),
+        d=1000)
+    data = types.SimpleNamespace(features={
+        "a": np.ones((4, 3), np.float32), "b": np.ones((4, 2), np.float32),
+        "bag": bag})
+    counts = costs.shard_counts(cfg, data)
+    assert counts == {
+        "a": {"rows": 4, "shape": (4, 3)}, "b": {"rows": 4, "shape": (4, 2)},
+        "bag": {"rows": 4, "nonzeros": 6, "square_nonzeros": 1 + 9 + 0 + 4}}
+    # Forward 2 nnz + gradient 2 nnz + Hessian product 2 sum(k^2) for the
+    # bag; 2nd(2 + d) for each dense shard.
+    assert costs.fit_flops(cfg, counts) == 2 * (
+        2 * 4 * 3 * 5 + 2 * 4 * 2 * 4 + (2 * 6 + 2 * 6 + 2 * 14))
+    # bf16 values, int32 indices, three f32 row vectors a coordinate.
+    assert costs.fit_hbm_bytes(cfg, counts) == 2 * (
+        4 * 3 * 2 + 48 + 4 * 2 * 2 + 48 + 6 * (2 + 4) + 48)
     flops, bytes_ = costs.newton_step_cost(rows=4, dim=3, lanes=2)
     assert flops == 2 * (2 * 4 * 3 * 5 + 9.0)
     assert bytes_ == 2 * 4 * (12 + 12 + 6)

@@ -14,11 +14,11 @@ import copy
 import numpy as np
 import pytest
 
-from benchmark import check, sut
+from benchmark import check, costs, sut
 from benchmark import reference as plain
 from benchmark.manifest import Manifest, _read_json
 
-from conftest import REPO_ROOT, rehearse, shrink, tiny_copy
+from conftest import FAKE_DEVICE, REPO_ROOT, rehearse, shrink, tiny_copy
 
 MAN = Manifest()
 NAME = "glmix_yahoo_music"
@@ -319,6 +319,33 @@ def test_the_rehearsal_prints_the_subspace_metrics(traced_rehearsal):
     assert m["fit.fixed_iterations"] > 0
     # Squared loss runs no Newton kernel: nothing for its roofline.
     assert "kernel.newton_roofline_pct" not in m
+
+
+@pytest.mark.parametrize("name, count, peak", [
+    ("fit.mfu_pct", costs.fit_flops, "flops_per_s"),
+    ("fit.hbm_share_pct", costs.fit_hbm_bytes, "hbm_bytes_per_s"),
+])
+def test_the_rehearsal_counts_the_bag_by_its_nonzeros(
+        traced_rehearsal, name, count, peak):
+    """The whole-fit shares the rehearsal prints are the yardstick's counts
+    of what the tiny data set holds, over window x peak: the bag by its
+    stored nonzeros (135 209, their squares a row summed 508 785: a count
+    of the generated bags block by block), the dense shards by their
+    shapes. Counted by its 993 columns the bag would read over ten times
+    more."""
+    config = _config("tiny")
+    counts = costs.shard_counts(
+        config, GENERATOR.generate(config, 2**31 + 40))
+    assert counts["per-user"] == {
+        "rows": 40_000, "nonzeros": 135_209, "square_nonzeros": 508_785}
+    out = traced_rehearsal
+    peaks = costs.chip_peaks(FAKE_DEVICE["kind"])
+    want = 100.0 * (count(config, counts) * out["attempted"]) / (
+        out["window"]["window_s"] * (1 * peaks[peak]))
+    assert out["metrics"][name]["value"] == want
+    as_dense = dict(counts, **{"per-user": {
+        "rows": 40_000, "shape": (40_000, 993)}})
+    assert count(config, as_dense) > 10 * count(config, counts)
 
 
 @pytest.mark.parametrize("name", ["plan.subspace_pad_ratio",
